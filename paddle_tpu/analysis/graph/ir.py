@@ -2,7 +2,7 @@
 
 The AST tier (:mod:`paddle_tpu.analysis.rules`) sees Python source; this
 module sees what XLA sees — the traced jaxpr. :func:`build_graph` flattens
-a ``ClosedJaxpr`` (inlining ``pjit``/``custom_vjp``/``custom_jvp``/
+a ``ClosedJaxpr`` (inlining ``jit``/``custom_vjp``/``custom_jvp``/
 ``remat``/``shard_map`` sub-jaxprs, keeping ``pallas_call``/``scan``/
 ``while``/``cond`` opaque) into a list of :class:`OpNode` with:
 
@@ -83,16 +83,12 @@ _CONTROL = {"scan", "while", "cond", "fori_loop", "custom_root",
 
 # sub-jaxpr params inlined into the flat graph, by primitive name
 _INLINE_PARAMS = {
-    "pjit": "jaxpr",
+    "jit": "jaxpr",
     "closed_call": "call_jaxpr",
     "core_call": "call_jaxpr",
     "custom_jvp_call": "call_jaxpr",
     "custom_vjp_call": "call_jaxpr",
-    "custom_jvp_call_jaxpr": "fun_jaxpr",
-    "custom_vjp_call_jaxpr": "fun_jaxpr",
     "remat2": "jaxpr",
-    "remat": "jaxpr",
-    "checkpoint": "jaxpr",
     "shard_map": "jaxpr",
 }
 
@@ -173,7 +169,7 @@ def _flops_of(prim: str, eqn, out_elems: int, in_elems: int) -> float:
 class VarRef:
     """A jaxpr var at one inline instance.
 
-    jax CACHES traced sub-jaxprs (two ``jnp.var`` calls share one pjit
+    jax CACHES traced sub-jaxprs (two ``jnp.var`` calls share one jit
     jaxpr object), so raw var identity collides when the same sub-jaxpr
     is inlined at two call sites. A VarRef is interned per
     ``(inline-scope, var)``: ref identity == logical-value identity
@@ -206,10 +202,10 @@ class OpNode:
     flops: float = 0.0
     file: str = ""
     line: int = 0
-    name: str = ""        # pallas kernel name / pjit name, when present
+    name: str = ""        # pallas kernel name / jit name, when present
     sharding_spec: object = None   # PartitionSpec on sharding_constraint
     effectful: bool = False
-    path: str = ""        # inline path, e.g. "pjit:_einsum"
+    path: str = ""        # inline path, e.g. "jit:_einsum"
 
     param_sig: str = ""   # stable digest of eqn.params (duplicate detection)
 
@@ -252,6 +248,15 @@ class DataflowGraph:
         return self.consumers.get(id(var), [])
 
 
+def pallas_kernel_name(eqn, default: str) -> str:
+    """A ``pallas_call`` eqn's name: its explicit ``name=`` when given,
+    else the kernel body function's name."""
+    body = eqn.params["jaxpr"]
+    return str(eqn.params.get("name")
+               or getattr(body, "jaxpr", body).debug_info.func_name
+               or default)
+
+
 def _user_frame(source_info, prefer_file: str | None = None,
                 exclude_files: frozenset = frozenset()):
     """(file, line) for an eqn: the innermost frame outside jax AND outside
@@ -260,7 +265,7 @@ def _user_frame(source_info, prefer_file: str | None = None,
     bench's own trace_layer call site) so spans land on model code."""
     try:
         from jax._src import source_info_util as siu
-        frames = list(siu.user_frames(source_info))
+        frames = list(siu.user_frames(source_info.traceback))
     except Exception:
         return "", 0
     fallback = ("", 0)
@@ -314,13 +319,14 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
     """Flatten a ClosedJaxpr into a :class:`DataflowGraph`.
 
     Sub-jaxprs of call-like primitives (see ``_INLINE_PARAMS``) are inlined
-    so an op chain split across ``pjit`` boundaries is still one chain;
+    so an op chain split across ``jit`` boundaries is still one chain;
     opaque primitives (``pallas_call``, control flow) become single nodes
     carrying their whole-body byte counts.
     """
     import itertools
 
     import jax
+    from jax.extend.core import Literal
 
     g = DataflowGraph(name=name)
     jaxpr, _consts = _as_open(closed_jaxpr)
@@ -351,7 +357,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
     g.invars = [ref_of(v, root_scope) for v in jaxpr.invars]
     g.constvars = [ref_of(v, root_scope) for v in jaxpr.constvars]
     g.outvars = [ref_of(v, root_scope) for v in jaxpr.outvars
-                 if not isinstance(v, jax.core.Literal)]
+                 if not isinstance(v, Literal)]
 
     def visit(jx, path: str, depth: int, scope: int, sub_map: dict):
         """Walk eqns; sub_map maps inner VarRefs -> outer VarRefs at
@@ -373,8 +379,8 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
                 outer_in = list(eqn.invars)
                 inner_in = list(inner.invars)
                 for iv, ov in zip(reversed(inner_in), reversed(outer_in)):
-                    if isinstance(ov, jax.core.Literal) or \
-                            isinstance(iv, jax.core.Literal):
+                    if isinstance(ov, Literal) or \
+                            isinstance(iv, Literal):
                         continue
                     nmap[ref_of(iv, inner_scope)] = resolve(
                         ref_of(ov, scope), sub_map)
@@ -382,7 +388,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
                 for iv, ov in zip(inner_out, eqn.outvars):
                     # identity passthrough (outvar is a formal invar) keeps
                     # its invar mapping; the post-visit loop aliases it
-                    if not isinstance(iv, jax.core.Literal) and \
+                    if not isinstance(iv, Literal) and \
                             ref_of(iv, inner_scope) not in nmap:
                         nmap[ref_of(iv, inner_scope)] = ref_of(ov, scope)
                 sub_name = str(eqn.params.get("name", "") or "")
@@ -391,7 +397,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
                 # inner outvar may itself be an inner invar (identity):
                 # record a passthrough producer for the outer outvar
                 for iv, ov in zip(inner_out, eqn.outvars):
-                    if isinstance(iv, jax.core.Literal):
+                    if isinstance(iv, Literal):
                         continue
                     ovr = resolve(ref_of(ov, scope), sub_map)
                     if id(ovr) not in g.producer:
@@ -403,7 +409,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
             node = OpNode(index=len(g.nodes), prim=prim,
                           kind=classify(prim), path=path)
             ins = [resolve(ref_of(v, scope), sub_map) for v in eqn.invars
-                   if not isinstance(v, jax.core.Literal)]
+                   if not isinstance(v, Literal)]
             node.invars = ins
             # map formal sub-jaxpr outvars to their outer vars so the
             # producer registration below links inner producers to outer
@@ -420,9 +426,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
             node.file, node.line = _user_frame(eqn.source_info, prefer,
                                                excludes)
             if prim == "pallas_call":
-                nsi = eqn.params.get("name_and_src_info")
-                node.name = str(getattr(nsi, "name", "") or
-                                eqn.params.get("name", "") or "pallas")
+                node.name = pallas_kernel_name(eqn, "pallas")
             elif prim == "sharding_constraint":
                 sh = eqn.params.get("sharding")
                 node.sharding_spec = getattr(sh, "spec", None)
@@ -440,7 +444,7 @@ def build_graph(closed_jaxpr, name: str = "<jaxpr>",
                                   sum(max(node_elems(v), 1)
                                       for v in e.invars
                                       if not isinstance(
-                                          v, jax.core.Literal)))
+                                          v, Literal)))
                     for e in inner.eqns)
             g.nodes.append(node)
             for v in ins:

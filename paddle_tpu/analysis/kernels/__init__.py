@@ -7,19 +7,17 @@ BEFORE it ever reaches Mosaic, and statically COSTED so the cost model
 and the future block-shape autotuner know what a launch holds resident
 and moves.
 
-**Model plane** (:mod:`.model` → :mod:`.rules`, ids PK200-PK205/207-209):
+**Model plane** (:mod:`.model` → :mod:`.rules`, ids PK200-PK204/207-209):
 each kernel module's ``pk_examples()`` invocations are traced (never
 lowered or executed) and every reached ``pallas_call`` becomes a
 :class:`~.model.KernelModel` — concrete grid, block shapes, evaluable
 index maps, scratch, body jaxpr. Rules then check VMEM residency
 against ``cost_model.chip_vmem_bytes()``, output coverage / overlap /
-bounds by abstract evaluation over the real grid, tail masking, the
-jax-0.4.x Mosaic compat lessons (scalar mulf provenance, int8 dot),
+bounds by abstract evaluation over the real grid, tail masking,
 custom_vjp accumulation dtype discipline, prefetch misuse and dead
 operands.
 
-**AST plane** (PK206): source-visible environment bugs — ``jnp.pad``
-inside a kernel body, a ``pallas_call`` outside ``x64_off()``.
+**AST plane** (PK206): a ``pallas_call`` outside ``x64_off()``.
 
 **Resource sheets** (:mod:`.resources`): per-kernel static VMEM
 bytes/step, FLOPs, HBM bytes and arithmetic intensity, exported as
@@ -103,12 +101,14 @@ def collect(paths, chip=None):
         models, notes = extract_module(path)
         for note in notes:
             findings.append(Finding(
-                rule_id="PK209", severity=INFO,
+                rule_id="PK209", severity=ERROR if note.failed else INFO,
                 message=(f"[{note.label}] " if note.label else "")
                 + note.message,
                 file=note.file,
-                hint="add pk_examples() so the tier can model and cost "
-                     "this module's kernels"))
+                hint="repair the example: an unmodelled kernel is unchecked"
+                if note.failed else
+                "add pk_examples() so the tier can model and cost "
+                "this module's kernels"))
         for m in models:
             sheet = resource_sheet(m, budget)
             key = (m.name, m.grid, sheet.block_bytes,
@@ -147,7 +147,7 @@ def kernel_cost(module_or_path, chip=None) -> dict:
     :class:`~.resources.ResourceSheet` schema."""
     import importlib
 
-    from ...cost_model.collective import CHIP_PRESETS, chip_vmem_bytes
+    from ...cost_model.collective import chip_name, chip_vmem_bytes
     if hasattr(module_or_path, "__file__"):
         path = module_or_path.__file__
     elif os.path.sep in str(module_or_path) \
@@ -155,17 +155,15 @@ def kernel_cost(module_or_path, chip=None) -> dict:
         path = str(module_or_path)
     else:
         path = importlib.import_module(str(module_or_path)).__file__
-    chip_name = chip or os.environ.get("PADDLE_TPU_CHIP", "v5e")
-    if chip_name not in CHIP_PRESETS:
-        chip_name = "v5e"
-    budget = chip_vmem_bytes(chip_name)
+    chip = chip_name(chip)
+    budget = chip_vmem_bytes(chip)
     models, notes = extract_module(path)
     return {
         "module": os.path.basename(path),
-        "chip": chip_name,
+        "chip": chip,
         "vmem_budget": budget,
         "kernels": [_join_measured(resource_sheet(m, budget).to_dict(),
-                                   chip_name) for m in models],
+                                   chip) for m in models],
         "notes": [f"[{n.label}] {n.message}" if n.label else n.message
                   for n in notes],
     }

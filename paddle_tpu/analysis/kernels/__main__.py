@@ -50,14 +50,14 @@ def main(argv=None) -> int:
         argv,
         prog="python -m paddle_tpu.analysis.kernels",
         description="Pallas kernel analyzer: VMEM residency, output "
-                    "coverage/overlap, index-map bounds, Mosaic 0.4.x "
-                    "compat and dtype discipline over the kernels' "
+                    "coverage/overlap, index-map bounds, x64 "
+                    "discipline and dtype discipline over the kernels' "
                     "pk_examples() traces, plus static resource sheets "
                     "(docs/static_analysis.md#kernel-tier).",
         rules=RULES,
         analyze=analyze,
         allowlist_name=ALLOWLIST_NAME,
-        select_example="PK200,PK205",
+        select_example="PK200,PK203",
         positional_help="kernel .py files or directories "
                         "(e.g. paddle_tpu/ops/kernels/)",
         payload_extra=payload_extra,

@@ -2,8 +2,8 @@
 
 Each function below embeds exactly one deliberate kernel bug (PK200
 VMEM overflow, PK201 overlapping writes, PK202 coverage gap, PK203
-out-of-bounds index map, PK205 non-SMEM scalar mulf, PK206 jnp.pad in a
-body / pallas_call outside ``x64_off()``), isolated so the analyzer's
+out-of-bounds index map, PK206 pallas_call outside ``x64_off()``),
+isolated so the analyzer's
 finding list maps 1:1 onto the plants. ``tests/test_kernel_analysis.py``
 asserts the mapping; running the module analyzes itself:
 
@@ -77,25 +77,6 @@ def oob_read(x):
             out_shape=jax.ShapeDtypeStruct((64, 128), F32))(x)
 
 
-def _vmem_scalar_kernel(x_ref, o_ref):
-    s = x_ref[0, 0]  # rank-0 load from a VMEM block: a 0-d VECTOR to Mosaic
-    o_ref[...] = x_ref[...] * (s * 2.0)  # s * 2.0 is the broken mixed mulf
-
-
-def vmem_scalar_mulf(x):
-    """PK205: all-scalar mulf mixing a VMEM-loaded (0-d vector) scalar
-    with an immediate — fails Mosaic verification on jax 0.4.x."""
-    with x64_off():
-        return pl.pallas_call(
-            _vmem_scalar_kernel,
-            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype))(x)
-
-
-def _pad_kernel(x_ref, o_ref):
-    # PK206 (AST): jnp.pad inside a kernel body — @_pad symbol dedup
-    o_ref[...] = jnp.pad(x_ref[...], ((0, 8), (0, 0)))
-
-
 def missing_x64_off(x):
     """PK206 (AST): a pallas_call with no ``x64_off()`` discipline in
     sight — x64 literals reach Mosaic. Never traced; the AST plane
@@ -106,7 +87,7 @@ def missing_x64_off(x):
 
 
 def pk_examples():
-    """The traced plants (PK206's are AST-only, so not traced)."""
+    """The traced plants (PK206's is AST-only, so not traced)."""
     S = jax.ShapeDtypeStruct
     return [
         ("vmem_overflow", vmem_overflow, (S((4096, 2048), F32),), {}),
@@ -114,8 +95,6 @@ def pk_examples():
          (S((128, 128), F32),), {}),
         ("coverage_gap", coverage_gap, (S((128, 128), F32),), {}),
         ("oob_read", oob_read, (S((128, 128), F32),), {}),
-        ("vmem_scalar_mulf", vmem_scalar_mulf,
-         (S((128, 128), F32),), {}),
     ]
 
 

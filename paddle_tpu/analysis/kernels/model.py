@@ -37,6 +37,7 @@ class ExtractionNote:
     file: str
     label: str
     message: str
+    failed: bool = False         # True: the module HAS examples and one broke
 
 
 @dataclasses.dataclass
@@ -114,7 +115,7 @@ class BlockInfo:
 @dataclasses.dataclass
 class KernelModel:
     """One ``pallas_call`` site, fully concretized by one example."""
-    name: str                    # kernel body name (name_and_src_info)
+    name: str                    # pallas_call name= or kernel body name
     label: str                   # pk_examples() label that reached it
     file: str                    # kernel module file (finding anchor)
     line: int                    # pallas_call call-site line if known
@@ -158,7 +159,7 @@ def _block_dims(block_shape, array_shape):
         return tuple(int(d) for d in array_shape)
     out = []
     for b, a in zip(block_shape, array_shape):
-        out.append(int(b) if isinstance(b, int) else 1)
+        out.append(int(getattr(b, "block_size", 1)))
     return tuple(out)
 
 
@@ -169,7 +170,7 @@ def _memory_space(aval) -> str:
 
 def iter_pallas_eqns(jaxpr_like):
     """Yield every ``pallas_call`` eqn reachable through call-like
-    primitives (pjit / custom_vjp / remat / scan / while / cond ...)."""
+    primitives (jit / custom_vjp / remat / scan / while / cond ...)."""
     from ..graph.ir import _INLINE_PARAMS, _as_open
     seen = set()
 
@@ -203,12 +204,11 @@ def _model_from_eqn(eqn, label: str, file: str) -> KernelModel:
     gm = eqn.params["grid_mapping"]
     body = eqn.params["jaxpr"]
     body = getattr(body, "jaxpr", body)
-    name = str(getattr(eqn.params.get("name_and_src_info"), "name", "")
-               or "kernel")
+    from ..graph.ir import _user_frame, pallas_kernel_name
+    name = pallas_kernel_name(eqn, "kernel")
 
     line = 0
     try:
-        from ..graph.ir import _user_frame
         _, line = _user_frame(eqn.source_info,
                               prefer_file=os.path.abspath(file))
         line = int(line)
@@ -222,7 +222,7 @@ def _model_from_eqn(eqn, label: str, file: str) -> KernelModel:
     mappings = list(gm.block_mappings)
 
     def info(bm, is_output, pos):
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         return BlockInfo(
             origin=str(getattr(bm, "origin", "") or ""),
             block_shape=_block_dims(bm.block_shape, arr.shape),
@@ -265,8 +265,7 @@ def extract_callable(fn, args=(), kwargs=None, label: str = "",
 
     The trace runs under ``x64_off()`` (the package-wide Mosaic int-width
     discipline) with ``force_dispatch(True)`` so wrappers take their real
-    kernel path off-TPU. Trace only — nothing is lowered or executed, so
-    known 0.4.x Mosaic crashes (int8 dot) cannot trigger here."""
+    kernel path off-TPU. Trace only — nothing is lowered or executed."""
     import jax
 
     from ...ops.kernels import _common as kcommon
@@ -308,16 +307,18 @@ def extract_module(path: str):
     """(models, notes) for one kernel module file.
 
     A module without ``pk_examples()`` yields no models and one note
-    (the CLI surfaces it at info severity); a failing example yields a
-    note naming the example, never a crash — the remaining examples
-    still analyze."""
+    (the CLI surfaces it at info severity). A module that fails to import,
+    or an example that fails to trace, yields a ``failed`` note naming it
+    (an error finding: an analyzer that modelled nothing must not read
+    "clean"); the remaining examples still analyze."""
     models: list = []
     notes: list = []
     try:
         mod = load_kernel_module(path)
     except Exception as e:
         notes.append(ExtractionNote(
-            path, "", f"module import failed: {type(e).__name__}: {e}"))
+            path, "", f"module import failed: {type(e).__name__}: {e}",
+            failed=True))
         return models, notes
     examples = getattr(mod, "pk_examples", None)
     if examples is None:
@@ -330,7 +331,7 @@ def extract_module(path: str):
     except Exception as e:
         notes.append(ExtractionNote(
             path, "pk_examples",
-            f"pk_examples() raised: {type(e).__name__}: {e}"))
+            f"pk_examples() raised: {type(e).__name__}: {e}", failed=True))
         return models, notes
     for entry in entries:
         label, fn, args, kwargs = (tuple(entry) + ((), None))[:4]
@@ -340,5 +341,6 @@ def extract_module(path: str):
         except Exception as e:
             notes.append(ExtractionNote(
                 path, label,
-                f"example trace failed: {type(e).__name__}: {e}"))
+                f"example trace failed: {type(e).__name__}: {e}",
+                failed=True))
     return models, notes

@@ -1,18 +1,18 @@
 """PK200-PK209: the Pallas kernel safety rules.
 
-Two planes share the rule table. The MODEL plane (PK200-PK205,
+Two planes share the rule table. The MODEL plane (PK200-PK204,
 PK207-PK209) runs on :class:`~.model.KernelModel`s — concrete grids,
 block shapes and evaluable index maps extracted from ``pk_examples()``
 traces — so VMEM residency, output coverage/overlap and index bounds are
 checked by abstract evaluation over the real grid, not by pattern
-matching. The AST plane (PK206) runs on source: the two jax-0.4.x
-Mosaic environment bugs that manifest before any jaxpr exists
-(``jnp.pad`` inside a kernel body, a ``pallas_call`` traced outside the
-package's ``x64_off()`` discipline) are caught where they are written.
+matching. The AST plane (PK206) runs on source: a ``pallas_call`` traced
+outside the package's ``x64_off()`` discipline (the framework's global
+x64 hands Mosaic 64-bit index arithmetic it aborts on) is caught where it
+is written, before any jaxpr exists.
 
 Severity policy mirrors the other tiers: ERROR = the kernel is wrong or
 will not survive Mosaic (lost writes, garbage output, OOB blocks, VMEM
-overflow, known 0.4.x crashes); WARNING = legal but against the
+overflow, 64-bit types reaching Mosaic); WARNING = legal but against the
 package's discipline (unmasked tails, bf16 accumulation, dead operands).
 """
 
@@ -68,21 +68,11 @@ RULES = {r.id: r for r in [
          "padded tail lanes are read or written unmasked",
          "pad the operand with pad_to_block() at the wrapper (the "
          "package discipline) or mask tail lanes in the body"),
-    Rule("PK205", "mosaic-numeric-compat", ERROR,
-         "a pattern Mosaic on jax 0.4.x miscompiles or crashes on: an "
-         "all-scalar float mul/div mixing a ref-loaded (0-d vector) "
-         "scalar with an immediate, or a dot_general on int8 operands",
-         "keep a vector operand in every multiply involving a "
-         "ref-loaded scalar (fold immediates in first); keep int8 dots "
-         "behind the dispatch gate until the toolchain upgrade"),
     Rule("PK206", "mosaic-trace-compat", ERROR,
-         "a kernel-environment bug visible in source: jnp.pad inside a "
-         "kernel body (the shared @_pad helper dedups i32/i64 variants "
-         "into one invalid MLIR symbol), or a pallas_call traced "
-         "outside x64_off()/jit_x64_off (x64 literals break Mosaic "
-         "legalization)",
-         "use _common.pad_tail/pad_to_block outside the body; wrap "
-         "every pallas_call in `with x64_off():` or decorate the "
+         "a pallas_call traced outside x64_off()/jit_x64_off: the "
+         "framework's global x64 turns index-map/loop literals into "
+         "64-bit types, and the chip's Mosaic compiler aborts on them",
+         "wrap every pallas_call in `with x64_off():` or decorate the "
          "caller with jit_x64_off"),
     Rule("PK207", "vjp-dtype-discipline", WARNING,
          "low-precision accumulation inside the kernel: a dot_general "
@@ -162,27 +152,12 @@ def _used_vars(body):
     return used
 
 
-def _rank(v) -> int:
-    return len(tuple(getattr(getattr(v, "aval", None), "shape", ()) or ()))
-
-
 def _dtype_name(v) -> str:
     import numpy as np
     try:
         return np.dtype(v.aval.dtype).name
     except Exception:
         return ""
-
-
-def _is_smem_ref(v) -> bool:
-    aval = getattr(v, "aval", None)
-    ms = getattr(aval, "memory_space", None)
-    return ms is not None and "smem" in str(ms).lower()
-
-
-def _is_literal(v) -> bool:
-    # jax Literals carry both .aval and .val; Vars carry only .aval
-    return hasattr(v, "val")
 
 
 def _has_mask_pattern(body) -> bool:
@@ -201,53 +176,6 @@ def _has_mask_pattern(body) -> bool:
             if saw_iota and saw_cmp:
                 return True
     return False
-
-
-def _scalar_mulf_hits(m: KernelModel):
-    """(eqn, prim) for an all-scalar float mul/div with MIXED operand
-    provenance — the ``mulf`` shape Mosaic fails to verify on jax 0.4.x.
-
-    To Mosaic, a rank-0 value loaded from a VMEM block (or reduced from
-    a vector) is a 0-d VECTOR, while a literal / SMEM-loaded /
-    program-id scalar is a true scalar. Multiplying a real vector by
-    either kind broadcasts fine, and uniform-provenance scalar products
-    constant-fold or stay in sregs — but ``loaded_scalar * immediate``
-    lowers to ``mulf(vector<f32>, f32)``, which fails verification (see
-    the in-tree workaround note in ops/kernels/adamw_pallas.py:
-    "every multiply keeps a VECTOR operand"). Sub-jaxpr invars (loop
-    carries) are treated as true scalars — provenance is not tracked
-    across the boundary, so this rule under-reports inside fori bodies
-    rather than false-positives."""
-    hits = []
-    for jx in _walk_jaxprs(m.body):
-        vec0 = set()   # rank-0 values that are 0-d vectors to Mosaic
-        for eqn in jx.eqns:
-            p = eqn.primitive.name
-            out0 = eqn.outvars[0] if eqn.outvars else None
-            is_r0 = out0 is not None and _rank(out0) == 0
-
-            if p in ("get", "load", "masked_load"):
-                if is_r0 and not _is_smem_ref(eqn.invars[0]):
-                    vec0.add(id(out0))
-                continue
-            if p in ("mul", "div") and out0 is not None:
-                dt = _dtype_name(out0)
-                if dt.startswith("float") or dt.startswith("bfloat"):
-                    ops = [v for v in eqn.invars if hasattr(v, "aval")]
-                    if ops and all(_rank(v) == 0 for v in ops):
-                        kinds = {id(v) in vec0 and not _is_literal(v)
-                                 for v in ops}
-                        if kinds == {True, False}:
-                            hits.append((eqn, p))
-                            continue
-            # 0-d vectorness propagates through rank-0 arithmetic, and a
-            # rank-0 result computed from vector data (a full reduce)
-            # is born a 0-d vector
-            if is_r0 and any(hasattr(v, "aval")
-                             and (id(v) in vec0 or _rank(v) >= 1)
-                             for v in eqn.invars):
-                vec0.add(id(out0))
-    return hits
 
 
 # ---------------------------------------------------------------------------
@@ -352,32 +280,6 @@ def check_model(m: KernelModel, sheet, findings=None) -> list:
             f"reach the kernel with no masking in the body — tail "
             f"lanes are processed as garbage", **where))
 
-    # PK205 — Mosaic numeric compat
-    for eqn, p in _scalar_mulf_hits(m):
-        out.append(_find(
-            "PK205",
-            f"kernel '{m.name}': all-scalar float {p} mixing a "
-            f"ref-loaded (0-d vector) scalar with an immediate — this "
-            f"mulf shape fails Mosaic verification on jax 0.4.x",
-            **where))
-        break  # one per kernel is enough signal
-    for jx in _walk_jaxprs(m.body):
-        stop = False
-        for eqn in jx.eqns:
-            if eqn.primitive.name == "dot_general":
-                dts = {_dtype_name(v) for v in eqn.invars
-                       if hasattr(v, "aval")}
-                if "int8" in dts:
-                    out.append(_find(
-                        "PK205",
-                        f"kernel '{m.name}': dot_general on int8 "
-                        f"operands — segfaults Mosaic on jax 0.4.x "
-                        f"(keep behind the dispatch gate)", **where))
-                    stop = True
-                    break
-        if stop:
-            break
-
     # PK207 — low-precision accumulation
     lowp = ("bfloat16", "float16")
     for jx in _walk_jaxprs(m.body):
@@ -457,16 +359,6 @@ def check_model(m: KernelModel, sheet, findings=None) -> list:
 # the AST plane (PK206)
 # ---------------------------------------------------------------------------
 
-def _is_kernel_body(fn: ast.FunctionDef) -> bool:
-    """Kernel bodies are recognized by their ref parameters (the
-    package convention: every body takes ``*_ref(s)`` args)."""
-    names = [a.arg for a in fn.args.args + fn.args.posonlyargs
-             + fn.args.kwonlyargs]
-    names += [fn.args.vararg.arg] if fn.args.vararg else []
-    return any(n.endswith("_ref") or n.endswith("_refs") or n == "refs"
-               for n in names)
-
-
 def _call_name(node: ast.Call) -> str:
     f = node.func
     if isinstance(f, ast.Attribute):
@@ -522,28 +414,11 @@ def check_source(source: str, filename: str = "<string>") -> list:
             stack.append(node)
         return stack
 
-    kernel_fns = [n for n in ast.walk(tree)
-                  if isinstance(n, ast.FunctionDef) and _is_kernel_body(n)]
-    kernel_fn_set = set(map(id, kernel_fns))
-
     for node in ast.walk(tree):
         if not isinstance(node, ast.Call):
             continue
         name = _call_name(node)
-        if name == "pad" and isinstance(node.func, ast.Attribute) \
-                and isinstance(node.func.value, ast.Name) \
-                and node.func.value.id in ("jnp", "np"):
-            if node.func.value.id == "jnp" and any(
-                    id(a) in kernel_fn_set for a in ancestry(node)):
-                enc = next((a.name for a in ancestry(node)
-                            if isinstance(a, ast.FunctionDef)), "")
-                out.append(_find(
-                    "PK206",
-                    "jnp.pad inside a kernel body: the shared @_pad "
-                    "pjit helper dedups i32/i64 specializations into "
-                    "one invalid MLIR symbol on jax 0.4.x",
-                    file=filename, line=node.lineno, symbol=enc))
-        elif name == "pallas_call":
+        if name == "pallas_call":
             stack = ancestry(node)
             if not _with_x64(stack):
                 enc = next((a.name for a in stack
@@ -552,7 +427,7 @@ def check_source(source: str, filename: str = "<string>") -> list:
                     "PK206",
                     "pallas_call traced outside x64_off(): the "
                     "framework's global x64 turns index-map/loop "
-                    "literals into i64 types Mosaic cannot legalize",
+                    "literals into i64 types Mosaic aborts on",
                     file=filename, line=node.lineno, symbol=enc))
     out.sort(key=lambda f: f.sort_key())
     return out

@@ -183,6 +183,11 @@ def _run_backward_impl(tensors, grad_tensors, retain_graph, create_graph,
                 else:
                     leaf_grads[id(t_in)] = _acc(leaf_grads.get(id(t_in)), c)
                     leaf_tensors[id(t_in)] = t_in
+            if not retain_graph:
+                # a consumed node frees what it saved: the activations it
+                # holds would otherwise live as long as the loss tensor
+                node.inputs, node.input_nodes = [], []
+                node.raw_inputs, node.jfn = [], None
 
     for t, g in root_leaf:
         g = _run_hooks(t, g)

@@ -38,7 +38,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 __all__ = ["LinkSpec", "ChipSpec", "CHIP_PRESETS", "chip_preset",
-           "chip_vmem_bytes", "all_reduce_s", "all_gather_s",
+           "chip_name", "chip_vmem_bytes", "all_reduce_s", "all_gather_s",
            "reduce_scatter_s", "all_to_all_s", "p2p_s",
            "collective_s", "COLLECTIVE_FORMULAS"]
 
@@ -114,16 +114,36 @@ def chip_preset(name: str) -> ChipSpec:
                        f"(have {sorted(CHIP_PRESETS)})") from None
 
 
-def chip_vmem_bytes(name: str | None = None) -> int:
-    """Per-core VMEM budget for the current (or named) chip preset.
+#: device_kind substrings (lower-case, first match wins) -> preset name
+_KIND_TO_PRESET = (("v6 lite", "v6e"), ("v6e", "v6e"), ("v5 lite", "v5e"),
+                   ("v5lite", "v5e"), ("v5e", "v5e"), ("v5p", "v5p"),
+                   ("v5", "v5p"), ("v4", "v4"))
 
-    The chip is named by ``$PADDLE_TPU_CHIP`` (default ``v5e``); unknown
-    names fall back to ``v5e`` too, so an exotic env value degrades to
-    the conservative 16 MiB rather than crashing a kernel import."""
+
+def chip_name(name: str | None = None) -> str:
+    """The preset to cost against: an explicit ``name``, else
+    ``$PADDLE_TPU_CHIP``, else the attached device's kind. An unknown
+    name or kind raises — there is no default chip."""
     import os
-    name = name or os.environ.get("PADDLE_TPU_CHIP", "v5e")
-    preset = CHIP_PRESETS.get(name) or CHIP_PRESETS["v5e"]
-    return int(preset["vmem_bytes"])
+    name = name or os.environ.get("PADDLE_TPU_CHIP")
+    if name:
+        chip_preset(name)
+        return name
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return "cpu"
+    kind = dev.device_kind.lower()
+    for marker, preset in _KIND_TO_PRESET:
+        if marker in kind:
+            return preset
+    raise KeyError(f"no chip preset for device kind {dev.device_kind!r} "
+                   f"(have {sorted(CHIP_PRESETS)})")
+
+
+def chip_vmem_bytes(name: str | None = None) -> int:
+    """Per-core VMEM budget of :func:`chip_name`'s preset."""
+    return int(chip_preset(chip_name(name))["vmem_bytes"])
 
 
 def roofline_ms(flops: float, hbm_bytes: float,
@@ -132,10 +152,7 @@ def roofline_ms(flops: float, hbm_bytes: float,
     the chip's roofline, in milliseconds. The prediction the tuning
     cache's measured entries are compared against (``kernel_cost``'s
     ``predicted_vs_measured``)."""
-    import os
-    chip = CHIP_PRESETS.get(
-        name or os.environ.get("PADDLE_TPU_CHIP", "v5e"),
-        CHIP_PRESETS["v5e"])
+    chip = chip_preset(chip_name(name))
     compute_s = float(flops) / float(chip["peak_flops"])
     memory_s = float(hbm_bytes) / (float(chip["hbm_gbps"]) * 1e9)
     return max(compute_s, memory_s) * 1e3

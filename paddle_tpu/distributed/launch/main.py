@@ -9,8 +9,10 @@ process, tails logs into --log_dir, and restarts failed workers up to
 
 TPU-native: the normal deployment is ONE process per host (jax.distributed
 over DCN; all local chips visible to that process), so --nproc_per_node
-defaults to 1; multi-proc-per-node remains available for CPU tests — the
-reference's Gloo-style pattern (SURVEY.md §4.2).
+defaults to 1; multi-proc-per-node remains available for CPU workers
+(JAX_PLATFORMS=cpu) — the reference's Gloo-style pattern (SURVEY.md §4.2) —
+and is refused on a host with TPU chips, where the children would contend
+for chips they are not bound to.
 """
 
 from __future__ import annotations
@@ -126,8 +128,23 @@ def _ps_env(args, role, index, server_eps, trainer_eps, master):
     return env
 
 
+def _local_tpu_chips() -> int:
+    """TPU chips on this host, counted from their device files so that the
+    launcher itself never initialises jax (a parent that has touched jax
+    holds the chip its children need)."""
+    import glob
+    return len(glob.glob("/dev/accel*")) or len(glob.glob("/dev/vfio/[0-9]*"))
+
+
 def launch(argv=None):
     args = _parse(argv if argv is not None else sys.argv[1:])
+    if args.nproc_per_node > 1 and _local_tpu_chips() and \
+            os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        raise SystemExit(
+            f"--nproc_per_node {args.nproc_per_node} on a TPU host: a chip "
+            "belongs to one process, and the children are not bound to "
+            "chips of their own. Run one process per host (it drives every "
+            "local chip), or set JAX_PLATFORMS=cpu for CPU workers.")
     master = args.master or f"127.0.0.1:{_free_port()}"
     log_dir = args.log_dir
     if log_dir:

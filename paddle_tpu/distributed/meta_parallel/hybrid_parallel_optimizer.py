@@ -45,7 +45,24 @@ class HybridParallelOptimizer:
                 optimizer._grad_clip, hcg)
 
     def step(self):
+        self._align_grads()
         self._inner_opt.step()
+
+    def _align_grads(self):
+        """Give every gradient its parameter's sharding before the update.
+
+        A gradient leaves the backward in whatever layout GSPMD chose for
+        it (a column-parallel weight's came back split over `sharding`
+        too, the vocab-parallel embedding's over the other axis). The
+        update then mixes layouts: each elementwise op reshards through
+        full-size gathers — on the chip that exhausted a device at the
+        first AdamW step — and the parameter comes out in the gradient's
+        layout instead of its declared one."""
+        from ..sharding_utils import mark_sharding
+        for p in getattr(self._inner_opt, "_parameter_list", None) or ():
+            g = getattr(p, "_grad", None)
+            if g is not None and p._sharding_spec is not None:
+                mark_sharding(g, p._sharding_spec)
 
     def _fused_scale_step(self, scale):
         # explicit opt-in to the GradScaler fused unscale+step hook: this

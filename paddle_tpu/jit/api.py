@@ -20,9 +20,11 @@ Shape/dtype changes retrace (a new cache entry), mirroring SOT guards.
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
+import weakref
 from functools import wraps
 
 import jax
@@ -123,26 +125,43 @@ def _aval_or_value(x):
 
 
 class _Tracker:
-    """Records concrete Tensors touched during the discovery call."""
+    """Records concrete Tensors touched during the discovery call.
+
+    The discovery call runs eagerly, so every intermediate (activations,
+    residuals saved for backward, gradients) is a concrete Tensor that gets
+    read too. Only what OUTLIVES the call is state: the tracker holds weak
+    references, and :meth:`survivors` keeps the tensors something else
+    still owns (parameters, optimizer accumulators, RNG and pool state).
+    Holding the intermediates instead pinned every activation of the step
+    in device memory for the life of the compiled function and threaded
+    them through the program as dead arguments."""
 
     def __init__(self):
-        self.order: list[Tensor] = []
-        self._seen: set[int] = set()
+        self._order: list = []          # weakrefs, first-touch order
+        self._seen: dict = {}           # id -> weakref (ids get reused)
 
     def _record(self, t: Tensor):
-        if id(t) in self._seen:
+        r = self._seen.get(id(t))
+        if r is not None and r() is t:
             return
         arr = t._d
         if isinstance(arr, jax.core.Tracer):
             return  # intermediate value created during this call
-        self._seen.add(id(t))
-        self.order.append(t)
+        r = weakref.ref(t)
+        self._seen[id(t)] = r
+        self._order.append(r)
 
     def on_read(self, t: Tensor):
         self._record(t)
 
     def on_write(self, t: Tensor):
         self._record(t)
+
+    def survivors(self) -> list:
+        """Tracked tensors still alive, in first-touch order. Call after
+        the discovery call has returned (its locals are gone)."""
+        gc.collect()    # autograd nodes and their tensors form cycles
+        return [t for t in (r() for r in self._order) if t is not None]
 
 
 def _is_floatlike(x):
@@ -209,7 +228,7 @@ class StaticFunction:
             out = self._fn(*args, **kwargs)
         finally:
             tensor_mod._TRACKER = prev
-        self._state = tracker.order
+        self._state = tracker.survivors()
         return out
 
     def _compile(self, treedef, sig, kwargs_static, state_tensors=None):
@@ -248,10 +267,20 @@ class StaticFunction:
         # capture out_tree via a mutable cell; jit the array part
         cell = {}
 
+        donate = self._donate
+
         def pure_arrays(state_arrays, arg_arrays):
             new_state, out_flat, out_tree = pure(state_arrays, arg_arrays)
             cell["out_tree"] = out_tree
-            return new_state, out_flat
+            # state the step only READ (a serving engine's weights) is not
+            # returned: jit copies a forwarded input into a fresh output
+            # buffer on every call, which doubled the weights' memory and
+            # re-wrote them each step. Donated state aliases its output at
+            # no cost and must come back (its input buffer is consumed).
+            cell["written"] = [
+                i for i, (n, a) in enumerate(zip(new_state, state_arrays))
+                if donate or n is not a]
+            return [new_state[i] for i in cell["written"]], out_flat
 
         jitted = jax.jit(pure_arrays,
                          donate_argnums=(0,) if self._donate else ())
@@ -436,9 +465,8 @@ class StaticFunction:
         from ..ops.kernels import _common as _kern
         if _kern.interpret_mode():
             # interpret-mode pallas (the CPU test hook) re-traces its grid
-            # emulation at the OUTER program's first-call lowering; on jax
-            # 0.4.x that retrace must see the kernels' 32-bit world or the
-            # mixed-dtype helper symbols fail MLIR verification
+            # emulation at the OUTER program's first-call lowering; that
+            # retrace must see the kernels' 32-bit world too
             with _kern.x64_off():
                 return self._run_compiled_inner(jitted, cell, state_list,
                                                 arg_arrays)
@@ -468,7 +496,8 @@ class StaticFunction:
                 _cont.note_program(f"to_static:{self._obs_name}", self)
         else:
             new_state, out_flat = jitted(state_arrays, arg_arrays)
-        for t, a in zip(state_list, new_state):
+        for i, a in zip(cell["written"], new_state):
+            t = state_list[i]
             t._d = stream_state_out(t, a)
             t._node = None
         return jax.tree_util.tree_unflatten(cell["out_tree"], out_flat)
@@ -674,6 +703,13 @@ class StaticFunction:
         jitted, _ = self._compile(treedef, sig, dict(kwargs), state_list)
         state_arrays = [t._d for t in state_list]
         return jitted.lower(state_arrays, list(args_flat)).compile().as_text()
+
+    def compiled_text_cached(self) -> list:
+        """Optimized HLO text of every program this function has compiled,
+        rebuilt from the abstract shapes kept with each cache entry (no
+        call arguments needed; the compile hits jax's compilation cache)."""
+        return [jitted.lower(*cell["avals"]).compile().as_text()
+                for jitted, cell, _ in self._cache.values()]
 
     # -- parity surface -----------------------------------------------------
     def concrete_program(self):

@@ -76,7 +76,7 @@ __all__ = [
 
 TRACEPARENT_VERSION = "00"
 
-#: child-span names the serving path emits (the docs' span taxonomy)
+#: child-span names the serving path emits (the docs' span kinds)
 SPAN_KINDS = ("queue_wait", "admit", "prefill", "prefill_chunk", "decode",
               "speculate", "evict", "cow", "stream")
 

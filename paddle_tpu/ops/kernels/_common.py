@@ -20,7 +20,6 @@ _FORCE_DISPATCH = False  # test hook: dispatch real kernels off-TPU (for
 def force_interpret(enable: bool) -> None:
     global _INTERPRET
     _INTERPRET = bool(enable)
-    available.cache_clear()
 
 
 def force_dispatch(enable: bool) -> None:
@@ -29,7 +28,6 @@ def force_dispatch(enable: bool) -> None:
     traces (jit(...).trace(...).lower(lowering_platforms=("tpu",)))."""
     global _FORCE_DISPATCH
     _FORCE_DISPATCH = bool(enable)
-    available.cache_clear()
 
 
 def interpret_mode() -> bool:
@@ -37,24 +35,15 @@ def interpret_mode() -> bool:
 
 
 def x64_off():
-    """Version-compat ``jax.enable_x64(False)``: top-level on newer jax,
-    only ``jax.experimental.disable_x64`` (same context manager) on
-    0.4.x. Every pallas_call in this package traces under it — the
-    framework enables x64 globally, which turns index-map/loop literals
-    into i64/f64 types Mosaic cannot legalize."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    from jax.experimental import disable_x64
-    return disable_x64()
+    """``jax.enable_x64(False)``. Every pallas_call in this package traces
+    under it — the framework enables x64 globally, which turns
+    index-map/loop literals into i64/f64 types Mosaic cannot legalize."""
+    return jax.enable_x64(False)
 
 
 def jit_x64_off(fn, **jit_kwargs):
     """``jax.jit`` whose CALLS run under :func:`x64_off` — so the trace
-    AND the compile/lowering see the same 32-bit world. On jax 0.4.x the
-    interpret-mode pallas grid emulation lowers index maps and padding
-    helpers at compile time; with only an in-body guard their python-int
-    arithmetic promotes to i64 under the framework's global x64 and
-    MLIR verification fails on the mixed-dtype calls."""
+    AND the compile/lowering see the same 32-bit world."""
     jitted = jax.jit(fn, **jit_kwargs)
 
     @functools.wraps(fn)
@@ -70,14 +59,7 @@ def round_up(n, multiple):
 
 
 def pad_tail(a, pad, axis=0, value=0.0):
-    """Append ``pad`` fill rows along ``axis``.
-
-    Concatenate rather than ``jnp.pad``: jnp.pad lowers through a shared
-    ``@_pad`` pjit helper, and on jax 0.4.x a kernel traced under
-    :func:`x64_off` inside an x64-on outer program gets that helper
-    specialized with BOTH i32 and i64 scalar operands under one MLIR
-    symbol — the dedup-by-name then fails verification. Concatenate has
-    no helper symbol and XLA fuses it identically."""
+    """Append ``pad`` fill rows along ``axis``."""
     import jax.numpy as jnp
     if not pad:
         return a
@@ -156,10 +138,25 @@ def padded_rows(rows):
 
 
 @functools.cache
+def _on_tpu() -> bool:
+    return jax.devices()[0].platform == "tpu"
+
+
+def partitioned() -> bool:
+    """True under a multi-device mesh. The chip's compiler refuses a Mosaic
+    kernel in a partitioned program ("cannot be automatically partitioned;
+    wrap the call in a shard_map"), and no kernel here carries a shard_map
+    yet — so a mesh-sharded program runs the XLA composites, which GSPMD
+    partitions."""
+    import sys
+    topo = sys.modules.get("paddle_tpu.distributed.topology")
+    mesh = topo.get_mesh() if topo is not None else None
+    return mesh is not None and mesh.size > 1
+
+
 def available() -> bool:
+    """Do the Pallas kernels dispatch? On a TPU outside a multi-device
+    mesh; always under the interpret/force-dispatch test hooks."""
     if _INTERPRET or _FORCE_DISPATCH:
         return True
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return _on_tpu() and not partitioned()

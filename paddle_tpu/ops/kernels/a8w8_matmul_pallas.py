@@ -44,7 +44,8 @@ from ._common import pad_to_block, pick_row_block, x64_off, jit_x64_off
 
 # 5/8 of the chip preset's VMEM (10 MiB on the 16 MiB presets): x + w +
 # out + acc blocks, leaving headroom for the pipeline's double buffering
-_VMEM_BUDGET = (chip_vmem_bytes() * 5) // 8
+def _vmem_budget():
+    return (chip_vmem_bytes() * 5) // 8
 
 
 def _kernel(x_ref, w_ref, ws_ref, o_ref, *, nk_layout):
@@ -66,7 +67,7 @@ def _pick_blocks(m, k, n, itemsize):
     bn = 256
     while k * bn > chip_vmem_bytes() // 4 and bn > 128:  # int8 weight block
         bn //= 2
-    budget_x = max(_VMEM_BUDGET - k * bn - bn * 4, k * itemsize * 8)
+    budget_x = max(_vmem_budget() - k * bn - bn * 4, k * itemsize * 8)
     bm = pick_row_block(m, k * itemsize, budget_x, key="a8w8")
     return bm, bn
 

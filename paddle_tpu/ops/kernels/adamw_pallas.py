@@ -40,9 +40,8 @@ def _adamw_kernel(s_ref, w_ref, g_ref, m_ref, v_ref,
     v = jnp.float32(beta2) * v_ref[...] + jnp.float32(1 - beta2) * (g * g)
     mhat = m * inv_bc1
     vhat = v * inv_bc2
-    # every multiply keeps a VECTOR operand: a ref-loaded scalar is a 0-d
-    # vector to Mosaic, and scalar x scalar products (lr * wd) lower to a
-    # mixed mulf(vector<f32>, f32) that fails verification on jax 0.4.x
+    # every multiply keeps a VECTOR operand (a ref-loaded scalar is a 0-d
+    # vector to Mosaic)
     w = w - (w * lr) * jnp.float32(wd)
     w = w - (mhat / (jnp.sqrt(vhat) + jnp.float32(eps))) * lr
     wo_ref[...] = w

@@ -12,7 +12,7 @@ fingerprint over everything that invalidates a measurement: kernel name,
 argument shapes, dtypes, chip preset, quant layout and ``jax.__version__``
 (a new compiler may pick different layouts — stale schedules must
 re-measure, never silently load).  The cache file is JSON at
-``$PADDLE_TPU_TUNE_CACHE`` (default ``~/.cache/paddle_tpu/
+``$PADDLE_TPU_TUNE_CACHE`` (default ``<checkout>/.jax_cache/
 tuning_cache.json``), written atomically (tmp + rename) so a crashed
 trial never truncates previous winners.
 
@@ -41,6 +41,8 @@ import json
 import os
 import time
 
+from ... import REPO_ROOT
+from ...cost_model.collective import chip_name
 from . import _common as kern
 from .decode_layer_pallas import BLOCK_I_KEY, decode_layer, use_kernel
 
@@ -71,8 +73,7 @@ def kernel_fingerprint(kernel, shapes=(), dtypes=(), chip=None,
     Keyed like the structure cache — same digest size, same "changed
     input means changed key, never a stale read" rule."""
     import jax
-    if chip is None:
-        chip = os.environ.get("PADDLE_TPU_CHIP", "v5e")
+    chip = chip_name(chip)
     payload = repr((str(kernel), tuple(tuple(s) for s in shapes),
                     tuple(str(d) for d in dtypes), str(chip),
                     str(quant), extra, jax.__version__))
@@ -88,8 +89,7 @@ class TuningCache:
 
     def __init__(self, path=None):
         self.path = path or os.environ.get(_CACHE_ENV) or os.path.join(
-            os.path.expanduser("~"), ".cache", "paddle_tpu",
-            "tuning_cache.json")
+            REPO_ROOT, ".jax_cache", "tuning_cache.json")
         self.hits = 0
         self.misses = 0
         self.measure_seconds = 0.0
@@ -237,7 +237,7 @@ def tune_decode_layer(b, h, h_kv, d, page_size, n_pages, hd, i_size,
     best = min(timings, key=timings.get)
     entry = {
         "kernel": "block_decode_layer",
-        "chip": chip or os.environ.get("PADDLE_TPU_CHIP", "v5e"),
+        "chip": chip_name(chip),
         "block_i": int(best),
         "ms": timings[best] * 1e3,
         "timings_ms": {str(c): t * 1e3 for c, t in timings.items()},
@@ -272,7 +272,7 @@ def lookup_measured(kernel, chip=None, cache=None):
     cost-model join (``kernel_cost`` prefers this measured ms over the
     analytic roofline). Telemetry-neutral (peeks, never counts)."""
     cache = cache or default_cache()
-    chip = chip or os.environ.get("PADDLE_TPU_CHIP", "v5e")
+    chip = chip_name(chip)
     best = None
     for entry in cache.entries().values():
         if not isinstance(entry, dict):

@@ -304,6 +304,13 @@ def use_kernel(q_shape, pool_shape, n_pages, hd, i_size,
     from . import _common as kern
     if not kern.available():
         return False
+    if not (kern.interpret_mode() or kern._FORCE_DISPATCH):
+        raise NotImplementedError(
+            "block_decode_layer is withdrawn on the chip: the v5e compiler "
+            "refuses its (1, hidden) row blocks (not (8, 128)-divisible nor "
+            "the full array), and its VMEM gate admits no published width. "
+            "Drop ServingConfig(fused_decode_layer=True); the default "
+            "decode path (mmha + block_decode_epilogue) serves.")
     if len(q_shape) != 3 or len(pool_shape) != 4:
         return False
     b, h, d = q_shape

@@ -67,12 +67,15 @@ def _reference_attention(q, k, v, causal, segment_ids=None):
 
 
 def _pallas_ok(q) -> bool:
-    """Kernel constraints: seq divisible by the block size it will pick."""
+    """Kernel constraints: seq divisible by the block size it will pick,
+    and that block a multiple of the 8-row sublane tile (the chip's
+    compiler refuses a 7-row k block: "cannot statically prove that index
+    in dimension 1 is a multiple of 8")."""
     if not available():
         return False
     s = q.shape[1]
     blk = min(256, s)
-    return s % blk == 0
+    return s % blk == 0 and blk % 8 == 0
 
 
 @jax.custom_vjp
@@ -87,24 +90,18 @@ def _flash_full(q, k, v):
 
 def _flash_impl(q, k, v, causal):
     if _pallas_ok(q):
-        try:
-            from .flash_attention_pallas import flash_attention_forward
-            return flash_attention_forward(q, k, v, causal=causal,
-                                           interpret=interpret_mode())
-        except Exception:
-            pass
+        from .flash_attention_pallas import flash_attention_forward
+        return flash_attention_forward(q, k, v, causal=causal,
+                                       interpret=interpret_mode())
     return _reference_attention(q, k, v, causal)
 
 
 def _fwd_impl(q, k, v, causal):
     if _pallas_ok(q):
-        try:
-            from .flash_attention_pallas import flash_attention_forward_lse
-            out, lse = flash_attention_forward_lse(q, k, v, causal=causal,
-                                                   interpret=interpret_mode())
-            return out, (q, k, v, out, lse)
-        except Exception:
-            pass
+        from .flash_attention_pallas import flash_attention_forward_lse
+        out, lse = flash_attention_forward_lse(q, k, v, causal=causal,
+                                               interpret=interpret_mode())
+        return out, (q, k, v, out, lse)
     out = _reference_attention(q, k, v, causal)
     return out, (q, k, v, None, None)
 
@@ -112,13 +109,10 @@ def _fwd_impl(q, k, v, causal):
 def _bwd_impl(causal, res, g):
     q, k, v, out, lse = res
     if lse is not None:
-        try:
-            from .flash_attention_pallas import flash_attention_backward
-            return flash_attention_backward(q, k, v, out, lse, g,
-                                            causal=causal,
-                                            interpret=interpret_mode())
-        except Exception:
-            pass
+        from .flash_attention_pallas import flash_attention_backward
+        return flash_attention_backward(q, k, v, out, lse, g,
+                                        causal=causal,
+                                        interpret=interpret_mode())
     _, vjp = jax.vjp(lambda a, b, c: _reference_attention(a, b, c, causal),
                      q, k, v)
     return vjp(g)

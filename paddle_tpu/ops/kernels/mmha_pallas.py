@@ -43,11 +43,6 @@ NEG_INF = -1e30
 # so t % BLOCK_T == 0 always holds on the decode path
 BLOCK_T = 256
 
-# full-cache VMEM residency bound per (batch, kv-head) program: k + v blocks
-# must fit comfortably under the chip preset's VMEM capacity with room for
-# the accumulators and double buffering — half the shared budget
-# (cost_model.chip_vmem_bytes, also the kernel analyzer's PK200 bound)
-_VMEM_BYTES = chip_vmem_bytes() // 2
 
 
 def _mmha_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, *, block_t, scale):
@@ -102,7 +97,9 @@ def use_kernel(q_shape, cache_shape, cache_dtype, block_t=BLOCK_T) -> bool:
     if t % min(block_t, t) or t < 8:
         return False
     itemsize = jnp.dtype(cache_dtype).itemsize
-    return 2 * t * d * itemsize <= _VMEM_BYTES
+    # k + v blocks stay VMEM-resident per (batch, kv-head) program: half the
+    # chip budget, the rest for accumulators and double buffering
+    return 2 * t * d * itemsize <= chip_vmem_bytes() // 2
 
 
 @functools.partial(jit_x64_off, static_argnames=("block_t", "interpret"))

@@ -27,7 +27,8 @@ from ._common import pad_to_block, pick_row_block, x64_off, jit_x64_off
 
 # x + w + out + acc blocks: 5/8 of the shared chip VMEM budget (10 MiB
 # on the 16 MiB presets), same source of truth as the kernel analyzer
-_VMEM_BUDGET = (chip_vmem_bytes() * 5) // 8
+def _vmem_budget():
+    return (chip_vmem_bytes() * 5) // 8
 
 
 def _wo_kernel(x_ref, w_ref, s_ref, o_ref):
@@ -61,7 +62,7 @@ def _pick_blocks(m, k, n, itemsize):
     bn = 256
     while k * bn > chip_vmem_bytes() // 4 and bn > 128:  # int8 weight block
         bn //= 2
-    budget_x = max(_VMEM_BUDGET - k * bn - bn * 4, k * itemsize * 8)
+    budget_x = max(_vmem_budget() - k * bn - bn * 4, k * itemsize * 8)
     bm = pick_row_block(m, k * itemsize, budget_x, key="wo_int8")
     return bm, bn
 
@@ -175,10 +176,12 @@ def unpack_int4_halves(packed):
 
 def _wo4_kernel(x_ref, w_ref, slo_ref, shi_ref, olo_ref, ohi_ref):
     x = x_ref[...]
-    b = w_ref[...]                                   # [K, bn] packed bytes
-    # int8 ARITHMETIC shifts sign-extend the nibbles for free (no int32
-    # widening, no select): hi = b >> 4; lo = (b << 4) >> 4
-    lo = ((b << 4) >> 4).astype(x.dtype)   # wrap-around then sign-extend
+    # [K, bn] packed bytes, widened: the chip's Mosaic has no int8 vector
+    # shifts (`arith.shli` on vector<..xi8> fails to legalize). In int32
+    # the ARITHMETIC shifts sign-extend the nibbles with no select:
+    # hi = b >> 4; lo = (b << 28) >> 28
+    b = w_ref[...].astype(jnp.int32)
+    lo = ((b << 28) >> 28).astype(x.dtype)
     hi = (b >> 4).astype(x.dtype)
     acc_lo = jax.lax.dot_general(x, lo, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -192,18 +195,18 @@ def _wo4_kernel(x_ref, w_ref, slo_ref, shi_ref, olo_ref, ohi_ref):
 
 def _pick_blocks_int4(m, k, itemsize):
     """Like _pick_blocks but budgeted for the int4 kernel's in-VMEM
-    expansion: per packed byte the kernel holds the byte plus two
-    sign-extended int8 planes plus their activation-dtype casts
-    (~3 + 2*itemsize bytes). Returns (bm, bn) or None when even the
+    expansion: per packed byte the kernel holds the byte, its int32
+    widening, two sign-extended int32 planes and their activation-dtype
+    casts (~13 + 2*itemsize bytes). Returns (bm, bn) or None when even the
     smallest block cannot fit (caller falls back to the composite —
     better a loud trace-time decision than a Mosaic OOM at compile)."""
-    per_byte = 3 + 2 * itemsize
+    per_byte = 13 + 2 * itemsize
     bn = 256
     while k * bn * per_byte > 6 * 1024 * 1024 and bn > 128:
         bn //= 2
     if k * bn * per_byte > 6 * 1024 * 1024:
         return None
-    budget_x = max(_VMEM_BUDGET - k * bn * per_byte - 2 * bn * 4,
+    budget_x = max(_vmem_budget() - k * bn * per_byte - 2 * bn * 4,
                    k * itemsize * 8)
     bm = pick_row_block(m, k * itemsize, budget_x, key="wo_int4")
     return bm, bn

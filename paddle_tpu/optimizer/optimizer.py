@@ -110,8 +110,15 @@ class Optimizer:
         if key not in self._accumulators[name]:
             dt = dtype if dtype is not None else (
                 jnp.float32 if self._multi_precision else param._data.dtype)
-            acc = Tensor(jnp.full(param._data.shape, fill_value, dt))
-            # moments follow their parameter's sharding (ZeRO/semi-auto)
+            # moments follow their parameter's sharding (ZeRO/semi-auto),
+            # in placement and not only in annotation: created unplaced,
+            # every full-size moment of a sharded model landed on the first
+            # device (10 GB on one chip of four at Llama-8B widths)
+            placed = getattr(param._data, "sharding", None)
+            if placed is not None and len(placed.device_set) == 1:
+                placed = None       # one device: stay uncommitted
+            acc = Tensor(jnp.full(param._data.shape, fill_value, dt,
+                                  device=placed))
             acc._sharding_spec = param._sharding_spec
             self._accumulators[name][key] = acc
         return self._accumulators[name][key]

@@ -279,10 +279,7 @@ def _probe_pp(mesh, dims):
     from jax.sharding import PartitionSpec as P
 
     pp = int(dims.get("pp", 1))
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:                      # newer jax
-        from jax import shard_map
+    from jax import shard_map
 
     perm = [(i, (i + 1) % pp) for i in range(pp)]
 

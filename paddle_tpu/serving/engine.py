@@ -624,13 +624,15 @@ class LLMEngine:
                 "verify": one(VERIFY_PROGRAM)}
 
     def program_stats(self) -> dict:
-        """Trace/compile/retrace counts of THIS engine's two compiled
+        """Trace/compile/retrace counts of THIS engine's compiled
         programs — the jit telemetry labels are shared process-wide, so
         counts are deltas since engine construction (the bench's
-        zero-retrace proof reads this)."""
+        zero-retrace proof reads this) — and, under ``path``, which
+        kernel or composite each traced program's stages took."""
         raw = self._raw_program_stats()
-        return {prog: {k: v - self._prog_base[prog][k]
-                       for k, v in vals.items()}
+        return {prog: dict({k: v - self._prog_base[prog][k]
+                            for k, v in vals.items()},
+                           path=dict(self._sm.paths.get(prog, {})))
                 for prog, vals in raw.items()}
 
     def stats(self) -> dict:

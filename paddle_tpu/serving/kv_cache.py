@@ -483,6 +483,13 @@ def reference_paged_attention(q, k_cache, v_cache, pos):
                                       jnp.asarray(pos, jnp.int32))
 
 
+def paged_attention_path(q_shape, cache_shape, cache_dtype) -> str:
+    """Which path :func:`paged_attention` takes for these shapes."""
+    from ..ops.kernels import mmha_pallas
+    return "mmha_decode" if mmha_pallas.use_kernel(
+        q_shape, cache_shape, cache_dtype) else "composite"
+
+
 def paged_attention(q, k_cache, v_cache, pos, interpret=None):
     """Decode attention over a gathered paged cache, per-row positions.
 
@@ -500,8 +507,8 @@ def paged_attention(q, k_cache, v_cache, pos, interpret=None):
     if interpret is True:
         return mmha_pallas.mmha_decode(q, k_cache, v_cache, pos,
                                        interpret=True)
-    if interpret is None and mmha_pallas.use_kernel(
-            q.shape, k_cache.shape, k_cache.dtype):
+    if interpret is None and paged_attention_path(
+            q.shape, k_cache.shape, k_cache.dtype) == "mmha_decode":
         return mmha_pallas.mmha_decode(q, k_cache, v_cache, pos,
                                        interpret=kern.interpret_mode())
     return reference_paged_attention(q, k_cache, v_cache, pos)

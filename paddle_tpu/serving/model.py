@@ -88,6 +88,11 @@ class ServingModel:
         self.head_dim = cfg.head_dim
         self.max_pos = cfg.max_position_embeddings
         self.pool: kv_cache.PagePool | None = None
+        # {program: {stage: kernel or "composite"}} — which path each
+        # compiled program's stages took at trace time (the kernel gates
+        # below decide per shape; nothing falls back unrecorded)
+        self.paths: dict = {}
+        self._prog = ""
         # fused decode epilogue (block_fused_pallas.decode_epilogue) needs
         # the final norm + head EXPOSED as attributes so the last junction
         # can fold the norm in and the head skip its own; a model carrying
@@ -155,6 +160,9 @@ class ServingModel:
     @property
     def quantized(self) -> bool:
         return bool(self._qweights)
+
+    def _note(self, stage: str, path: str) -> None:
+        self.paths.setdefault(self._prog, {})[stage] = path
 
     # -- shared pieces -------------------------------------------------------
 
@@ -227,8 +235,11 @@ class ServingModel:
         the per-op loops below run byte-identically to before."""
         from ..core.flags import flag
         from ..ops.kernels import _common as kern
-        return (self._fused_block and kern.available()
-                and flag("use_pallas_kernels") and flag("use_fused_blocks"))
+        active = (self._fused_block and kern.available()
+                  and flag("use_pallas_kernels") and flag("use_fused_blocks"))
+        if not active:
+            self._note("junction", "composite")
+        return active
 
     def _fused_layer_active(self) -> bool:
         """Decode-layer mega-kernel gate: ``ServingConfig(
@@ -266,9 +277,11 @@ class ServingModel:
         from ..ops.kernels import block_fused_pallas as bfp
         eps = norm_mod._epsilon
         if bfp.use_kernel(tuple(x.shape), tuple(residual.shape)):
+            self._note("junction", "block_decode_epilogue")
             fn = lambda a, r, w: bfp.decode_epilogue(  # noqa: E731
                 a, r, w, eps, kern.interpret_mode())
         else:  # tiny batches below the kernel's amortization floor
+            self._note("junction", "composite")
             fn = lambda a, r, w: bfp.reference_fused_epilogue(  # noqa: E731
                 a, r, w, None, 0, 0.0, eps, None, "rms")
         return apply_multi(fn, x, residual, norm_mod.weight,
@@ -294,6 +307,7 @@ class ServingModel:
         carry position 0 and an all-trash table. Returns logits Tensor
         ``[B, vocab]`` for the NEXT position.
         """
+        self._prog = "decode"
         pool = self.pool
         ps = pool.page_size
         pos = positions._data.astype(jnp.int32)
@@ -316,8 +330,10 @@ class ServingModel:
                     tuple(pool.k._data.shape[1:]), int(tab.shape[1]), hd,
                     int(layer.mlp.gate_proj.weight.shape[1]),
                     pool.k._data.dtype) for layer in layers):
+                self._note("layer", "block_decode_layer")
                 return self._decode_forward_fused_layer(
                     tokens, pos, tab, page_ids, slots, sin, cos, b)
+            self._note("layer", "composite")
         fused = self._fused_active()
         x = self.model.embed_tokens(Tensor(tokens._data.reshape(b, 1)))
         hres = x
@@ -334,6 +350,8 @@ class ServingModel:
             pool.v._data = vp
             kc = kv_cache.gather_layer(kp, i, tab)
             vc = kv_cache.gather_layer(vp, i, tab)
+            self._note("attention", kv_cache.paged_attention_path(
+                q._data.shape, kc.shape, kc.dtype))
             out = kv_cache.paged_attention(q._data, kc, vc, pos)
             attn_out = self._linear(
                 "o", i, Tensor(out.reshape(b, 1,
@@ -420,6 +438,7 @@ class ServingModel:
         All shapes static; per-request variation rides in values — the
         compiled verify program NEVER retraces.
         """
+        self._prog = "verify"
         pool = self.pool
         ps = pool.page_size
         base = positions._data.astype(jnp.int32)              # [B]
@@ -494,6 +513,7 @@ class ServingModel:
         Returns logits Tensor ``[1, vocab]`` at position ``prompt_len-1``
         (the first generated token's distribution).
         """
+        self._prog = "prefill"
         pool = self.pool
         n = int(tokens.shape[1])
         plen = prompt_len._data.reshape(()).astype(jnp.int32)
@@ -557,6 +577,7 @@ class ServingModel:
         first generated token exactly like the monolithic program's
         ``logits[prompt_len - 1]``.
         """
+        self._prog = "chunk"
         pool = self.pool
         ps = pool.page_size
         n = int(tokens.shape[1])

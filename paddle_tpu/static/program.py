@@ -108,18 +108,8 @@ class Program:
     def __init__(self):
         import weakref
         _ALL_PROGRAMS.append(weakref.ref(self))
-        try:
-            self._dbg = api_util.debug_info("static_program", lambda *a: a,
-                                            (), {})
-        except TypeError:
-            # older jax (<=0.4.x) signature: (traced_for, src,
-            # fun_signature, args, kwargs, static_argnums, static_argnames).
-            # Static tracing itself needs the newer jax, but this module is
-            # imported by EVERY create_parameter call — a raise here bricks
-            # eager/jit param creation process-wide (the first import dies,
-            # later ones silently reuse the cached .program submodule)
-            self._dbg = api_util.debug_info("static_program", None, None,
-                                            (), {}, (), ())
+        self._dbg = api_util.debug_info("static_program", lambda *a: a,
+                                        (), {})
         self._trace = None
         self._ambient_cm = None       # entered set_current_trace context
         self._prev_tracker = None
@@ -352,7 +342,7 @@ class Program:
         # remaining consts become explicit per-call inputs too: leaving
         # them as closure constants makes jax hoist them as hidden jit
         # parameters, which breaks the C++ fastpath on repeat executions
-        # (buffer-count mismatch) in this jax version
+        # (buffer-count mismatch)
         jaxpr = jaxpr.replace(
             constvars=[],
             invars=lift_vars + kept_vars + list(jaxpr.invars))

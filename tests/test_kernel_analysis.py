@@ -148,68 +148,8 @@ def test_pk204_masked_tail_clean():
 
 
 # ---------------------------------------------------------------------------
-# PK205 — Mosaic numeric compat (jax 0.4.x)
+# PK206 — AST plane (pallas_call outside x64_off)
 # ---------------------------------------------------------------------------
-
-def test_pk205_mixed_scalar_mulf_flagged():
-    def fn(x):
-        def k(x_ref, o_ref):
-            s = x_ref[0, 0]             # ref-loaded: a 0-d VECTOR to Mosaic
-            o_ref[...] = x_ref[...] * (s * 2.0)   # 0-d vector x immediate
-        return pl.pallas_call(
-            k, grid=(1,),
-            in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
-            out_shape=S((8, 128), F32))(x)
-    rules, _, _ = _rules(fn, S((8, 128), F32))
-    assert "PK205" in rules
-
-
-def test_pk205_vector_times_loaded_scalar_clean():
-    # the adamw_pallas idiom: every multiply keeps a real vector operand,
-    # so the ref-loaded scalar broadcasts fine — must NOT be flagged
-    def fn(x):
-        def k(x_ref, o_ref):
-            s = x_ref[0, 0]
-            o_ref[...] = x_ref[...] * s
-        return pl.pallas_call(
-            k, grid=(1,),
-            in_specs=[pl.BlockSpec((8, 128), lambda i: (0, 0))],
-            out_specs=pl.BlockSpec((8, 128), lambda i: (0, 0)),
-            out_shape=S((8, 128), F32))(x)
-    rules, _, _ = _rules(fn, S((8, 128), F32))
-    assert "PK205" not in rules
-
-
-def test_pk205_int8_dot_flagged():
-    def fn(a, b):
-        def k(a_ref, b_ref, o_ref):
-            o_ref[...] = jax.lax.dot_general(
-                a_ref[...], b_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.int32)
-        ident = lambda i: (0, 0)
-        return pl.pallas_call(
-            k, grid=(1,),
-            in_specs=[pl.BlockSpec((8, 128), ident),
-                      pl.BlockSpec((128, 128), ident)],
-            out_specs=pl.BlockSpec((8, 128), ident),
-            out_shape=S((8, 128), jnp.int32))(a, b)
-    rules, _, _ = _rules(fn, S((8, 128), jnp.int8), S((128, 128), jnp.int8))
-    assert "PK205" in rules
-
-
-# ---------------------------------------------------------------------------
-# PK206 — AST plane (jnp.pad in body, pallas_call outside x64_off)
-# ---------------------------------------------------------------------------
-
-def test_pk206_jnp_pad_in_kernel_body_flagged():
-    src = (
-        "import jax.numpy as jnp\n"
-        "def _k(x_ref, o_ref):\n"
-        "    o_ref[...] = jnp.pad(x_ref[...], ((0, 1), (0, 0)))\n")
-    fs = check_source(src, "fix.py")
-    assert any(f.rule_id == "PK206" and "pad" in f.message for f in fs)
-
 
 def test_pk206_pallas_call_outside_x64_off_flagged():
     src = (
@@ -375,7 +315,7 @@ def test_demo_trips_every_error_rule():
     demo = os.path.join(REPO, "paddle_tpu", "analysis", "kernels", "demo.py")
     fs = analyze_paths([demo])
     errs = {f.rule_id for f in fs if f.severity == ERROR}
-    assert {"PK200", "PK201", "PK202", "PK203", "PK205", "PK206"} <= errs
+    assert {"PK200", "PK201", "PK202", "PK203", "PK206"} <= errs
 
 
 # ---------------------------------------------------------------------------
@@ -383,7 +323,6 @@ def test_demo_trips_every_error_rule():
 # ---------------------------------------------------------------------------
 
 def test_mmha_sheet_matches_infile_budget():
-    from paddle_tpu.ops.kernels import mmha_pallas
     cost = kernel_cost("paddle_tpu.ops.kernels.mmha_pallas")
     sheet = next(s for s in cost["kernels"] if s["kernel"] == "_mmha_kernel")
     # pk_examples decode shape: q/o blocks (1,1,8,128) bf16, k/v blocks
@@ -391,10 +330,11 @@ def test_mmha_sheet_matches_infile_budget():
     kv = 2 * 2048 * 128 * 2
     assert sheet["block_bytes"] == kv + 2 * 8 * 128 * 2
     # the in-file dispatch gate budgets exactly the k+v residency
-    # (use_kernel: 2*t*d*itemsize <= _VMEM_BYTES); the analyzer's total
-    # adds q/o blocks + body intermediates — within 25% of the gated
-    # quantity at decode shapes (q/o are tiny next to the cache)
-    assert kv <= mmha_pallas._VMEM_BYTES
+    # (use_kernel: 2*t*d*itemsize <= chip_vmem_bytes() // 2); the
+    # analyzer's total adds q/o blocks + body intermediates — within 25%
+    # of the gated quantity at decode shapes (q/o are tiny next to the
+    # cache)
+    assert kv <= chip_vmem_bytes() // 2
     assert kv <= sheet["vmem_bytes"] <= int(kv * 1.25)
     assert sheet["fits_vmem"]
     assert cost["vmem_budget"] == chip_vmem_bytes()

@@ -287,18 +287,22 @@ def test_eviction_reclaims_pages_and_recovers():
 
 
 def test_eos_completes_early_and_pads_nothing():
-    paddle.seed(15)
+    paddle.seed(16)
     model = _model()
     eng = _engine(model, max_new_tokens=12)
     ref = eng.generate([3, 1, 4], timeout=300)
-    eos = ref[2]                       # force an early stop on token #3
+    # stop on token #3 — or earlier when the random model's greedy stream
+    # repeats a token before then (the stream depends on the RNG, the
+    # contract under test does not)
+    stop = max(i for i in range(3) if ref[i] not in ref[:i])
+    eos = ref[stop]
     eng2 = _engine(model, max_new_tokens=12, eos_token_id=eos)
     try:
         got = eng2.generate([3, 1, 4], timeout=300)
     finally:
         eng.shutdown()
         eng2.shutdown()
-    assert got == ref[:3]
+    assert got == ref[:stop + 1]
     assert got[-1] == eos
 
 

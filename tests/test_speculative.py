@@ -34,6 +34,17 @@ def _model(**kw):
     return llama_tiny(**cfg)
 
 
+def _ngram_hits(prompt, out, k=4):
+    """Drafts of the n-gram drafter whose first token the greedy stream
+    `out` then confirms — how predictable a random model's stream is."""
+    d, hist, n = NgramDrafter(), list(prompt), 0
+    for t in out:
+        p = d.propose(hist, k)
+        n += bool(p) and p[0] == t
+        hist.append(t)
+    return n
+
+
 def _engine(model=None, **kw):
     cfg = dict(page_size=8, num_pages=17, max_batch=2, max_new_tokens=6)
     cfg.update(kw)
@@ -166,11 +177,15 @@ def test_temperature_rejection_sampling_distribution_chisq():
 def test_greedy_spec_on_off_generate_token_exact():
     """THE speculative contract: greedy spec-on == spec-off ==
     model.generate, while drafts actually land."""
-    paddle.seed(11)
+    # seed picked so the random model's greedy stream repeats itself
+    # (jax's default RNG stream, threefry-partitionable, decides which do)
+    paddle.seed(17)
     model = llama_tiny()                     # vocab 512, pos 128
     prompt = [5, 9, 11, 2, 7]
     ref = model.generate(np.asarray([prompt]), max_new_tokens=24)
     expect = [int(t) for t in ref[0, len(prompt):]]
+    assert _ngram_hits(prompt, expect) >= 1, \
+        "this seed's greedy stream gives the drafter nothing: pick another"
     off = _engine(model, page_size=16, num_pages=33, max_new_tokens=24,
                   spec_k=0)
     on = _engine(model, page_size=16, num_pages=33, max_new_tokens=24,
@@ -488,7 +503,7 @@ def test_multi_token_accounting_counts_tokens_not_steps():
     """Fix satellite: `paddle_tpu_serving_tokens_total{kind=generated}`
     and the TPOT samples must count ACCEPTED TOKENS, not engine
     iterations, when a verify step emits a burst."""
-    paddle.seed(49)
+    paddle.seed(52)       # a seed whose greedy stream repeats (see above)
     tok0 = obs.value("paddle_tpu_serving_tokens_total", kind="generated")
     eng = _engine(_model(), page_size=8, num_pages=33, max_new_tokens=12,
                   spec_k=4)
@@ -499,6 +514,8 @@ def test_multi_token_accounting_counts_tokens_not_steps():
         steps = eng.scheduler.decode_steps
     finally:
         eng.shutdown()
+    assert _ngram_hits([8, 6, 8, 6, 8], got) >= 1, \
+        "this seed's greedy stream gives the drafter nothing: pick another"
     assert spec["accepted_tokens"] >= 1      # bursts actually happened
     assert steps < len(got)                  # fewer steps than tokens
     delta = obs.value("paddle_tpu_serving_tokens_total",

@@ -39,7 +39,7 @@ def test_fused_allreduce_matches_per_param_and_drops_collectives():
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     devs = np.array(jax.devices("cpu")[:8])
