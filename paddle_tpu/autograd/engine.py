@@ -67,7 +67,8 @@ def run_backward(tensors, grad_tensors=None, retain_graph=False, create_graph=Fa
         return {}
 
     from ..profiler.profiler import host_self_span
-    with host_self_span("backward_engine(host)"):
+    with host_self_span("backward_engine(host)"), \
+            jax.named_scope("backward"):
         return _run_backward_impl(tensors, grad_tensors, retain_graph,
                                   create_graph, inputs, accumulate_leaf,
                                   allow_unused)

@@ -18,6 +18,7 @@ import numpy as np
 
 from ..observability import (enabled as _obs_enabled,
                              histogram as _obs_histogram)
+from ..observability import tracing as _tracing
 from .dataset import Dataset, IterableDataset
 from .sampler import BatchSampler
 
@@ -310,7 +311,7 @@ class DataLoader:
     def __iter__(self):
         it = self._checkpointable_iter() if self._checkpointable \
             else self._plain_iter()
-        if not _obs_enabled():
+        if not _obs_enabled() and not _tracing.tracing_enabled():
             yield from it
             return
         # wait/compute split: time blocked in next() is loader wait; time
@@ -319,10 +320,11 @@ class DataLoader:
         prev_yield = None
         while True:
             t0 = time.perf_counter()
-            try:
-                batch = next(it)
-            except StopIteration:
-                return
+            with _tracing.span("io.next"):
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    return
             now = time.perf_counter()
             _OBS_WAIT.observe(now - t0)
             if prev_yield is not None:

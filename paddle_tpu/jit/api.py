@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import gc
 import os
+import re
 import threading
 import time
 import weakref
@@ -35,6 +36,7 @@ from ..core.tensor import Tensor
 from ..observability import counter as _obs_counter, gauge as _obs_gauge
 from ..observability import continuous as _cont
 from ..observability import flight as _flight
+from ..observability import tracing as _tracing
 
 __all__ = ["to_static", "not_to_static", "in_to_static_trace", "ignore_module",
            "enable_to_static"]
@@ -164,6 +166,12 @@ class _Tracker:
         return [t for t in (r() for r in self._order) if t is not None]
 
 
+#: an instruction of optimized HLO text with its name and `op_name`
+_HLO_OP_NAME = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+) = .*?metadata=\{[^}]*?op_name="([^"]*)"',
+    re.M)
+
+
 def _is_floatlike(x):
     return isinstance(x, (Tensor, jax.Array)) or hasattr(x, "__array__")
 
@@ -191,6 +199,11 @@ class StaticFunction:
         # share a bare __name__ (every Layer's 'forward')
         self._obs_name = getattr(fn, "__qualname__", None) or \
             getattr(fn, "__name__", "fn")
+        # the compiled program's name: XLA modules read
+        # `jit_pure_arrays__<this>`, host dispatches
+        # `PjitFunction(pure_arrays__<this>)`
+        self._program_name = "pure_arrays__" + re.sub(
+            r"[^A-Za-z0-9_]", "_", self._obs_name)
         self._segmented: set = set()    # signature keys compiled in segments
         self._seg_cache: dict = {}
         wraps(fn)(self)
@@ -282,6 +295,7 @@ class StaticFunction:
                 if donate or n is not a]
             return [new_state[i] for i in cell["written"]], out_flat
 
+        pure_arrays.__name__ = pure_arrays.__qualname__ = self._program_name
         jitted = jax.jit(pure_arrays,
                          donate_argnums=(0,) if self._donate else ())
         return jitted, cell
@@ -400,7 +414,8 @@ class StaticFunction:
                 _OBS_RETRACES.inc(fn=fn_name)
             _OBS_MISSES.inc(fn=fn_name)
             t0 = time.perf_counter()
-            out = self._discover(args, kwargs)
+            with _tracing.span("jit.run", fn=fn_name, phase="eager"):
+                out = self._discover(args, kwargs)
             dt = time.perf_counter() - t0
             _OBS_TRACE_SECONDS.inc(dt, fn=fn_name)
             self._state_by_key[key] = list(self._state)
@@ -412,7 +427,18 @@ class StaticFunction:
             return out
         _OBS_HITS.inc(fn=fn_name)
         entry = self._cache.get(key)
-        if entry is None:
+        with _tracing.span("jit.run", fn=fn_name,
+                           phase="run" if entry is not None else "compile"):
+            return self._call_compiled(key, entry, treedef, sig, kwargs,
+                                       args, arg_arrays)
+
+    def _call_compiled(self, key, entry, treedef, sig, kwargs, args,
+                       arg_arrays):
+        """Run the compiled program of a discovered signature, building it
+        first where `entry` (its cache entry) is None."""
+        fn_name = self._obs_name
+        fresh = entry is None
+        if fresh:
             state_list = self._state_by_key[key]
             t0 = time.perf_counter()
             jitted, cell = self._compile(treedef, sig, dict(kwargs),
@@ -432,7 +458,10 @@ class StaticFunction:
         self._last_key = key
         jitted, cell, state_list = entry
         try:
-            return self._run_compiled(jitted, cell, state_list, arg_arrays)
+            out = self._run_compiled(jitted, cell, state_list, arg_arrays)
+            if fresh and _tracing.tracing_enabled():
+                self._note_program(jitted, cell)
+            return out
         except (jax.errors.TracerBoolConversionError,
                 jax.errors.TracerIntegerConversionError,
                 jax.errors.TracerArrayConversionError,
@@ -453,9 +482,22 @@ class StaticFunction:
                 f"to_static: {getattr(self._fn, '__name__', self._fn)!r} "
                 "uses data-dependent Python control flow; compiling in "
                 "SEGMENTS around the graph break (SOT analog). Cause: "
-                f"{type(e).__name__}", UserWarning, stacklevel=2)
+                f"{type(e).__name__}", UserWarning, stacklevel=3)
             return self._call_segmented(key, treedef, kwargs, args,
                                         arg_arrays)
+
+    def _note_program(self, jitted, cell):
+        """With tracing on, hand the tracer the table that gives each
+        instruction of the program that just ran the name of the code that
+        made it (`jax.named_scope` path included): a device trace names
+        the instruction alone. The lowering and its executable are the
+        ones jax cached for the call."""
+        try:
+            text = jitted.lower(*cell["avals"]).compile().as_text()
+        except Exception:   # noqa: BLE001 - a table less, never a step less
+            return
+        _tracing.note_program("jit_" + self._program_name,
+                              dict(_HLO_OP_NAME.findall(text)))
 
     def _run_compiled(self, jitted, cell, state_list, arg_arrays):
         # NOTE the donation contract: Tensors aliasing state from OUTSIDE

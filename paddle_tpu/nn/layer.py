@@ -8,9 +8,11 @@ hook API.
 
 from __future__ import annotations
 
+import threading
 from collections import OrderedDict
 from typing import Any, Callable, Iterator
 
+import jax
 import numpy as np
 
 from ..core import dtype as dtypes
@@ -18,6 +20,8 @@ from ..core.tensor import Tensor, Parameter
 from ..framework.parameter import ParamAttr
 
 __all__ = ["Layer"]
+
+_calls = threading.local()      # .depth: Layer calls open on this thread
 
 
 class _HookHandle:
@@ -219,6 +223,19 @@ class Layer:
             f"{self.__class__.__name__} must implement forward()")
 
     def __call__(self, *inputs, **kwargs):
+        if getattr(_calls, "depth", 0):
+            return self._call_impl(*inputs, **kwargs)
+        # the outermost call names the device ops made under it `forward`
+        # (metadata only). One scope per model call, not one per layer: the
+        # eager discovery step of `to_static` calls layers by the thousand
+        _calls.depth = 1
+        try:
+            with jax.named_scope("forward"):
+                return self._call_impl(*inputs, **kwargs)
+        finally:
+            _calls.depth = 0
+
+    def _call_impl(self, *inputs, **kwargs):
         for hook in list(self._forward_pre_hooks.values()):
             if hook is None:
                 continue

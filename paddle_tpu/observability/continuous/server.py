@@ -254,9 +254,10 @@ class _Handler(BaseHTTPRequestHandler):
                               "stats": tr.stats()})
 
     def _trace(self, trace_id, _q):
-        """Span tree of one trace (completed reservoir or in-flight)."""
+        """Span tree of one trace (completed reservoir or in-flight), in
+        wall-clock times: it leaves the process here."""
         from .. import tracing
-        snap = tracing.get_trace(trace_id)
+        snap = tracing.get_trace(trace_id, wall=True)
         if snap is None:
             self._send_json(404, {"error": f"unknown trace id "
                                            f"{trace_id!r}"})

@@ -1,7 +1,25 @@
-"""Per-request distributed tracing for the serving path (ISSUE 16).
+"""Request traces and step spans: the program's own tracing.
 
-The metrics registry, flight recorder and continuous profiler are all
-step- and program-centric; this package adds the request axis: every
+Two axes, one switch (``PADDLE_TPU_TRACE`` / :func:`enable`), one clock.
+
+**Step spans** (:func:`span`, :func:`step_spans`): a process-wide, bounded,
+drop-oldest buffer of named intervals at the layer boundaries of the
+program (``serving.step``, ``serving.decode``, ``engine.dispatch``,
+``jit.run``, ``io.next``, ...; :data:`STEP_SPANS`). A span records its
+name, start, end, its own id, the id of the span that caused it (the one
+open on the thread when it began), a small dict of counts and a few
+attributes. While tracing is on every step span is also a
+``jax.profiler.TraceAnnotation("paddle_tpu/<name>")``, so in any profiler
+trace the spans lie on the profiler's own time axis over the device ops.
+
+**Clock.** Every span, of a request or of a step, is stamped with
+``time.perf_counter()``: monotonic, and the clock a benchmark stamps its
+own latencies with. What shows wall-clock times (:func:`to_chrome_trace`,
+the request log, the flight snapshot) converts with the one
+``(time.time(), time.perf_counter())`` pair taken at import
+(:func:`to_wall`).
+
+**Request traces** (ISSUE 16). The request axis: every
 ``LLMEngine.submit`` opens a **root span** carrying a 128-bit trace id,
 and the scheduler emits **child spans** for each lifecycle stage (queue
 wait, admission, prefill chunks, burst-aggregated decode/speculate
@@ -17,11 +35,12 @@ Design rules (shared with the rest of the observability stack):
   singleton whose methods return :data:`NOOP_SPAN`; hot call sites guard
   with an identity check (``trace is NOOP_TRACE``) so the disabled cost
   is one pointer comparison;
-* **measured overhead** — the tracer self-times its span-append path
-  (``stats()["cost_s"]``); ``bench.py serve`` folds that into
-  ``extra.serve.tracing.overhead_pct`` and ``tools/perf_gate.py``
-  soft-gates it (``PERF_GATE_TRACE_TOL_PCT``, default 1%);
-* **bounded everywhere** — per-request span buffer
+* **cost measured from outside** — the tracer does not time itself; what
+  tracing costs is the difference between a run with it on and one with
+  it off (PERF.md);
+* **bounded everywhere** — the step-span buffer
+  (:data:`STEP_CAPACITY`), the compiled programs' tables
+  (:data:`PROGRAM_VARIANTS` a name), per-request span buffer
   (``PADDLE_TPU_TRACE_SPANS``), completed-trace reservoir
   (``PADDLE_TPU_TRACE_RESERVOIR``), request-log ring
   (``PADDLE_TPU_TRACE_REQUESTS``) and the live-trace table all evict
@@ -44,8 +63,10 @@ unmatched-span convention the flight exporter uses for death spans.
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import threading
 import time
 from collections import OrderedDict, deque
 
@@ -60,6 +81,11 @@ __all__ = [
     "get_tracer",
     "tracing_enabled",
     "enable",
+    "to_wall",
+    "span",
+    "step_spans",
+    "note_program",
+    "programs",
     "start_request",
     "get_trace",
     "requests",
@@ -79,6 +105,27 @@ TRACEPARENT_VERSION = "00"
 #: child-span names the serving path emits (the docs' span kinds)
 SPAN_KINDS = ("queue_wait", "admit", "prefill", "prefill_chunk", "decode",
               "speculate", "evict", "cow", "stream")
+
+#: step-span names the program emits, by the module that opens them
+STEP_SPANS = ("serving.step", "serving.admit", "serving.prefill",
+              "serving.prefill_chunk", "serving.decode", "serving.verify",
+              "serving.emit", "engine.upload", "engine.dispatch",
+              "engine.pull", "jit.run", "io.next")
+
+#: step spans the buffer holds before it drops the oldest: some two hours
+#: of decode steps at nine spans each
+STEP_CAPACITY = 65536
+
+#: instruction tables kept for one program name (a signature each)
+PROGRAM_VARIANTS = 16
+
+# the one pair that ties the span clock to the wall clock
+_WALL0, _PERF0 = time.time(), time.perf_counter()
+
+
+def to_wall(t):
+    """A span's stamp (``time.perf_counter()``) as seconds since the epoch."""
+    return None if t is None else float(t) - _PERF0 + _WALL0
 
 
 def _env_int(name, default):
@@ -154,7 +201,8 @@ class Span:
         self.name = name
         self.span_id = _gen_span_id()
         self.parent_id = parent_id
-        self.t_start = time.time() if t_start is None else float(t_start)
+        self.t_start = time.perf_counter() if t_start is None \
+            else float(t_start)
         self.t_end = None
         self.attributes = dict(attributes) if attributes else {}
         self._trace = _trace
@@ -178,10 +226,13 @@ class Span:
             self.attributes["error"] = repr(exc)
         self.end()
 
-    def to_dict(self) -> dict:
+    def to_dict(self, wall: bool = False) -> dict:
+        """``wall=True`` converts the stamps for a reader of wall-clock
+        times (a flight dump)."""
+        conv = to_wall if wall else (lambda t: t)
         d = {"name": self.name, "span_id": self.span_id,
-             "parent_id": self.parent_id, "t_start": self.t_start,
-             "t_end": self.t_end}
+             "parent_id": self.parent_id, "t_start": conv(self.t_start),
+             "t_end": conv(self.t_end)}
         if self.attributes:
             d["attributes"] = dict(self.attributes)
         return d
@@ -202,6 +253,9 @@ class _NoopSpan:
     def set(self, **attrs):
         return self
 
+    def count(self, **counts):
+        return self
+
     def end(self, t_end=None, **attrs):
         return None
 
@@ -211,7 +265,7 @@ class _NoopSpan:
     def __exit__(self, exc_type, exc, tb):
         return None
 
-    def to_dict(self):
+    def to_dict(self, wall=False):
         return {}
 
 
@@ -265,7 +319,6 @@ class RequestTrace:
         self._spans: list[Span] = []      # finished children, bounded
         self._open: dict[str, Span] = {}  # span_id -> open child
         self._dropped = 0
-        self._cost_s = 0.0
         self._finished = False
 
     # -- span lifecycle -------------------------------------------------
@@ -275,7 +328,6 @@ class RequestTrace:
 
     def span(self, name, parent=None, t_start=None, **attrs) -> Span:
         """Open a child span (ended via ``.end()`` / context manager)."""
-        t0 = time.perf_counter()
         parent_id = parent.span_id if parent is not None else self.root.span_id
         s = Span(name, parent_id=parent_id, t_start=t_start,
                  attributes=attrs or None, _trace=self)
@@ -286,12 +338,10 @@ class RequestTrace:
                 s._trace = None  # still usable, just not recorded
             else:
                 self._open[s.span_id] = s
-            self._cost_s += time.perf_counter() - t0
         return s
 
     def add_span(self, name, t_start, t_end, parent=None, **attrs) -> Span:
         """Record an already-timed span in one call (burst flushes)."""
-        t0 = time.perf_counter()
         parent_id = parent.span_id if parent is not None else self.root.span_id
         s = Span(name, parent_id=parent_id, t_start=t_start,
                  attributes=attrs or None, _trace=None)
@@ -301,12 +351,10 @@ class RequestTrace:
                 self._dropped += 1
             else:
                 self._spans.append(s)
-            self._cost_s += time.perf_counter() - t0
         return s
 
     def _end_span(self, span: Span, t_end=None) -> None:
-        t0 = time.perf_counter()
-        end = time.time() if t_end is None else float(t_end)
+        end = time.perf_counter() if t_end is None else float(t_end)
         with self._lock:
             if span.t_end is None:
                 span.t_end = end
@@ -316,13 +364,11 @@ class RequestTrace:
                 self._spans.append(span)
             elif live is not None:
                 self._dropped += 1
-            self._cost_s += time.perf_counter() - t0
 
     def finish(self, state="completed", **fields) -> dict | None:
         """Close the root span, build the request record and hand the
         trace to the tracer's reservoir + request log. Idempotent."""
-        t0 = time.perf_counter()
-        now = time.time()
+        now = time.perf_counter()
         with self._lock:
             if self._finished:
                 return None
@@ -340,24 +386,22 @@ class RequestTrace:
             self._open.clear()
             spans = list(self._spans)
             dropped = self._dropped
-            self._cost_s += time.perf_counter() - t0
-            cost_s = self._cost_s
         record = self._build_record(state, spans, dropped, fields)
         # tracer lock taken strictly after the trace lock was released:
         # the two lock classes are never nested in either order
-        self._tracer._complete(self, record, len(spans), cost_s)
+        self._tracer._complete(self, record, len(spans))
         return record
 
     # -- introspection --------------------------------------------------
     def _build_record(self, state, spans, dropped, fields) -> dict:
         root = self.root
-        e2e_s = (root.t_end or time.time()) - root.t_start
-        record = {
+        e2e_s = (root.t_end or time.perf_counter()) - root.t_start
+        record = {      # a line of the request log: wall-clock stamps
             "trace_id": self.trace_id,
             "request_id": self.request_id,
             "state": state,
-            "t_start": root.t_start,
-            "t_end": root.t_end,
+            "t_start": to_wall(root.t_start),
+            "t_end": to_wall(root.t_end),
             "e2e_ms": round(e2e_s * 1000.0, 3),
             "spans": len(spans),
             "dropped_spans": dropped,
@@ -377,12 +421,14 @@ class RequestTrace:
         return record
 
     def snapshot(self) -> dict:
-        """Full span tree (finished + still-open children)."""
+        """Full span tree (finished + still-open children), stamps on
+        the span clock (``"clock": "perf_counter"``; :func:`to_wall`)."""
         with self._lock:
             spans = [s.to_dict() for s in self._spans]
             open_ = [s.to_dict() for s in self._open.values()]
             dropped = self._dropped
         d = {"trace_id": self.trace_id, "request_id": self.request_id,
+             "clock": "perf_counter",
              "root": self.root.to_dict(), "spans": spans}
         if open_:
             d["open"] = open_
@@ -393,13 +439,14 @@ class RequestTrace:
     def open_spans(self) -> list[dict]:
         """Spans without an end time (root included while unfinished),
         each stamped with trace/request ids — this is what a flight dump
-        carries for an in-flight request at death."""
+        carries for an in-flight request at death, so its stamps are
+        wall-clock times."""
         out = []
         with self._lock:
             if self._finished:
                 return out
             for s in [self.root] + list(self._open.values()):
-                d = s.to_dict()
+                d = s.to_dict(wall=True)
                 d["trace_id"] = self.trace_id
                 d["request_id"] = self.request_id
                 out.append(d)
@@ -409,7 +456,7 @@ class RequestTrace:
 def _coverage(root, spans) -> float:
     """Fraction of the root span's wall covered by the union of child
     span intervals (the bench's span-coverage acceptance stat)."""
-    t0, t1 = root.t_start, root.t_end or time.time()
+    t0, t1 = root.t_start, root.t_end or time.perf_counter()
     if t1 <= t0:
         return 1.0 if spans else 0.0
     ivals = []
@@ -433,13 +480,135 @@ def _coverage(root, spans) -> float:
     return min(1.0, covered / (t1 - t0))
 
 
+# ---------------------------------------------------------------------------
+# Step spans
+
+_step_ids = itertools.count(1)
+_thread = threading.local()       # .cur: the step span open on this thread
+_ANNOTATION = None                # jax.profiler.TraceAnnotation, or False
+
+
+def _annotation():
+    """The profiler's annotation class, looked up at the first span (this
+    package imports nothing but the standard library at import)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        try:
+            from jax.profiler import TraceAnnotation
+            _ANNOTATION = TraceAnnotation
+        except Exception:       # noqa: BLE001 - no jax: spans without them
+            _ANNOTATION = False
+    return _ANNOTATION
+
+
+class StepSpan:
+    """One interval of the program's own work, open from its creation to
+    ``end()`` (or the end of its ``with`` block). The span open on the
+    thread when it began is its cause (``parent_id``)."""
+
+    __slots__ = ("name", "span_id", "parent_id", "t_start", "t_end",
+                 "counts", "attributes", "_buffer", "_prev", "_ann")
+
+    def __init__(self, buffer, name, attributes):
+        self.name = name
+        self.span_id = next(_step_ids)
+        self._buffer = buffer
+        self._prev = prev = getattr(_thread, "cur", None)
+        self.parent_id = prev.span_id if prev is not None else None
+        self.counts = {}
+        self.attributes = attributes
+        self.t_end = None
+        _thread.cur = self
+        ann = _annotation()
+        self._ann = ann("paddle_tpu/" + name) if ann else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t_start = time.perf_counter()
+
+    def count(self, **counts) -> "StepSpan":
+        self.counts.update(counts)
+        return self
+
+    def set(self, **attrs) -> "StepSpan":
+        self.attributes.update(attrs)
+        return self
+
+    def end(self) -> None:
+        if self.t_end is not None:
+            return
+        self.t_end = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if getattr(_thread, "cur", None) is self:
+            _thread.cur = self._prev
+        self._prev = None
+        self._buffer.push(self)
+
+    def __enter__(self) -> "StepSpan":
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        if exc is not None:
+            self.attributes.setdefault("error", repr(exc))
+        self.end()
+
+    def to_dict(self) -> dict:
+        return {"name": self.name, "span_id": self.span_id,
+                "parent_id": self.parent_id, "t_start": self.t_start,
+                "t_end": self.t_end, "counts": dict(self.counts),
+                "attributes": dict(self.attributes)}
+
+
+class _StepBuffer:
+    """Finished step spans, newest kept: a bounded ring that counts what it
+    drops and remembers the latest start among the dropped, so a reader
+    knows whether an interval it asks for is whole. The lock is a leaf."""
+
+    def __init__(self, capacity):
+        self.capacity = max(1, int(capacity))
+        self._lock = _tsan.lock("observability.tracing.StepBuffer")
+        self._spans: deque = deque(maxlen=self.capacity)
+        self._dropped = 0
+        self._dropped_until = None
+
+    def push(self, span) -> None:
+        with self._lock:
+            if len(self._spans) == self.capacity:
+                old = self._spans[0]
+                self._dropped += 1
+                if self._dropped_until is None or \
+                        old.t_start > self._dropped_until:
+                    self._dropped_until = old.t_start
+            self._spans.append(span)
+
+    def read(self, since=None, until=None) -> dict:
+        with self._lock:
+            spans = list(self._spans)
+            dropped, dropped_until = self._dropped, self._dropped_until
+        return {"spans": [s.to_dict() for s in spans
+                          if (since is None or s.t_start >= since)
+                          and (until is None or s.t_start <= until)],
+                "dropped": dropped, "dropped_until": dropped_until}
+
+    def __len__(self):
+        return len(self._spans)
+
+    def reset(self) -> None:
+        with self._lock:
+            self._spans.clear()
+            self._dropped = 0
+            self._dropped_until = None
+
+
 class Tracer:
-    """Process-global trace collector: live traces, a sampled reservoir
-    of completed traces, a ring of request-log records and histogram
-    exemplars. All state behind one leaf lock."""
+    """Process-global trace collector: the step-span buffer, live request
+    traces, a sampled reservoir of completed traces, a ring of request-log
+    records and histogram exemplars. Request state behind one leaf lock,
+    the step buffer behind its own."""
 
     def __init__(self, enabled=None, max_spans=None, reservoir=None,
-                 log_capacity=None, sample_every=None):
+                 log_capacity=None, sample_every=None, step_capacity=None):
         if enabled is None:
             enabled = os.environ.get("PADDLE_TPU_TRACE", "1") != "0"
         self.enabled = bool(enabled)
@@ -453,6 +622,11 @@ class Tracer:
         #: log line is always written); deterministic counter sampling
         self.sample_every = max(1, sample_every if sample_every is not None
                                 else _env_int("PADDLE_TPU_TRACE_SAMPLE", 1))
+        self._steps = _StepBuffer(
+            step_capacity if step_capacity is not None else STEP_CAPACITY)
+        #: {module name: {"variants": [{instruction: op_name}, ...],
+        #: "dropped": n}} of the compiled programs (:meth:`note_program`)
+        self._programs: "OrderedDict[str, dict]" = OrderedDict()
         self._lock = _tsan.lock("observability.tracing.Tracer")
         self._live: "OrderedDict[str, RequestTrace]" = OrderedDict()
         self._live_capacity = max(64, self.reservoir_capacity * 4)
@@ -462,7 +636,52 @@ class Tracer:
         self._completions = 0
         self._spans_total = 0
         self._dropped_live = 0
-        self._cost_s = 0.0
+
+    # -- step spans -------------------------------------------------------
+    def span(self, name, **attrs):
+        """Open a step span (``with`` it, or call its ``end()``):
+        :data:`NOOP_SPAN` after one flag check when tracing is off.
+        ``attrs`` are its attributes; ``.count(...)`` sets counts."""
+        if not self.enabled:
+            return NOOP_SPAN
+        return StepSpan(self._steps, name, attrs)
+
+    def step_spans(self, since=None, until=None) -> dict:
+        """``{"spans": [...], "dropped": n, "dropped_until": t}``: the
+        finished step spans whose start lies in ``[since, until]`` (span
+        clock; None = open end), as dicts; how many spans the buffer has
+        dropped since the process began (or the last :meth:`reset`); and
+        the latest start among those, None if none was dropped. An
+        interval that begins after ``dropped_until`` is whole."""
+        return self._steps.read(since, until)
+
+    def note_program(self, module: str, ops: dict) -> None:
+        """Remember, for one compiled program, which code made each of its
+        instructions: ``ops`` maps an instruction's name in the optimized
+        HLO to its ``op_name`` (the jit and ``jax.named_scope`` path). A
+        device trace names only the instruction; with this table a reader
+        gives it the scope. Programs of one name (a signature each) are
+        kept side by side, the newest :data:`PROGRAM_VARIANTS` of them,
+        and the tables dropped are counted: a reader then knows that the
+        name's tables no longer cover what ran. The oldest names go
+        first."""
+        with self._lock:
+            entry = self._programs.setdefault(
+                module, {"variants": [], "dropped": 0})
+            entry["variants"].append(dict(ops))
+            if len(entry["variants"]) > PROGRAM_VARIANTS:
+                del entry["variants"][0]
+                entry["dropped"] += 1
+            self._programs.move_to_end(module)
+            while len(self._programs) > 64:
+                self._programs.popitem(last=False)
+
+    def programs(self) -> dict:
+        """``{module: {"variants": [table, ...], "dropped": n}}``."""
+        with self._lock:
+            return {m: {"variants": list(e["variants"]),
+                        "dropped": e["dropped"]}
+                    for m, e in self._programs.items()}
 
     # -- request lifecycle ----------------------------------------------
     def start_request(self, request_id=None, traceparent=None, **attrs):
@@ -481,12 +700,11 @@ class Tracer:
                 self._dropped_live += 1
         return tr
 
-    def _complete(self, tr, record, n_spans, cost_s) -> None:
+    def _complete(self, tr, record, n_spans) -> None:
         with self._lock:
             self._live.pop(tr.trace_id, None)
             self._completions += 1
             self._spans_total += n_spans
-            self._cost_s += cost_s
             self._log.append(record)
             if (self._completions - 1) % self.sample_every == 0:
                 self._reservoir[tr.trace_id] = None  # snapshot outside lock
@@ -501,16 +719,18 @@ class Tracer:
                     self._reservoir[tr.trace_id] = snap
 
     # -- lookups ---------------------------------------------------------
-    def get_trace(self, trace_id) -> dict | None:
-        """Span tree for a trace id: completed (reservoir) or live."""
+    def get_trace(self, trace_id, wall: bool = False) -> dict | None:
+        """Span tree for a trace id: completed (reservoir) or live.
+        ``wall=True`` gives the stamps as wall-clock times, for a reader
+        outside the process."""
         with self._lock:
             snap = self._reservoir.get(trace_id)
             live = self._live.get(trace_id)
-        if snap is not None:
-            return snap
-        if live is not None:
-            return live.snapshot()
-        return None
+        if snap is None and live is not None:
+            snap = live.snapshot()
+        if snap is not None and wall:
+            snap = _snapshot_to_wall(snap)
+        return snap
 
     def requests(self, last=None) -> list[dict]:
         """Most recent request-log records, oldest first."""
@@ -563,25 +783,25 @@ class Tracer:
     # -- maintenance ------------------------------------------------------
     def stats(self) -> dict:
         with self._lock:
-            n_spans = self._spans_total
-            cost = self._cost_s
-            return {
+            out = {
                 "enabled": self.enabled,
                 "live": len(self._live),
                 "reservoir": len(self._reservoir),
                 "completions": self._completions,
-                "spans_total": n_spans,
+                "spans_total": self._spans_total,
                 "dropped_live": self._dropped_live,
-                "cost_s": round(cost, 6),
-                "span_cost_us": round(cost / n_spans * 1e6, 3)
-                if n_spans else 0.0,
             }
+        out["step_spans"] = len(self._steps)
+        out["step_spans_dropped"] = self._steps._dropped
+        return out
 
     def flight_snapshot(self) -> dict:
         """Bounded payload the flight recorder embeds in every dump:
-        open spans of in-flight requests + a tail of recent traces."""
+        open spans of in-flight requests + a tail of recent traces, all
+        in wall-clock times (a dump is read after the process is gone)."""
         with self._lock:
-            recent = [s for s in list(self._reservoir.values())[-8:]
+            recent = [_snapshot_to_wall(s)
+                      for s in list(self._reservoir.values())[-8:]
                       if s is not None]
             log_tail = list(self._log)[-16:]
         return {"open_spans": self.open_spans(), "traces": recent,
@@ -593,10 +813,30 @@ class Tracer:
             self._reservoir.clear()
             self._log.clear()
             self._exemplars.clear()
+            self._programs.clear()
             self._completions = 0
             self._spans_total = 0
             self._dropped_live = 0
-            self._cost_s = 0.0
+        self._steps.reset()
+
+
+def _snapshot_to_wall(snap: dict) -> dict:
+    """A trace snapshot with its stamps as wall-clock times; one that is
+    not on the span clock (read back from a dump) is returned as it is."""
+    if snap.get("clock") != "perf_counter":
+        return snap
+
+    def conv(span):
+        return dict(span, t_start=to_wall(span.get("t_start")),
+                    t_end=to_wall(span.get("t_end")))
+
+    out = dict(snap, clock="wall")
+    if out.get("root"):
+        out["root"] = conv(out["root"])
+    for key in ("spans", "open"):
+        if out.get(key):
+            out[key] = [conv(sp) for sp in out[key]]
+    return out
 
 
 _TRACER = Tracer()
@@ -616,13 +856,33 @@ def enable(on: bool = True) -> None:
     _TRACER.enabled = bool(on)
 
 
+def span(name, **attrs):
+    """:meth:`Tracer.span` of the process-wide tracer, inlined: the hot
+    paths call this, and off it costs one call and one flag check."""
+    if not _TRACER.enabled:
+        return NOOP_SPAN
+    return StepSpan(_TRACER._steps, name, attrs)
+
+
+def step_spans(since=None, until=None) -> dict:
+    return _TRACER.step_spans(since, until)
+
+
+def note_program(module, ops) -> None:
+    _TRACER.note_program(module, ops)
+
+
+def programs() -> dict:
+    return _TRACER.programs()
+
+
 def start_request(request_id=None, traceparent=None, **attrs):
     return _TRACER.start_request(request_id=request_id,
                                  traceparent=traceparent, **attrs)
 
 
-def get_trace(trace_id):
-    return _TRACER.get_trace(trace_id)
+def get_trace(trace_id, wall=False):
+    return _TRACER.get_trace(trace_id, wall=wall)
 
 
 def requests(last=None):
@@ -674,12 +934,15 @@ def render_request_log(last=None) -> str:
     return "\n".join(lines) + ("\n" if lines else "")
 
 
-def to_chrome_trace(traces, open_spans=(), trace=None) -> dict:
-    """Render trace snapshots (+ loose open spans) as Chrome-trace JSON,
-    merged into ``trace`` if given. Conventions match the flight
-    exporter: closed spans are ``ph:"X"`` complete events; spans without
-    an end (a dying process's in-flight requests) are kept as ``ph:"B"``
-    begin events rather than dropped."""
+def to_chrome_trace(traces, open_spans=(), trace=None, steps=()) -> dict:
+    """Render trace snapshots (+ loose open spans, + step spans as
+    :func:`step_spans` gives them) as Chrome-trace JSON in wall-clock
+    microseconds, merged into ``trace`` if given. Snapshots on the span
+    clock are converted; those read back from a dump already are wall
+    clock. Conventions match the flight exporter: closed spans are
+    ``ph:"X"`` complete events; spans without an end (a dying process's
+    in-flight requests) are kept as ``ph:"B"`` begin events rather than
+    dropped."""
     out = trace if trace is not None else {"traceEvents": [],
                                            "displayTimeUnit": "ms"}
     events = out.setdefault("traceEvents", [])
@@ -705,7 +968,16 @@ def to_chrome_trace(traces, open_spans=(), trace=None) -> dict:
             ev["ph"] = "B"  # open at death: keep, flight-style
         events.append(ev)
 
+    for sp in steps or ():
+        events.append({
+            "name": sp["name"], "cat": "step", "pid": 1, "tid": 0, "ph": "X",
+            "ts": round(to_wall(sp["t_start"]) * 1e6, 1),
+            "dur": round((sp["t_end"] - sp["t_start"]) * 1e6, 1),
+            "args": dict(sp.get("attributes") or {}, span_id=sp["span_id"],
+                         parent_id=sp["parent_id"],
+                         **(sp.get("counts") or {}))})
     for snap in traces or ():
+        snap = _snapshot_to_wall(snap)
         trace_id = snap.get("trace_id")
         request_id = snap.get("request_id")
         root = snap.get("root")

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from collections import defaultdict
 
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor, Parameter
@@ -135,7 +136,8 @@ class Optimizer:
     def step(self):
         from ..jit.api import in_to_static_trace
         from ..profiler.profiler import host_self_span
-        with host_self_span("optimizer_step(host)"):
+        with host_self_span("optimizer_step(host)"), \
+                jax.named_scope("optimizer"):
             if self._fuse and not in_to_static_trace():
                 self._fused().step()
                 return

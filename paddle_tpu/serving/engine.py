@@ -41,6 +41,7 @@ from ..core.tensor import Tensor
 from ..jit.api import to_static
 from ..observability import counter as _obs_counter
 from ..observability import flight as _flight
+from ..observability import tracing as _tracing
 from .kv_cache import PagePool
 from .model import ServingModel
 from .scheduler import Request, Scheduler, ServingError
@@ -291,12 +292,13 @@ class LLMEngine:
 
         from .speculative import scaled_filtered_logits
 
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        arr = scaled_filtered_logits(logits, temps, self.config.top_k)
-        kk = jax.random.fold_in(key, step.astype(jnp.uint32))
-        g = jax.random.gumbel(kk, arr.shape)
-        sampled = jnp.argmax(arr + g, axis=-1).astype(jnp.int32)
-        return jnp.where(temps > 0, sampled, greedy)
+        with jax.named_scope("head_sample"):
+            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            arr = scaled_filtered_logits(logits, temps, self.config.top_k)
+            kk = jax.random.fold_in(key, step.astype(jnp.uint32))
+            g = jax.random.gumbel(kk, arr.shape)
+            sampled = jnp.argmax(arr + g, axis=-1).astype(jnp.int32)
+            return jnp.where(temps > 0, sampled, greedy)
 
     # -- programs interface the scheduler drives -----------------------------
 
@@ -370,14 +372,22 @@ class LLMEngine:
             return int(np.asarray(out.numpy()).reshape(-1)[0])
         return None
 
+    def gathered_positions(self, program: str) -> int:
+        """Positions of the pool that one call of `program` ("decode",
+        "verify", "chunk") gathers per layer, whatever is live: read off
+        the shapes its forward gathers (0 before its first call)."""
+        return self._sm.gathered.get(program, 0)
+
     def decode(self, tokens, positions, tables, temps):
         import paddle_tpu as paddle
         step = self._step_seq
         self._step_seq += 1
-        out = self._decode_sf(
-            paddle.to_tensor(tokens), paddle.to_tensor(positions),
-            paddle.to_tensor(tables), paddle.to_tensor(temps),
-            self._key_t, paddle.to_tensor(np.int32(step)))
+        with _tracing.span("engine.upload"):
+            args = (paddle.to_tensor(tokens), paddle.to_tensor(positions),
+                    paddle.to_tensor(tables), paddle.to_tensor(temps),
+                    self._key_t, paddle.to_tensor(np.int32(step)))
+        with _tracing.span("engine.dispatch"):
+            out = self._decode_sf(*args)
         self._last_step_wall = time.time()
         if _flight.enabled() and self.scheduler.decode_steps % \
                 max(1, self.config.flight_every) == 0:
@@ -385,7 +395,8 @@ class LLMEngine:
                            step=self.scheduler.decode_steps,
                            active=len(self.scheduler.active_requests()),
                            free_pages=self.pool.free_pages)
-        return np.asarray(out.numpy())
+        with _tracing.span("engine.pull"):   # device time + the copy back
+            return np.asarray(out.numpy())
 
     def verify(self, tokens, positions, dlens, tables, temps):
         """One speculative verify step: tokens ``[B, spec_k+1]`` (last
@@ -396,13 +407,16 @@ class LLMEngine:
         import paddle_tpu as paddle
         step = self._step_seq
         self._step_seq += 1
-        out = self._verify_sf(
-            paddle.to_tensor(tokens), paddle.to_tensor(positions),
-            paddle.to_tensor(dlens), paddle.to_tensor(tables),
-            paddle.to_tensor(temps), self._key_t,
-            paddle.to_tensor(np.int32(step)))
+        with _tracing.span("engine.upload"):
+            args = (paddle.to_tensor(tokens), paddle.to_tensor(positions),
+                    paddle.to_tensor(dlens), paddle.to_tensor(tables),
+                    paddle.to_tensor(temps), self._key_t,
+                    paddle.to_tensor(np.int32(step)))
+        with _tracing.span("engine.dispatch"):
+            out = self._verify_sf(*args)
         self._last_step_wall = time.time()
-        arr = np.asarray(out.numpy())
+        with _tracing.span("engine.pull"):
+            arr = np.asarray(out.numpy())
         return arr[:, :-1], arr[:, -1]
 
     # -- lifecycle -----------------------------------------------------------
@@ -440,7 +454,6 @@ class LLMEngine:
                     # engine thread, not the signal handler (CS102):
                     # tracer locks are safe to take here
                     try:
-                        from ..observability import tracing as _tracing
                         self._preempt_spans = _tracing.open_spans()
                     except Exception:
                         self._preempt_spans = None

@@ -64,6 +64,7 @@ from __future__ import annotations
 import math
 from collections import OrderedDict
 
+import jax
 import jax.numpy as jnp
 
 from ..analysis.concurrency import tsan as _tsan
@@ -396,8 +397,9 @@ def write_token(pool, layer: int, page_ids, slots, vals):
     (physical page and in-page slot per batch row — inactive rows point
     at the trash page); vals ``[B, Hkv, D]``. Returns the updated pool.
     """
-    return pool.at[layer, page_ids, :, slots, :].set(
-        vals.astype(pool.dtype))
+    with jax.named_scope("kv_write"):
+        return pool.at[layer, page_ids, :, slots, :].set(
+            vals.astype(pool.dtype))
 
 
 def write_prefill(pool, layer: int, table_row, prompt_len, vals,
@@ -408,12 +410,13 @@ def write_prefill(pool, layer: int, table_row, prompt_len, vals,
     pool ``[L, P, Hkv, ps, D]``; ``table_row`` ``[max_pages]`` int32;
     ``prompt_len`` traced scalar; vals ``[L_bucket, Hkv, D]``.
     """
-    n = vals.shape[0]
-    t = jnp.arange(n, dtype=jnp.int32)
-    page = jnp.where(t < prompt_len, table_row[t // page_size],
-                     jnp.int32(TRASH_PAGE))
-    return pool.at[layer, page, :, t % page_size, :].set(
-        vals.astype(pool.dtype))
+    with jax.named_scope("kv_write"):
+        n = vals.shape[0]
+        t = jnp.arange(n, dtype=jnp.int32)
+        page = jnp.where(t < prompt_len, table_row[t // page_size],
+                         jnp.int32(TRASH_PAGE))
+        return pool.at[layer, page, :, t % page_size, :].set(
+            vals.astype(pool.dtype))
 
 
 def gather_layer(pool, layer: int, tables):
@@ -423,10 +426,11 @@ def gather_layer(pool, layer: int, tables):
 
     pool ``[L, P, Hkv, ps, D]``; tables ``[B, max_pages]`` int32.
     """
-    kp = pool[layer][tables]                  # [B, Pmax, Hkv, ps, D]
-    kp = jnp.moveaxis(kp, 2, 1)               # [B, Hkv, Pmax, ps, D]
-    b, h, pmax, ps, d = kp.shape
-    return kp.reshape(b, h, pmax * ps, d)
+    with jax.named_scope("kv_gather"):
+        kp = pool[layer][tables]              # [B, Pmax, Hkv, ps, D]
+        kp = jnp.moveaxis(kp, 2, 1)           # [B, Hkv, Pmax, ps, D]
+        b, h, pmax, ps, d = kp.shape
+        return kp.reshape(b, h, pmax * ps, d)
 
 
 def chunk_attention(q, k_cache, v_cache, start):
@@ -449,7 +453,6 @@ def chunk_attention(q, k_cache, v_cache, start):
     the trash page, so they can never contaminate a real lane. Returns
     ``[B, C, H, D]``.
     """
-    import jax
     b, s, h, d = q.shape
     h_kv, t = k_cache.shape[1], k_cache.shape[2]
     rep = h // h_kv

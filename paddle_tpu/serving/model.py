@@ -20,6 +20,7 @@ raises at adapter construction with the missing pieces named.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
@@ -92,6 +93,9 @@ class ServingModel:
         # compiled program's stages took at trace time (the kernel gates
         # below decide per shape; nothing falls back unrecorded)
         self.paths: dict = {}
+        # {program: positions of the pool one layer's page-table gather
+        # reads per call}, from the shapes each forward gathers
+        self.gathered: dict = {}
         self._prog = ""
         # fused decode epilogue (block_fused_pallas.decode_epilogue) needs
         # the final norm + head EXPOSED as attributes so the last junction
@@ -164,6 +168,12 @@ class ServingModel:
     def _note(self, stage: str, path: str) -> None:
         self.paths.setdefault(self._prog, {})[stage] = path
 
+    def _note_gather(self, tables) -> None:
+        """The gather of this program reads every slot of every row of
+        `tables` ([rows, max_pages]), whatever is live."""
+        self.gathered[self._prog] = \
+            int(tables.shape[0]) * int(tables.shape[1]) * self.pool.page_size
+
     # -- shared pieces -------------------------------------------------------
 
     def _rope_tables(self):
@@ -226,6 +236,23 @@ class ServingModel:
                                     layer.post_attention_layernorm.weight,
                                     layer.post_attention_layernorm._epsilon)
         return h + self._mlp(i, layer.mlp, y)
+
+    def _layer_tail(self, i, layers, fused, x, hres, attn_out):
+        """(x, y, hres) after layer `i`'s post-attention half. Fused: both
+        residual junctions are single block_decode_epilogue passes and the
+        next layer's input norm (the final model norm after the LAST
+        layer) folds into the MLP junction, so `y` is the next normed
+        input and `hres` the residual stream; else `x` is the stream."""
+        layer = layers[i]
+        if not fused:
+            return self._block_tail(i, layer, x, attn_out), None, hres
+        y, hres = self._junction(attn_out, hres,
+                                 layer.post_attention_layernorm)
+        m = self._mlp(i, layer.mlp, y)
+        nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
+            else self.model.norm
+        y, hres = self._junction(m, hres, nxt)
+        return x, y, hres
 
     # -- fused-block (mega-kernel) serving path ------------------------------
 
@@ -313,6 +340,7 @@ class ServingModel:
         pos = positions._data.astype(jnp.int32)
         tab = tables._data.astype(jnp.int32)
         b = int(tokens.shape[0])
+        self._note_gather(tab)
         page_ids = jnp.take_along_axis(tab, (pos // ps)[:, None],
                                        axis=1)[:, 0]
         slots = pos % ps
@@ -339,9 +367,10 @@ class ServingModel:
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
-            h = y if fused else layer.input_layernorm(x)
-            q, k, v = self._qkv(i, layer, h, b, 1)
-            q, k = F.rope(q, k, sin, cos)
+            with jax.named_scope("attention"):
+                h = y if fused else layer.input_layernorm(x)
+                q, k, v = self._qkv(i, layer, h, b, 1)
+                q, k = F.rope(q, k, sin, cos)
             kp = kv_cache.write_token(pool.k._data, i, page_ids, slots,
                                       k._data[:, 0])
             vp = kv_cache.write_token(pool.v._data, i, page_ids, slots,
@@ -350,26 +379,19 @@ class ServingModel:
             pool.v._data = vp
             kc = kv_cache.gather_layer(kp, i, tab)
             vc = kv_cache.gather_layer(vp, i, tab)
-            self._note("attention", kv_cache.paged_attention_path(
-                q._data.shape, kc.shape, kc.dtype))
-            out = kv_cache.paged_attention(q._data, kc, vc, pos)
-            attn_out = self._linear(
-                "o", i, Tensor(out.reshape(b, 1,
-                                           self.n_head * self.head_dim)),
-                layer.self_attn.o_proj)
-            if fused:
-                # both residual junctions of the decode step are single
-                # block_decode_epilogue passes; the final model norm folds
-                # into the LAST layer's MLP junction
-                y, hres = self._junction(attn_out, hres,
-                                         layer.post_attention_layernorm)
-                m = self._mlp(i, layer.mlp, y)
-                nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-                    else self.model.norm
-                y, hres = self._junction(m, hres, nxt)
-            else:
-                x = self._block_tail(i, layer, x, attn_out)
-        logits = self._head_normed(y) if fused else self._head(x)
+            with jax.named_scope("attention"):
+                self._note("attention", kv_cache.paged_attention_path(
+                    q._data.shape, kc.shape, kc.dtype))
+                out = kv_cache.paged_attention(q._data, kc, vc, pos)
+                attn_out = self._linear(
+                    "o", i, Tensor(out.reshape(b, 1,
+                                               self.n_head * self.head_dim)),
+                    layer.self_attn.o_proj)
+            with jax.named_scope("mlp"):
+                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
+                                              attn_out)
+        with jax.named_scope("head_sample"):
+            logits = self._head_normed(y) if fused else self._head(x)
         return Tensor(logits._data[:, 0, :])
 
     def _decode_forward_fused_layer(self, tokens, pos, tab, page_ids,
@@ -393,8 +415,9 @@ class ServingModel:
         hres = x._data[:, 0]                                  # [B, Hd]
         y = layers[0].input_layernorm(x)
         for i, layer in enumerate(layers):
-            q, k, v = self._qkv(i, layer, y, b, 1)
-            q, k = F.rope(q, k, sin, cos)
+            with jax.named_scope("attention"):
+                q, k, v = self._qkv(i, layer, y, b, 1)
+                q, k = F.rope(q, k, sin, cos)
             kp = kv_cache.write_token(pool.k._data, i, page_ids, slots,
                                       k._data[:, 0])
             vp = kv_cache.write_token(pool.v._data, i, page_ids, slots,
@@ -412,7 +435,8 @@ class ServingModel:
                 eps_next=getattr(nxt, "_epsilon", 1e-6),
                 interpret=kern.interpret_mode())
             y = Tensor(yj[:, None])
-        logits = self._head_normed(y)
+        with jax.named_scope("head_sample"):
+            logits = self._head_normed(y)
         return Tensor(logits._data[:, 0, :])
 
     # -- speculative verify --------------------------------------------------
@@ -446,6 +470,7 @@ class ServingModel:
         tab = tables._data.astype(jnp.int32)                  # [B, P]
         b, s = int(tokens.shape[0]), int(tokens.shape[1])
         max_pages = int(tab.shape[1])
+        self._note_gather(tab)
 
         lane = jnp.arange(s, dtype=jnp.int32)[None]           # [1, S]
         pos = base[:, None] + lane                            # [B, S]
@@ -467,9 +492,10 @@ class ServingModel:
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
-            h = y if fused else layer.input_layernorm(x)
-            q, k, v = self._qkv(i, layer, h, b, s)
-            q, k = F.rope(q, k, sin, cos)
+            with jax.named_scope("attention"):
+                h = y if fused else layer.input_layernorm(x)
+                q, k, v = self._qkv(i, layer, h, b, s)
+                q, k = F.rope(q, k, sin, cos)
             # write_token scatter over the flattened [B*S] lanes: one
             # (page, slot) per lane, invalid lanes steered to trash
             kp = kv_cache.write_token(
@@ -482,22 +508,19 @@ class ServingModel:
             pool.v._data = vp
             kc = kv_cache.gather_layer(kp, i, tab)
             vc = kv_cache.gather_layer(vp, i, tab)
-            out = kv_cache.chunk_attention(q._data, kc, vc, base)
-            attn_out = self._linear(
-                "o", i, Tensor(out.reshape(b, s,
-                                           self.n_head * self.head_dim)),
-                layer.self_attn.o_proj)
-            if fused:
-                y, hres = self._junction(attn_out, hres,
-                                         layer.post_attention_layernorm)
-                m = self._mlp(i, layer.mlp, y)
-                nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-                    else self.model.norm
-                y, hres = self._junction(m, hres, nxt)
-            else:
-                x = self._block_tail(i, layer, x, attn_out)
+            with jax.named_scope("attention"):
+                out = kv_cache.chunk_attention(q._data, kc, vc, base)
+                attn_out = self._linear(
+                    "o", i, Tensor(out.reshape(b, s,
+                                               self.n_head * self.head_dim)),
+                    layer.self_attn.o_proj)
+            with jax.named_scope("mlp"):
+                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
+                                              attn_out)
         h_all = y if fused else x                             # [B, S, H]
-        logits = self._head_normed(h_all) if fused else self._head(h_all)
+        with jax.named_scope("head_sample"):
+            logits = self._head_normed(h_all) if fused \
+                else self._head(h_all)
         return logits                                         # [B, S, V]
 
     # -- prefill -------------------------------------------------------------
@@ -529,33 +552,31 @@ class ServingModel:
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
-            h = y if fused else layer.input_layernorm(x)
-            q, k, v = self._qkv(i, layer, h, 1, n)
-            q, k = F.rope(q, k, sin, cos)
+            with jax.named_scope("attention"):
+                h = y if fused else layer.input_layernorm(x)
+                q, k, v = self._qkv(i, layer, h, 1, n)
+                q, k = F.rope(q, k, sin, cos)
             pool.k._data = kv_cache.write_prefill(
                 pool.k._data, i, tab_row, plen, k._data[0],
                 pool.page_size)
             pool.v._data = kv_cache.write_prefill(
                 pool.v._data, i, tab_row, plen, v._data[0],
                 pool.page_size)
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=True)
-            attn_out = self._linear(
-                "o", i, out.reshape([1, n, self.n_head * self.head_dim]),
-                layer.self_attn.o_proj)
-            if fused:
-                y, hres = self._junction(attn_out, hres,
-                                         layer.post_attention_layernorm)
-                m = self._mlp(i, layer.mlp, y)
-                nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-                    else self.model.norm
-                y, hres = self._junction(m, hres, nxt)
-            else:
-                x = self._block_tail(i, layer, x, attn_out)
-        import jax
-        h_last = jax.lax.dynamic_slice_in_dim(
-            (y if fused else x)._data, plen - 1, 1, axis=1)  # [1, 1, H]
-        last = Tensor(h_last)
-        logits = self._head_normed(last) if fused else self._head(last)
+            with jax.named_scope("attention"):
+                out = F.scaled_dot_product_attention(q, k, v,
+                                                     is_causal=True)
+                attn_out = self._linear(
+                    "o", i,
+                    out.reshape([1, n, self.n_head * self.head_dim]),
+                    layer.self_attn.o_proj)
+            with jax.named_scope("mlp"):
+                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
+                                              attn_out)
+        with jax.named_scope("head_sample"):
+            h_last = jax.lax.dynamic_slice_in_dim(
+                (y if fused else x)._data, plen - 1, 1, axis=1)  # [1, 1, H]
+            last = Tensor(h_last)
+            logits = self._head_normed(last) if fused else self._head(last)
         return Tensor(logits._data[:, 0, :])
 
     # -- chunked prefill -----------------------------------------------------
@@ -585,6 +606,7 @@ class ServingModel:
         clen = chunk_len._data.reshape(()).astype(jnp.int32)
         tab_row = table_row._data.astype(jnp.int32)
         max_pages = int(tab_row.shape[0])
+        self._note_gather(tab_row[None])
 
         t_loc = jnp.arange(n, dtype=jnp.int32)
         pos = s0 + t_loc                      # absolute sequence positions
@@ -606,9 +628,10 @@ class ServingModel:
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
-            h = y if fused else layer.input_layernorm(x)
-            q, k, v = self._qkv(i, layer, h, 1, n)
-            q, k = F.rope(q, k, sin, cos)
+            with jax.named_scope("attention"):
+                h = y if fused else layer.input_layernorm(x)
+                q, k, v = self._qkv(i, layer, h, 1, n)
+                q, k = F.rope(q, k, sin, cos)
             # write_token's scatter semantics fit a chunk exactly: one
             # (page, slot) per lane, padding lanes steered to trash
             kp = kv_cache.write_token(pool.k._data, i, w_page, w_slot,
@@ -619,23 +642,18 @@ class ServingModel:
             pool.v._data = vp
             kc = kv_cache.gather_layer(kp, i, tab_row[None])
             vc = kv_cache.gather_layer(vp, i, tab_row[None])
-            out = kv_cache.chunk_attention(q._data, kc, vc, s0)
-            attn_out = self._linear(
-                "o", i, Tensor(out.reshape(1, n,
-                                           self.n_head * self.head_dim)),
-                layer.self_attn.o_proj)
-            if fused:
-                y, hres = self._junction(attn_out, hres,
-                                         layer.post_attention_layernorm)
-                m = self._mlp(i, layer.mlp, y)
-                nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-                    else self.model.norm
-                y, hres = self._junction(m, hres, nxt)
-            else:
-                x = self._block_tail(i, layer, x, attn_out)
-        import jax
-        h_last = jax.lax.dynamic_slice_in_dim(
-            (y if fused else x)._data, clen - 1, 1, axis=1)  # [1, 1, H]
-        last = Tensor(h_last)
-        logits = self._head_normed(last) if fused else self._head(last)
+            with jax.named_scope("attention"):
+                out = kv_cache.chunk_attention(q._data, kc, vc, s0)
+                attn_out = self._linear(
+                    "o", i, Tensor(out.reshape(1, n,
+                                               self.n_head * self.head_dim)),
+                    layer.self_attn.o_proj)
+            with jax.named_scope("mlp"):
+                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
+                                              attn_out)
+        with jax.named_scope("head_sample"):
+            h_last = jax.lax.dynamic_slice_in_dim(
+                (y if fused else x)._data, clen - 1, 1, axis=1)  # [1, 1, H]
+            last = Tensor(h_last)
+            logits = self._head_normed(last) if fused else self._head(last)
         return Tensor(logits._data[:, 0, :])

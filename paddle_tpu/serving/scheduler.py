@@ -103,6 +103,15 @@ _EVICTIONS = _obs_counter("paddle_tpu_serving_evictions_total",
 _COW = _obs_counter("paddle_tpu_serving_cow_copies_total",
                     "copy-on-write page copies (a write was about to "
                     "land in a shared page)")
+_PREFILL_TOKENS = _obs_counter(
+    "paddle_tpu_serving_prefill_tokens_total",
+    "tokens through the prefill programs: kind=real those of the prompt "
+    "that were computed, kind=padded the bucket they were padded to")
+_KV_POSITIONS = _obs_counter(
+    "paddle_tpu_serving_kv_positions_total",
+    "KV positions per layer over all decode and verify steps: kind=live "
+    "those of the contexts in the batch, kind=gathered those the program's "
+    "page-table gather read")
 _QUEUE = _obs_gauge("paddle_tpu_serving_queue_depth",
                     "requests waiting for admission")
 _ACTIVE = _obs_gauge("paddle_tpu_serving_active_requests",
@@ -202,8 +211,7 @@ class Request:
         self.queue_ms = 0.0
         self.prefill_ms = 0.0
         self.decode_ms: float | None = None
-        self._t_enqueued = self.t_submit
-        self._t_enqueued_wall = time.time()
+        self._t_enqueued = time.perf_counter()    # the span clock
         # request trace: NOOP_TRACE when PADDLE_TPU_TRACE=0 — hot paths
         # identity-check it before building span attributes
         self.trace = _tracing.start_request(
@@ -286,16 +294,19 @@ class Request:
             except Exception:
                 pass  # a user callback must never kill the engine loop
 
-    def _trace_step(self, kind: str, t_start: float, tokens: int = 1,
-                    **extra) -> None:
+    def _trace_step(self, kind: str, t_start: float | None,
+                    tokens: int = 1, **extra) -> None:
         """Fold one decode/verify iteration into the current span burst.
         Per-token spans would dominate tracer cost, so consecutive
         same-kind steps aggregate into ONE span until the kind changes
         or the burst cap (``PADDLE_TPU_TRACE_BURST``) is hit; numeric
         extras (proposed/accepted/rollback_pages) sum across the burst.
+        ``t_start`` is the step's start on the span clock (None: now).
         Engine-thread-owned state — never touched from user threads."""
         if self.trace is _tracing.NOOP_TRACE:
             return
+        if t_start is None:
+            t_start = time.perf_counter()
         b = self._tr_burst
         if b is not None and b["kind"] != kind:
             self._trace_flush()
@@ -315,7 +326,8 @@ class Request:
         if b is None:
             return
         self._tr_burst = None
-        self.trace.add_span(b["kind"], t_start=b["t0"], t_end=time.time(),
+        self.trace.add_span(b["kind"], t_start=b["t0"],
+                            t_end=time.perf_counter(),
                             steps=b["steps"], tokens=b["tokens"],
                             **b["extra"])
 
@@ -482,8 +494,7 @@ class Scheduler:
             i -= 1
         self.waiting.insert(i, req)
         req.state = QUEUED
-        req._t_enqueued = time.monotonic()
-        req._t_enqueued_wall = time.time()
+        req._t_enqueued = time.perf_counter()
         _QUEUE.set(len(self.waiting))
 
     # -- introspection -------------------------------------------------------
@@ -591,9 +602,11 @@ class Scheduler:
     def step(self) -> bool:
         """One scheduler iteration (admit → chunked prefill → grow/evict
         → batched decode). Returns True when any device work ran."""
-        admitted = self._admit()
-        chunked = self._prefill_chunks()
-        ran_decode = self._decode()
+        with _tracing.span("serving.step"):
+            with _tracing.span("serving.admit"):
+                admitted = self._admit()
+            chunked = self._prefill_chunks()
+            ran_decode = self._decode()
         return bool(admitted or chunked or ran_decode)
 
     def drain_step(self) -> bool:
@@ -649,7 +662,9 @@ class Scheduler:
     def _admit(self) -> int:
         admitted = 0
         while True:
-            t_adm0 = time.time()
+            # read for the request's `admit` span alone
+            t_adm0 = time.perf_counter() if _tracing.tracing_enabled() \
+                else None
             with self.lock:
                 if not self.waiting:
                     break
@@ -706,17 +721,19 @@ class Scheduler:
                 req.state = RUNNING
                 if self.spec_k and req.spec is None:
                     req.spec = SpecState(self.spec_k, self.spec_adaptive)
-                wait_ms = (time.monotonic() - req._t_enqueued) * 1000.0
+                wait_ms = (time.perf_counter() - req._t_enqueued) * 1000.0
                 req.queue_ms += wait_ms
                 self.queue_wait_ms_sum += wait_ms
                 self.admissions += 1
                 _ACTIVE.set(len([r for r in self.slots if r is not None]))
             _QUEUE_WAIT.observe(wait_ms)
             if req.trace is not _tracing.NOOP_TRACE:
-                t_now = time.time()
+                t_now = time.perf_counter()
                 req.trace.add_span("queue_wait",
-                                   t_start=req._t_enqueued_wall, t_end=t_now)
-                req.trace.add_span("admit", t_start=t_adm0, t_end=t_now,
+                                   t_start=req._t_enqueued, t_end=t_now)
+                req.trace.add_span("admit",
+                                   t_start=t_now if t_adm0 is None
+                                   else t_adm0, t_end=t_now,
                                    cached_tokens=matched,
                                    claimed_pages=len(claimed),
                                    pages=len(req.pages), context=ctx_len,
@@ -733,19 +750,27 @@ class Scheduler:
             if self.chunk:
                 admitted += 1     # chunked mode: device work interleaves
                 continue
-            t_pf0 = time.time()
-            try:
-                first = self.programs.prefill(req)
-            except Exception as e:   # noqa: BLE001 — request-scoped failure
-                self._release(req)
-                req._finish(FAILED, f"prefill failed: {e!r}")
-                continue
-            t_pf1 = time.time()
+            n_real = ctx_len - matched
+            bucket = self.programs.bucket_for(n_real)
+            _PREFILL_TOKENS.inc(n_real, kind="real")
+            _PREFILL_TOKENS.inc(bucket, kind="padded")
+            with _tracing.span("serving.prefill",
+                               request_id=req.request_id) as sp:
+                sp.count(tokens=n_real, bucket=bucket)
+                t_pf0 = time.perf_counter()
+                try:
+                    first = self.programs.prefill(req)
+                except Exception as e:   # noqa: BLE001 — request-scoped
+                    self._release(req)
+                    req._finish(FAILED, f"prefill failed: {e!r}")
+                    continue
+                t_pf1 = time.perf_counter()
             req.prefill_ms += (t_pf1 - t_pf0) * 1000.0
             if req.trace is not _tracing.NOOP_TRACE:
+                # step_span: the id of the step span that served it
                 req.trace.add_span("prefill", t_start=t_pf0, t_end=t_pf1,
-                                   tokens=ctx_len - matched,
-                                   cached_tokens=matched)
+                                   tokens=n_real, cached_tokens=matched,
+                                   step_span=sp.span_id)
             with self.lock:
                 # the SCHEDULER owns prefill progress — a programs
                 # implementation only runs device work (the engine
@@ -769,9 +794,10 @@ class Scheduler:
         _flight.record("serving_prefill", request=req.request_id,
                        prompt=req.cur_len(), pages=len(req.pages),
                        cached_tokens=cached_tokens)
-        req._emit(first)
-        _TOKENS.inc(kind="generated")
-        self._maybe_complete(req)
+        with _tracing.span("serving.emit"):
+            req._emit(first)
+            _TOKENS.inc(kind="generated")
+            self._maybe_complete(req)
 
     def _release(self, req: Request) -> None:
         """Take req out of its slot and drop its page references (a
@@ -824,7 +850,7 @@ class Scheduler:
         _flight.record("serving_evict", request=victim.request_id,
                        generated=len(victim.tokens))
         if victim.trace is not _tracing.NOOP_TRACE:
-            now = time.time()
+            now = time.perf_counter()
             victim.trace.add_span("evict", t_start=now, t_end=now,
                                   generated=len(victim.tokens),
                                   evictions=victim.evictions)
@@ -875,7 +901,8 @@ class Scheduler:
                     if not self._evict_for(req):
                         return False
                     continue
-                t_cp0 = time.time()
+                t_cp0 = time.perf_counter() \
+                    if req.trace is not _tracing.NOOP_TRACE else None
                 self.pool.copy_page(page, fresh)
                 with self.lock:
                     if req.slot is None:      # evicted meanwhile
@@ -890,7 +917,8 @@ class Scheduler:
                                src=int(page), page=int(fresh))
                 if req.trace is not _tracing.NOOP_TRACE:
                     req.trace.add_span("cow", t_start=t_cp0,
-                                       t_end=time.time(), src=int(page),
+                                       t_end=time.perf_counter(),
+                                       src=int(page),
                                        page=int(fresh))
                 break
         return True
@@ -921,18 +949,25 @@ class Scheduler:
                 continue
             if not self._make_writable(req, start, n):
                 continue             # evicted while making room
-            t_ch0 = time.time()
-            try:
-                tok = self.programs.prefill_chunk(req, n)
-            except Exception as e:   # noqa: BLE001 — request-scoped failure
-                self._release(req)
-                req._finish(FAILED, f"prefill failed: {e!r}")
-                continue
-            t_ch1 = time.time()
+            bucket = self.programs.bucket_for(n)
+            _PREFILL_TOKENS.inc(n, kind="real")
+            _PREFILL_TOKENS.inc(bucket, kind="padded")
+            with _tracing.span("serving.prefill_chunk",
+                               request_id=req.request_id) as sp:
+                sp.count(tokens=n, bucket=bucket)
+                t_ch0 = time.perf_counter()
+                try:
+                    tok = self.programs.prefill_chunk(req, n)
+                except Exception as e:   # noqa: BLE001 — request-scoped
+                    self._release(req)
+                    req._finish(FAILED, f"prefill failed: {e!r}")
+                    continue
+                t_ch1 = time.perf_counter()
             req.prefill_ms += (t_ch1 - t_ch0) * 1000.0
             if req.trace is not _tracing.NOOP_TRACE:
                 req.trace.add_span("prefill_chunk", t_start=t_ch0,
-                                   t_end=t_ch1, start=start, n=n)
+                                   t_end=t_ch1, start=start, n=n,
+                                   step_span=sp.span_id)
             budget -= n
             ran += 1
             with self.lock:
@@ -1052,16 +1087,32 @@ class Scheduler:
                 positions[req.slot] = req.cur_len() - 1
                 temps[req.slot] = max(req.temperature, 0.0)
             tables = self._masked_tables()
-        t_dec0 = time.time()
-        out = self.programs.decode(tokens, positions, tables, temps)
+        with _tracing.span("serving.decode") as sp:
+            out = self.programs.decode(tokens, positions, tables, temps)
+        # live rows carry cur_len - 1, the others 0
+        self._count_positions(sp, "decode", len(active),
+                              int(positions.sum()) + len(active))
         self._account_step(len(active) / float(self.max_batch),
                            emitted=len(active), rows=len(active))
-        for req in active:
-            req._emit(int(out[req.slot]))
-            _TOKENS.inc(kind="generated")
-            req._trace_step("decode", t_dec0)
-            self._maybe_complete(req)
+        with _tracing.span("serving.emit"):
+            for req in active:
+                req._emit(int(out[req.slot]))
+                _TOKENS.inc(kind="generated")
+                req._trace_step("decode", sp.t_start)
+                self._maybe_complete(req)
         return True
+
+    def _count_positions(self, sp, program: str, rows: int,
+                         live: int) -> None:
+        """One batched step's KV positions per layer, live against
+        gathered, on its step span and on the operator's counters. What
+        the program gathers is the engine's to say, from the shapes its
+        forward gathers (a bare fake of `programs` gathers nothing)."""
+        fn = getattr(self.programs, "gathered_positions", None)
+        gathered = int(fn(program)) if fn is not None else 0
+        sp.count(rows=rows, positions=live, gathered=gathered)
+        _KV_POSITIONS.inc(live, kind="live")
+        _KV_POSITIONS.inc(gathered, kind="gathered")
 
     # -- speculative decoding ------------------------------------------------
 
@@ -1165,35 +1216,39 @@ class Scheduler:
             n_prop = int(dlens.sum())
         _flight.record("serving_spec_propose", rows=len(active),
                        proposed=n_prop)
-        t_ver0 = time.time()
-        out, acc = self.programs.verify(tokens, positions, dlens, tables,
-                                        temps)
+        with _tracing.span("serving.verify") as sp:
+            out, acc = self.programs.verify(tokens, positions, dlens, tables,
+                                            temps)
+        self._count_positions(sp, "verify", len(active),
+                              int(positions.sum()) + len(active))
         occ = len(active) / float(self.max_batch)
         n_acc = n_emit = 0
-        for req in active:
-            a = int(acc[req.slot])
-            d_n = int(dlens[req.slot])
-            emitted = [int(t) for t in out[req.slot, :a + 1]]
-            # the burst must stop exactly where sequential decode would
-            emitted = emitted[:req.max_new_tokens - len(req.tokens)]
-            if req.eos_token_id is not None and req.eos_token_id in emitted:
-                emitted = emitted[:emitted.index(req.eos_token_id) + 1]
-            req._emit_burst(emitted)
-            _TOKENS.inc(len(emitted), kind="generated")
-            n_acc += a
-            n_emit += len(emitted)
-            st = req.spec
-            if st is not None and d_n:
-                st.update(d_n, a)
-            if st is not None and req.slot is not None:
-                # every step, not just drafting ones: the gauge must
-                # track adaptive K falling to 0 (and _release zeroes it
-                # when the slot empties)
-                _SPEC_K.set(st.k, slot=str(req.slot))
-            rb = self._rollback(req)
-            req._trace_step("speculate", t_ver0, tokens=len(emitted),
-                            proposed=d_n, accepted=a, rollback_pages=rb)
-            self._maybe_complete(req)
+        with _tracing.span("serving.emit"):
+            for req in active:
+                a = int(acc[req.slot])
+                d_n = int(dlens[req.slot])
+                emitted = [int(t) for t in out[req.slot, :a + 1]]
+                # the burst must stop exactly where sequential decode would
+                emitted = emitted[:req.max_new_tokens - len(req.tokens)]
+                eos = req.eos_token_id
+                if eos is not None and eos in emitted:
+                    emitted = emitted[:emitted.index(eos) + 1]
+                req._emit_burst(emitted)
+                _TOKENS.inc(len(emitted), kind="generated")
+                n_acc += a
+                n_emit += len(emitted)
+                st = req.spec
+                if st is not None and d_n:
+                    st.update(d_n, a)
+                if st is not None and req.slot is not None:
+                    # every step, not just drafting ones: the gauge must
+                    # track adaptive K falling to 0 (and _release zeroes it
+                    # when the slot empties)
+                    _SPEC_K.set(st.k, slot=str(req.slot))
+                rb = self._rollback(req)
+                req._trace_step("speculate", sp.t_start, tokens=len(emitted),
+                                proposed=d_n, accepted=a, rollback_pages=rb)
+                self._maybe_complete(req)
         self._account_step(occ, emitted=n_emit, rows=len(active),
                            proposed=n_prop, accepted=n_acc, verify=True)
         if n_prop:

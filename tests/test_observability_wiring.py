@@ -2,6 +2,7 @@
 the dataloader, profiler spans + chrome-trace merge, StepTimer, and the
 bench/perf_gate telemetry block."""
 
+import contextlib
 import json
 import os
 import subprocess
@@ -202,3 +203,156 @@ def test_perf_gate_fails_on_steady_state_retraces(tmp_path):
     bad = run(cur_retrace)
     assert bad.returncode == 1, bad.stdout + bad.stderr
     assert "RETRACE" in bad.stdout
+
+
+# -- ISSUE 25: named programs, named scopes, jit.run and io.next spans -------
+
+def _tiny_train_step(name="train_step"):
+    from paddle_tpu.models import gpt2_tiny
+    paddle.seed(0)
+    model = gpt2_tiny()
+    opt = paddle.optimizer.AdamW(3e-4, parameters=model.parameters())
+
+    def step(x, y):
+        _, loss = model(x, labels=y)
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+        return loss
+
+    step.__name__ = step.__qualname__ = name
+    data = np.arange(8 * 17).reshape(8, 17) % 1024
+    return (paddle.jit.to_static(step), paddle.to_tensor(data[:, :-1]),
+            paddle.to_tensor(data[:, 1:]))
+
+
+@contextlib.contextmanager
+def _tracing_set(on):
+    """The process-wide tracer, emptied and switched `on` for the block."""
+    from paddle_tpu.observability import tracing
+    tr = tracing.get_tracer()
+    was = tr.enabled
+    tr.reset()
+    tr.enabled = on
+    try:
+        yield tracing
+    finally:
+        tr.enabled = was
+        tr.reset()
+
+
+def test_to_static_program_is_named_after_its_function():
+    import jax
+
+    @paddle.jit.to_static
+    def scale(x):
+        return x * 2.0 + 1.0
+
+    x = paddle.to_tensor(np.ones((4, 4), np.float32))
+    for _ in range(2):
+        scale(x)
+    want = "pure_arrays__test_to_static_program_is_named_after_its_" \
+           "function__locals__scale"
+    assert scale._program_name == want
+    (jitted, cell, _), = scale._cache.values()
+    lowered = jitted.lower(*cell["avals"])
+    assert f"module @jit_{want} " in lowered.as_text()[:400]
+    assert f"HloModule jit_{want}" in lowered.compile().as_text()[:400]
+    # the prefix the accepted metric files match on is kept
+    assert jitted.__wrapped__.__name__.startswith("pure_arrays__")
+    assert isinstance(jitted, type(jax.jit(lambda a: a)))
+
+
+def test_train_step_hlo_carries_forward_backward_optimizer():
+    step, x, y = _tiny_train_step()
+    for _ in range(2):
+        float(step(x, y))
+    (text,) = step.compiled_text_cached()
+    import re
+    ops = re.findall(r'op_name="([^"]*)"', text)
+    for scope in ("forward", "backward", "optimizer"):
+        mine = [o for o in ops
+                if re.match(r"jit\([^/]*\)/(jit\(main\)/)?%s/" % scope, o)]
+        assert len(mine) > 20, (scope, len(mine))
+    # each op under one of the three, named by the outermost scope
+    assert not [o for o in ops if "/forward/forward/" in o]
+    assert any(o.startswith("jit(pure_arrays__train_step)/") for o in ops)
+
+
+def test_jit_run_spans_name_function_and_phase():
+    with _tracing_set(True) as tracing:
+        step, x, y = _tiny_train_step("wired_step")
+        for _ in range(4):
+            float(step(x, y))
+        runs = [s for s in tracing.step_spans()["spans"]
+                if s["name"] == "jit.run"
+                and s["attributes"]["fn"] == "wired_step"]
+        assert [s["attributes"]["phase"] for s in runs] == \
+            ["eager", "compile", "run", "run"]
+        assert runs[1]["t_end"] - runs[1]["t_start"] > \
+            10 * (runs[3]["t_end"] - runs[3]["t_start"])
+        # with tracing on, the compiled program hands over the table that
+        # names the code behind each instruction
+        entry = tracing.programs()["jit_pure_arrays__wired_step"]
+        assert entry["dropped"] == 0
+        table, = entry["variants"]
+        scopes = {o.split("/")[1] for o in table.values() if "/" in o}
+        assert {"forward", "backward", "optimizer"} <= scopes
+
+
+def test_tracing_off_leaves_no_spans_and_no_program_tables():
+    with _tracing_set(False) as tracing:
+        step, x, y = _tiny_train_step("quiet_step")
+        for _ in range(3):
+            float(step(x, y))
+        assert tracing.step_spans()["spans"] == []
+        assert tracing.programs() == {}
+
+
+def test_dataloader_next_is_a_step_span():
+    from paddle_tpu.io import DataLoader, Dataset
+
+    class Rows(Dataset):
+        def __len__(self):
+            return 12
+
+        def __getitem__(self, i):
+            return np.full((3,), i, np.float32)
+
+    with _tracing_set(True) as tracing:
+        n = sum(1 for _ in DataLoader(Rows(), batch_size=4))
+        spans = [s for s in tracing.step_spans()["spans"]
+                 if s["name"] == "io.next"]
+        # one per batch, and the one that found the end
+        assert n == 3 and len(spans) == 4
+
+
+def test_serving_programs_carry_their_scopes():
+    from paddle_tpu.models.llama import llama_tiny
+    from paddle_tpu.serving import LLMEngine, ServingConfig
+    with _tracing_set(True) as tracing:
+        paddle.seed(0)
+        model = llama_tiny(vocab_size=128, max_position_embeddings=64,
+                           hidden_size=32, num_layers=1, num_heads=2,
+                           num_kv_heads=1, intermediate_size=64)
+        eng = LLMEngine(model, ServingConfig(
+            page_size=8, num_pages=17, max_batch=2, max_new_tokens=4,
+            prefix_cache=False))
+        try:
+            for _ in range(2):
+                eng.generate([1, 2, 3, 4, 5], timeout=300)
+        finally:
+            eng.shutdown(drain=False)
+        progs = tracing.programs()
+        decode, = progs["jit_pure_arrays__serving_decode_step"]["variants"]
+        prefill, = progs["jit_pure_arrays__serving_prefill"]["variants"]
+
+        def scopes(table):
+            return {p for o in table.values() for p in o.split("/")[1:-1]}
+
+        assert {"kv_gather", "kv_write", "attention", "mlp",
+                "head_sample"} <= scopes(decode)
+        assert {"kv_write", "attention", "mlp", "head_sample"} \
+            <= scopes(prefill)
+        assert "kv_gather" not in scopes(prefill)
+        assert eng.gathered_positions("decode") == 2 * 8 * 8

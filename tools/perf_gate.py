@@ -58,8 +58,7 @@ speculative A/B's spec-on p50 TPOT vs spec-off within-round
 (PERF_GATE_SPEC_TPOT_TOL_PCT, default 25% — speculation that costs
 latency on its own workload is a regression). The request-tracing probe
 (``extra.serve.tracing``) joins the hard sub-block sweep (tracing must
-not flip SERVE-RETRACE/SERVE-LEAK/SERVE-LOST) and soft-gates the
-tracer's measured overhead (PERF_GATE_TRACE_TOL_PCT, default 1%).
+not flip SERVE-RETRACE/SERVE-LEAK/SERVE-LOST).
 
 The mega-kernel harvest (``extra.fusion_targets``) adds a soft gate: the
 top remaining (not ``fused``) target's est_saved_bytes must stay below
@@ -640,23 +639,6 @@ def serve_gates(cd, bd):
                   f"{on_fp:.2f} ms fused vs {off_fp:.2f} ms composite "
                   f"(delta {delta:+.2%}, block_i "
                   f"{fon.get('tuned_block_i')})")
-    # request tracing must stay effectively free: the tracer's measured
-    # self-cost (span-append wall folded into tracer stats) as a share
-    # of the traced workload's wall
-    trace_tol = _tol_pct("PERF_GATE_TRACE_TOL_PCT", 1.0)
-    tb = cur.get("tracing") or {}
-    ov = tb.get("overhead_pct")
-    if trace_tol > 0 and ov is not None:
-        if float(ov) > trace_tol:
-            soft.append(
-                f"perf gate [REGRESSION:trace-overhead] request tracing "
-                f"cost {float(ov):.3f}% of the serve wall (ceiling "
-                f"{trace_tol:g}% via PERF_GATE_TRACE_TOL_PCT)")
-        else:
-            print(f"perf gate [ok:trace-overhead] request tracing "
-                  f"{float(ov):.3f}% of the serve wall (ceiling "
-                  f"{trace_tol:g}%, span cost "
-                  f"{tb.get('span_cost_us')} us)")
     tol = _tol_pct("PERF_GATE_SERVE_TOL_PCT", 30.0)
     base = serve_block(bd) if bd else None
     if tol > 0 and base and base.get("tokens_per_s"):
