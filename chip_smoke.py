@@ -280,7 +280,7 @@ def phase_serve(tiny: bool, seed: int) -> dict:
     check(leaked == 0 and lost == 0, f"pages leaked {leaked} lost {lost}")
     if on_tpu:
         path = programs["decode"]["path"]
-        check(path.get("attention") == "mmha_decode" and
+        check(path.get("attention") == "paged_mmha_decode" and
               path.get("junction") == "block_decode_epilogue",
               f"decode took a composite path: {path}")
         check(len(kernels["decode"]) >= 2 and kernels["prefill"],

@@ -50,6 +50,8 @@ class BlockInfo:
     index_map_jaxpr: Any         # ClosedJaxpr (grid ids + prefetch refs)
     is_output: bool
     position: int                # operand position within inputs/outputs
+    in_hbm: bool = False         # left in HBM (memory_space ANY/HBM): the
+    #                              kernel fetches from it with its own DMAs
 
     @property
     def nblocks(self) -> tuple:
@@ -59,6 +61,10 @@ class BlockInfo:
 
     @property
     def block_bytes(self) -> int:
+        """Bytes of one block in VMEM: none for an operand left in HBM
+        (what the kernel fetches of it lands in scratch, counted there)."""
+        if self.in_hbm:
+            return 0
         n = 1
         for b in self.block_shape:
             n *= int(b)
@@ -223,6 +229,8 @@ def _model_from_eqn(eqn, label: str, file: str) -> KernelModel:
 
     def info(bm, is_output, pos):
         arr = bm.array_aval
+        space = getattr(getattr(bm, "block_aval", None), "memory_space",
+                        None)
         return BlockInfo(
             origin=str(getattr(bm, "origin", "") or ""),
             block_shape=_block_dims(bm.block_shape, arr.shape),
@@ -230,7 +238,9 @@ def _model_from_eqn(eqn, label: str, file: str) -> KernelModel:
             dtype=arr.dtype,
             index_map_jaxpr=bm.index_map_jaxpr,
             is_output=is_output,
-            position=pos)
+            position=pos,
+            in_hbm=space is not None and str(space).lower() in ("any",
+                                                                "hbm"))
 
     inputs = [info(bm, False, i) for i, bm in enumerate(mappings[:n_in])]
     outputs = [info(bm, True, i) for i, bm in enumerate(mappings[n_in:])]

@@ -42,7 +42,10 @@ def _aval_nbytes(aval) -> int:
     import numpy as np
     aval = getattr(aval, "inner_aval", aval)
     shape = tuple(getattr(aval, "shape", ()) or ())
-    dtype = np.dtype(getattr(aval, "dtype", np.float32))
+    try:
+        dtype = np.dtype(getattr(aval, "dtype", np.float32))
+    except TypeError:       # a semaphore: no vector memory
+        return 0
     n = 1
     for d in shape:
         n *= int(d)

@@ -17,6 +17,27 @@ Cache layout is [B, Hkv, T, D] — time-contiguous per head, so each chunk is
 one stride-free VMEM tile. T must be a multiple of the chunk size; the
 decode path rounds its cache allocation up (masking hides the tail), see
 models/llama.py _init_kv_cache.
+
+Two kernels live here, one for each way a KV cache is kept:
+
+* :func:`mmha_decode` — the contiguous cache above. What
+  ``models/generation.py:cached_attention`` (``model.generate``) and
+  ``incubate/nn/functional/fused_transformer_serving.py`` call: one buffer a
+  request, no page table. Its BlockSpec brings all T positions of a
+  (row, KV head) into VMEM whatever ``pos`` is; only the loop is bounded.
+* :func:`paged_mmha_decode` — the serving engine's decode program
+  (``serving/kv_cache.py:paged_attention``). It takes the WHOLE paged pool
+  ``[L, P, Hkv, ps, D]`` left in HBM, the layer, the page tables and the
+  per-row positions as scalar prefetch, and fetches each row's live pages
+  itself: grid over rows, inside a loop over the row's
+  ``ceil((pos+1) / (pages_per_block*ps))`` blocks only, each block's pages
+  brought in by double-buffered DMAs (one 32 KB page serves all KV heads;
+  the next block, or the next row's first, is in flight under the current
+  block's arithmetic). No gathered ``[B, Hkv, T, D]`` view exists, so a
+  decode step reads what is live and not ``max_batch x max_seq_len``. It
+  feeds the MXU operands in the pool's dtype (bf16 as it is stored; the
+  f32 casts of the contiguous kernel made the MXU run multi-pass) and
+  keeps scores, softmax and accumulators in f32.
 """
 
 from __future__ import annotations
@@ -146,6 +167,206 @@ def mmha_decode(q, k_buf, v_buf, pos, block_t=BLOCK_T, interpret=False):
     return out[:, :, :rep, :].reshape(b, 1, h, d)
 
 
+# -- paged decode attention (the serving decode program's kernel) -----------
+
+#: KV pages one block of the paged kernel fetches and scores at a time
+#: (x page_size positions). Chosen on the chip at the serving cells' shapes
+#: (B 32, Hkv 8, rep 4, D 128, ps 16, contexts 50-2500): see PERF.md.
+PAGES_PER_BLOCK = 8
+
+
+def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
+                       o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s, slot_s,
+                       *, ppb, max_pages, scale):
+    # grid (B,), one row a step, run in order. q_ref/o_ref
+    # [1, Hkv, rep_p, D]; k_hbm/v_hbm the WHOLE pool [L, P, Hkv, ps, D],
+    # left in HBM; k_buf/v_buf [2, ppb, Hkv, ps, D] VMEM (two slots);
+    # sems [2 (k, v), 2 (slot)]; m_s/l_s [Hkv, rep_p, 128] lane-broadcast,
+    # acc_s [Hkv, rep_p, D] f32; slot_s [1] SMEM: the slot the row's first
+    # block lands in (the previous row started that fetch).
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    layer = layer_ref[0]
+    pos = pos_ref[b]
+    h_kv, ps, d = k_buf.shape[2:]
+    rep_p = q_ref.shape[2]
+    bt = ppb * ps
+    # position-bounded: only the blocks that hold a live position. A row
+    # with nothing live (pos < 0) still takes the one block the row before
+    # it started fetching, and scores nothing
+    n_blocks = jnp.maximum(pos, 0) // jnp.int32(bt) + jnp.int32(1)
+
+    def fetch(row, blk, slot):
+        for j in range(ppb):
+            # a table narrower than a whole number of blocks: its last
+            # block re-reads the last page (past `pos`, masked below)
+            idx = jnp.minimum(blk * ppb + j, max_pages - 1)
+            page = tables_ref[row * max_pages + idx]
+            pltpu.make_async_copy(k_hbm.at[layer, page], k_buf.at[slot, j],
+                                  sems.at[0, slot]).start()
+            pltpu.make_async_copy(v_hbm.at[layer, page], v_buf.at[slot, j],
+                                  sems.at[1, slot]).start()
+
+    def wait(slot):
+        for j in range(ppb):    # a wait needs the copy's shape, not its page
+            pltpu.make_async_copy(k_hbm.at[layer, 0], k_buf.at[slot, j],
+                                  sems.at[0, slot]).wait()
+            pltpu.make_async_copy(v_hbm.at[layer, 0], v_buf.at[slot, j],
+                                  sems.at[1, slot]).wait()
+
+    @pl.when(b == 0)
+    def _first():
+        slot_s[0] = jnp.int32(0)
+        fetch(b, jnp.int32(0), jnp.int32(0))
+
+    slot0 = slot_s[0]
+    m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+    l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+    acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    def score_block(i, slot):
+        t_idx = i * bt + jax.lax.broadcasted_iota(jnp.int32, (rep_p, bt), 1)
+        live = t_idx <= pos
+        for g in range(h_kv):   # one page DMA serves every KV head
+            k = k_buf[slot, :, g].reshape(bt, d)
+            v = v_buf[slot, :, g].reshape(bt, d)
+            # operands in the pool's dtype (bf16 on the MXU as it is),
+            # scores, softmax and accumulators in f32
+            s = jax.lax.dot_general(
+                q_ref[0, g].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * jnp.float32(scale)
+            s = jnp.where(live, s, jnp.float32(NEG_INF))
+            m_prev = m_s[g][:, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_new = alpha * l_s[g][:, :1] + jnp.sum(p, axis=1, keepdims=True)
+            acc_s[g] = alpha * acc_s[g] + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_s[g] = jnp.broadcast_to(m_new, m_s.shape[1:])
+            l_s[g] = jnp.broadcast_to(l_new, l_s.shape[1:])
+
+    def body(i, carry):
+        slot = (slot0 + i) % 2
+        # the next block of this row or, behind its last, the first block
+        # of the next row: its fetch runs under this block's arithmetic
+        last = i + 1 >= n_blocks
+        nrow = jnp.where(last, b + 1, b)
+
+        @pl.when(nrow < n_rows)
+        def _prefetch():
+            fetch(nrow, jnp.where(last, 0, i + 1), 1 - slot)
+
+        wait(slot)
+
+        @pl.when(pos >= 0)
+        def _score():
+            score_block(i, slot)
+        return carry
+
+    jax.lax.fori_loop(jnp.int32(0), n_blocks, body, jnp.int32(0))
+    slot_s[0] = (slot0 + n_blocks) % 2
+    for g in range(h_kv):
+        o_ref[0, g] = (acc_s[g] / jnp.maximum(
+            l_s[g][:, :1], jnp.float32(1e-30))).astype(o_ref.dtype)
+
+
+def use_paged_kernel(q_shape, pool_shape, pool_dtype) -> bool:
+    """Gate of :func:`paged_mmha_decode`: kernels dispatching, one new
+    token, and a page the kernel's tiles admit (head width a multiple of
+    the 128 lanes, page size a multiple of the pool dtype's sublane tile,
+    so a block's pages stack into one [T, D] operand without a relayout)."""
+    from . import _common as kern
+    if not kern.available():
+        return False
+    if len(q_shape) != 4 or q_shape[1] != 1 or len(pool_shape) != 5:
+        return False
+    _, _, h_kv, ps, d = pool_shape
+    if q_shape[3] != d or q_shape[2] % h_kv or d % 128:
+        return False
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    if itemsize not in (2, 4) or ps % (32 // itemsize):
+        return False
+    # two slots of K and V blocks stay well inside VMEM
+    return 4 * PAGES_PER_BLOCK * h_kv * ps * d * itemsize \
+        <= chip_vmem_bytes() // 4
+
+
+def paged_block_positions(page_size, max_pages,
+                          pages_per_block=PAGES_PER_BLOCK) -> int:
+    """Positions in one block of :func:`paged_mmha_decode`: a row holding
+    n live positions has ceil(n / this) blocks of pages read."""
+    return min(pages_per_block, max_pages) * page_size
+
+
+@functools.partial(jit_x64_off,
+                   static_argnames=("pages_per_block", "interpret"))
+def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos,
+                      pages_per_block=PAGES_PER_BLOCK, interpret=False):
+    """Decode attention straight from the paged pool.
+
+    q [B, 1, H, D]; k_pool/v_pool [L, P, Hkv, ps, D], whole and left where
+    they are (the kernel fetches pages itself, so no slice or gathered view
+    of them is ever built); layer: traced int32 scalar; tables
+    [B, max_pages] int32 (physical page of each logical page); pos [B]
+    int32, last valid position per row (its token already written), or -1
+    for a row with nothing live. A row reads ceil((pos+1) /
+    (pages_per_block*ps)) blocks of pages, the last one masked per element;
+    a row with nothing live reads the first block of its table, scores
+    nothing and returns zeros. Returns [B, 1, H, D]."""
+    b, s, h, d = q.shape
+    if s != 1:
+        raise ValueError(
+            f"paged_mmha_decode takes exactly one new token, got {s}")
+    _, _, h_kv, ps, _ = k_pool.shape
+    max_pages = tables.shape[1]
+    rep = h // h_kv
+    rep_p = max(8, round_up(rep, 8))
+    ppb = paged_block_positions(ps, max_pages, pages_per_block) // ps
+    scale = 1.0 / math.sqrt(d)
+
+    qg = q[:, 0].reshape(b, h_kv, rep, d)
+    if rep_p != rep:
+        qg = jnp.concatenate(
+            [qg, jnp.zeros((b, h_kv, rep_p - rep, d), qg.dtype)], axis=2)
+
+    row = lambda bi, *_: (bi, 0, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((1, h_kv, rep_p, d), row),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, h_kv, rep_p, d), row),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, h_kv, ps, d), k_pool.dtype),
+            pltpu.VMEM((2, ppb, h_kv, ps, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((h_kv, rep_p, 128), jnp.float32),
+            pltpu.VMEM((h_kv, rep_p, 128), jnp.float32),
+            pltpu.VMEM((h_kv, rep_p, d), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    with _x64_off():
+        out = pl.pallas_call(
+            functools.partial(_paged_mmha_kernel, ppb=ppb,
+                              max_pages=max_pages, scale=scale),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((b, h_kv, rep_p, d), q.dtype),
+            # rows in order: each starts the next one's first fetch
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
+        )(jnp.reshape(layer, (1,)).astype(jnp.int32),
+          tables.reshape(-1).astype(jnp.int32),
+          jnp.reshape(pos, (-1,)).astype(jnp.int32), qg, k_pool, v_pool)
+    return out[:, :, :rep, :].reshape(b, 1, h, d)
+
+
 def reference_mmha(q, k_buf, v_buf, pos):
     """Composite decode attention (what XLA runs without the kernel):
     grouped einsum over the [B, Hkv, T, D] cache with a <=pos mask.
@@ -175,4 +396,8 @@ def pk_examples():
         ("mmha_decode", mmha_decode,
          (s((8, 1, 32, 128), bf16), s((8, 8, 2048, 128), bf16),
           s((8, 8, 2048, 128), bf16), s((8,), jnp.int32)), {}),
+        ("paged_mmha_decode", paged_mmha_decode,
+         (s((8, 1, 32, 128), bf16), s((2, 257, 8, 16, 128), bf16),
+          s((2, 257, 8, 16, 128), bf16), s((), jnp.int32),
+          s((8, 128), jnp.int32), s((8,), jnp.int32)), {}),
     ]

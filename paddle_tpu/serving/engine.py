@@ -372,11 +372,18 @@ class LLMEngine:
             return int(np.asarray(out.numpy()).reshape(-1)[0])
         return None
 
-    def gathered_positions(self, program: str) -> int:
+    def gathered_positions(self, program: str, lengths=()) -> int:
         """Positions of the pool that one call of `program` ("decode",
-        "verify", "chunk") gathers per layer, whatever is live: read off
-        the shapes its forward gathers (0 before its first call)."""
-        return self._sm.gathered.get(program, 0)
+        "verify", "chunk") reads per layer for live rows holding
+        `lengths` positions each (0 before its first call). A page-table
+        gather reads every slot of every table row whatever is live, from
+        the shapes its forward gathers; the paged decode kernel reads each
+        live row's positions rounded up to its block."""
+        whole, block = self._sm.gathered.get(program, (0, 0))
+        if not block:
+            return whole
+        return int((-(-np.asarray(lengths, np.int64) // block)
+                    * block).sum())
 
     def decode(self, tokens, positions, tables, temps):
         import paddle_tpu as paddle

@@ -24,15 +24,25 @@ Device-side helpers (pure jnp, called inside traced programs):
 
 * :func:`write_token` — scatter one new (k, v) per batch row into its
   page/slot (the decode-step write).
+* :func:`write_token_rows` — the same write for the paged decode path,
+  shaped so that the pool keeps its layout for the kernel that reads it.
 * :func:`write_prefill` — scatter a whole prompt's (k, v) rows, padding
   positions steered to the trash page (the prefill write).
+* :func:`paged_attention` — the decode program's attention, per-row
+  positions. Where the kernels dispatch and the page shape fits their
+  tiles it is ONE Pallas kernel (``ops/kernels/mmha_pallas.py:
+  paged_mmha_decode``) that takes the whole pool, the layer, the page
+  tables and the positions and fetches each row's live pages itself,
+  block by block: no gathered view exists, and a step reads what is
+  live, not ``max_batch x max_seq_len``. Elsewhere (the CPU tests, page
+  shapes the tiles do not admit) it is the composite below over
+  :func:`gather_layer`.
 * :func:`gather_layer` — page-table gather producing the contiguous
-  ``[B, Hkv, T, D]`` view the existing mmha/cached-attention math
-  consumes.
-* :func:`paged_attention` — per-row-position decode attention over the
-  gathered view: the fused mmha Pallas kernel when eligible, else the
-  same grouped-einsum composite as ``models/generation.py:
-  cached_attention`` (interpret-parity-tested against it).
+  ``[B, Hkv, T, D]`` view: what :func:`chunk_attention` (chunked prefill,
+  speculative verify) reads, the decode composite, and the oracle the
+  paged kernel is tested against.
+* :func:`reference_paged_attention` — the composite: the same
+  grouped-einsum math as ``models/generation.py:cached_attention``.
 
 Host-side :class:`PagePool` owns the pool tensors and the accounting.
 Since the prefix cache landed, a non-trash page is in exactly ONE of
@@ -74,12 +84,17 @@ from ..observability import gauge as _obs_gauge, counter as _obs_counter
 __all__ = [
     "PagePool", "PagePoolError", "PagePoolExhausted", "PageDoubleFree",
     "TRASH_PAGE",
-    "write_token", "write_prefill", "gather_layer", "paged_attention",
+    "write_token", "write_token_rows", "write_prefill", "gather_layer",
+    "paged_attention",
     "chunk_attention",
 ]
 
 #: physical page id reserved as the write sink for padding / inactive rows
 TRASH_PAGE = 0
+
+#: :func:`paged_attention_path`'s name for the paged kernel (the other is
+#: "composite"); what ``program_stats()`` shows under path/attention
+PAGED_PATH = "paged_mmha_decode"
 
 _PAGES = _obs_gauge("paddle_tpu_serving_kv_pages",
                     "KV-cache pages by state (free/used/cached/total)")
@@ -402,6 +417,21 @@ def write_token(pool, layer: int, page_ids, slots, vals):
             vals.astype(pool.dtype))
 
 
+def write_token_rows(pool, layer: int, page_ids, slots, vals):
+    """:func:`write_token` for the paged decode path: the same rows land
+    in the same places, scattered as one ``[D]`` row per (batch row, KV
+    head). With ``[Hkv, D]`` windows the compiler lays the whole pool out
+    for the scatter (``Hkv`` next to ``D``) and copies it back to its own
+    layout in front of every kernel that reads it in place; with no
+    window but the last dimension the pool keeps its layout from the
+    program's entry to its exit.
+    """
+    with jax.named_scope("kv_write"):
+        heads = jnp.arange(pool.shape[2], dtype=jnp.int32)
+        return pool.at[layer, page_ids[:, None], heads[None, :],
+                       slots[:, None], :].set(vals.astype(pool.dtype))
+
+
 def write_prefill(pool, layer: int, table_row, prompt_len, vals,
                   page_size: int):
     """Scatter a prompt's k or v rows; positions >= ``prompt_len``
@@ -486,32 +516,52 @@ def reference_paged_attention(q, k_cache, v_cache, pos):
                                       jnp.asarray(pos, jnp.int32))
 
 
-def paged_attention_path(q_shape, cache_shape, cache_dtype) -> str:
-    """Which path :func:`paged_attention` takes for these shapes."""
+def paged_attention_path(q_shape, pool_shape, pool_dtype) -> str:
+    """Which path :func:`paged_attention` takes, from what the code can
+    observe: kernels dispatching, one new token, a head width and page
+    size the paged kernel's tiles admit, the pool's dtype."""
     from ..ops.kernels import mmha_pallas
-    return "mmha_decode" if mmha_pallas.use_kernel(
-        q_shape, cache_shape, cache_dtype) else "composite"
+    return PAGED_PATH if mmha_pallas.use_paged_kernel(
+        q_shape, pool_shape, pool_dtype) else "composite"
 
 
-def paged_attention(q, k_cache, v_cache, pos, interpret=None):
-    """Decode attention over a gathered paged cache, per-row positions.
+def paged_block_positions(path: str, page_size, max_pages) -> int:
+    """Positions in one block of pages that a decode call on `path` reads
+    at a time: a row's read is its live positions rounded up to this. 0 on
+    the composite, whose gather reads every slot of every table row."""
+    if path != PAGED_PATH:
+        return 0
+    from ..ops.kernels import mmha_pallas
+    return mmha_pallas.paged_block_positions(page_size, max_pages)
 
-    Dispatch mirrors ``cached_attention``: the fused mmha Pallas kernel
-    (ops/kernels/mmha_pallas.py — extended to vector ``pos`` for this
-    runtime) when its gate admits the shape, else
-    :func:`reference_paged_attention`. ``interpret=True`` forces the
-    kernel in interpret mode (the parity tests' path);
-    ``interpret=False`` forces the composite.
+
+def paged_attention(q, k_pool, v_pool, layer, tables, pos, interpret=None):
+    """Decode attention of one layer over the paged pool, per-row
+    positions.
+
+    q ``[B, 1, H, D]``; k_pool/v_pool ``[L, P, Hkv, ps, D]`` (the row's
+    new token already written); ``layer`` int; tables ``[B, max_pages]``
+    int32; pos ``[B]`` int32, last valid position per row. The paged
+    Pallas kernel when :func:`paged_attention_path` admits the shapes,
+    else :func:`reference_paged_attention` over :func:`gather_layer`.
+    ``interpret=True`` forces the kernel in interpret mode (the parity
+    tests' path); ``interpret=False`` forces the composite. The kernel
+    call carries the scope ``kv_gather``: the fetch through the page
+    table happens inside it.
     """
     from ..ops.kernels import _common as kern
     from ..ops.kernels import mmha_pallas
 
     pos = jnp.asarray(pos, jnp.int32)
-    if interpret is True:
-        return mmha_pallas.mmha_decode(q, k_cache, v_cache, pos,
-                                       interpret=True)
-    if interpret is None and paged_attention_path(
-            q.shape, k_cache.shape, k_cache.dtype) == "mmha_decode":
-        return mmha_pallas.mmha_decode(q, k_cache, v_cache, pos,
-                                       interpret=kern.interpret_mode())
-    return reference_paged_attention(q, k_cache, v_cache, pos)
+    if interpret is True or (interpret is None and paged_attention_path(
+            q.shape, k_pool.shape, k_pool.dtype) == PAGED_PATH):
+        with jax.named_scope("kv_gather"):
+            # an inactive slot (position 0, an all-trash table) has nothing
+            # live: the kernel scores nothing for it
+            pos = jnp.where(tables[:, 0] == TRASH_PAGE, jnp.int32(-1), pos)
+            return mmha_pallas.paged_mmha_decode(
+                q, k_pool, v_pool, jnp.int32(layer), tables, pos,
+                interpret=interpret is True or kern.interpret_mode())
+    return reference_paged_attention(
+        q, gather_layer(k_pool, layer, tables),
+        gather_layer(v_pool, layer, tables), pos)

@@ -93,8 +93,11 @@ class ServingModel:
         # compiled program's stages took at trace time (the kernel gates
         # below decide per shape; nothing falls back unrecorded)
         self.paths: dict = {}
-        # {program: positions of the pool one layer's page-table gather
-        # reads per call}, from the shapes each forward gathers
+        # {program: (whole, block)}: the positions of the pool one layer
+        # reads per call. A page-table gather reads `whole` (every slot of
+        # every table row, from the shapes the forward gathers) whatever
+        # is live and has block 0; the paged decode kernel reads each
+        # row's live positions rounded up to `block`
         self.gathered: dict = {}
         self._prog = ""
         # fused decode epilogue (block_fused_pallas.decode_epilogue) needs
@@ -168,11 +171,14 @@ class ServingModel:
     def _note(self, stage: str, path: str) -> None:
         self.paths.setdefault(self._prog, {})[stage] = path
 
-    def _note_gather(self, tables) -> None:
-        """The gather of this program reads every slot of every row of
-        `tables` ([rows, max_pages]), whatever is live."""
-        self.gathered[self._prog] = \
-            int(tables.shape[0]) * int(tables.shape[1]) * self.pool.page_size
+    def _note_gather(self, tables, block: int = 0) -> None:
+        """What this program reads of the pool per layer: a gather reads
+        every slot of every row of `tables` ([rows, max_pages]), whatever
+        is live; the paged kernel (`block` > 0) each row's live positions
+        rounded up to its block."""
+        self.gathered[self._prog] = (
+            int(tables.shape[0]) * int(tables.shape[1])
+            * self.pool.page_size, int(block))
 
     # -- shared pieces -------------------------------------------------------
 
@@ -340,7 +346,9 @@ class ServingModel:
         pos = positions._data.astype(jnp.int32)
         tab = tables._data.astype(jnp.int32)
         b = int(tokens.shape[0])
-        self._note_gather(tab)
+        path = kv_cache.paged_attention_path(
+            (b, 1, self.n_head, self.head_dim), pool.k._data.shape,
+            pool.k._data.dtype)
         page_ids = jnp.take_along_axis(tab, (pos // ps)[:, None],
                                        axis=1)[:, 0]
         slots = pos % ps
@@ -359,9 +367,15 @@ class ServingModel:
                     int(layer.mlp.gate_proj.weight.shape[1]),
                     pool.k._data.dtype) for layer in layers):
                 self._note("layer", "block_decode_layer")
+                self._note_gather(tab)
                 return self._decode_forward_fused_layer(
                     tokens, pos, tab, page_ids, slots, sin, cos, b)
             self._note("layer", "composite")
+        self._note("attention", path)
+        self._note_gather(tab, kv_cache.paged_block_positions(
+            path, ps, int(tab.shape[1])))
+        write = kv_cache.write_token_rows if path == kv_cache.PAGED_PATH \
+            else kv_cache.write_token
         fused = self._fused_active()
         x = self.model.embed_tokens(Tensor(tokens._data.reshape(b, 1)))
         hres = x
@@ -371,18 +385,14 @@ class ServingModel:
                 h = y if fused else layer.input_layernorm(x)
                 q, k, v = self._qkv(i, layer, h, b, 1)
                 q, k = F.rope(q, k, sin, cos)
-            kp = kv_cache.write_token(pool.k._data, i, page_ids, slots,
-                                      k._data[:, 0])
-            vp = kv_cache.write_token(pool.v._data, i, page_ids, slots,
-                                      v._data[:, 0])
+            kp = write(pool.k._data, i, page_ids, slots, k._data[:, 0])
+            vp = write(pool.v._data, i, page_ids, slots, v._data[:, 0])
             pool.k._data = kp
             pool.v._data = vp
-            kc = kv_cache.gather_layer(kp, i, tab)
-            vc = kv_cache.gather_layer(vp, i, tab)
             with jax.named_scope("attention"):
-                self._note("attention", kv_cache.paged_attention_path(
-                    q._data.shape, kc.shape, kc.dtype))
-                out = kv_cache.paged_attention(q._data, kc, vc, pos)
+                # the whole pools go in: the paged kernel fetches the
+                # live pages itself (a layer slice here would be copied)
+                out = kv_cache.paged_attention(q._data, kp, vp, i, tab, pos)
                 attn_out = self._linear(
                     "o", i, Tensor(out.reshape(b, 1,
                                                self.n_head * self.head_dim)),

@@ -110,8 +110,10 @@ _PREFILL_TOKENS = _obs_counter(
 _KV_POSITIONS = _obs_counter(
     "paddle_tpu_serving_kv_positions_total",
     "KV positions per layer over all decode and verify steps: kind=live "
-    "those of the contexts in the batch, kind=gathered those the program's "
-    "page-table gather read")
+    "those of the contexts in the batch, kind=gathered those the program "
+    "read of the pool (a page-table gather: every slot of every table row; "
+    "the paged decode kernel: the live rows' positions rounded up to its "
+    "block)")
 _QUEUE = _obs_gauge("paddle_tpu_serving_queue_depth",
                     "requests waiting for admission")
 _ACTIVE = _obs_gauge("paddle_tpu_serving_active_requests",
@@ -1089,9 +1091,8 @@ class Scheduler:
             tables = self._masked_tables()
         with _tracing.span("serving.decode") as sp:
             out = self.programs.decode(tokens, positions, tables, temps)
-        # live rows carry cur_len - 1, the others 0
-        self._count_positions(sp, "decode", len(active),
-                              int(positions.sum()) + len(active))
+        self._count_positions(
+            sp, "decode", [positions[req.slot] + 1 for req in active])
         self._account_step(len(active) / float(self.max_batch),
                            emitted=len(active), rows=len(active))
         with _tracing.span("serving.emit"):
@@ -1102,15 +1103,17 @@ class Scheduler:
                 self._maybe_complete(req)
         return True
 
-    def _count_positions(self, sp, program: str, rows: int,
-                         live: int) -> None:
-        """One batched step's KV positions per layer, live against
-        gathered, on its step span and on the operator's counters. What
-        the program gathers is the engine's to say, from the shapes its
-        forward gathers (a bare fake of `programs` gathers nothing)."""
+    def _count_positions(self, sp, program: str, lengths) -> None:
+        """One batched step's KV positions per layer, live (`lengths` of
+        the live rows) against what the program read of the pool, on its
+        step span and on the operator's counters. What the program reads
+        is the engine's to say: the shapes its forward gathers or, on the
+        paged decode path, the rows' lengths rounded up to the kernel's
+        block (a bare fake of `programs` reads nothing)."""
         fn = getattr(self.programs, "gathered_positions", None)
-        gathered = int(fn(program)) if fn is not None else 0
-        sp.count(rows=rows, positions=live, gathered=gathered)
+        gathered = int(fn(program, lengths)) if fn is not None else 0
+        live = int(sum(lengths))
+        sp.count(rows=len(lengths), positions=live, gathered=gathered)
         _KV_POSITIONS.inc(live, kind="live")
         _KV_POSITIONS.inc(gathered, kind="gathered")
 
@@ -1219,8 +1222,8 @@ class Scheduler:
         with _tracing.span("serving.verify") as sp:
             out, acc = self.programs.verify(tokens, positions, dlens, tables,
                                             temps)
-        self._count_positions(sp, "verify", len(active),
-                              int(positions.sum()) + len(active))
+        self._count_positions(
+            sp, "verify", [positions[req.slot] + 1 for req in active])
         occ = len(active) / float(self.max_batch)
         n_acc = n_emit = 0
         with _tracing.span("serving.emit"):

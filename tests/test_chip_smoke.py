@@ -84,12 +84,12 @@ def test_kernels_in_names_custom_calls_by_their_wrapper():
     import chip_smoke
     hlo = "\n".join([
         '%a = f32[8] custom-call(%x), custom_call_target="tpu_custom_call", '
-        'metadata={op_name="jit(pure_arrays)/jit(mmha_decode)/pallas_call"}',
+        'metadata={op_name="jit(pure_arrays)/attention/kv_gather/jit(paged_mmha_decode)/pallas_call"}',
         '%b = f32[8] custom-call(%x), custom_call_target="tpu_custom_call", '
-        'metadata={op_name="jit(pure_arrays)/jit(mmha_decode)/pallas_call"}',
+        'metadata={op_name="jit(pure_arrays)/attention/kv_gather/jit(paged_mmha_decode)/pallas_call"}',
         '%c = f32[8] custom-call(%x), custom_call_target="Sharding"',
     ])
-    assert chip_smoke.kernels_in(hlo) == {"mmha_decode": 2}
+    assert chip_smoke.kernels_in(hlo) == {"paged_mmha_decode": 2}
 
 
 class _FixedLogits:

@@ -130,8 +130,7 @@ def test_paged_composite_parity_vs_cached_attention():
     q = rng.standard_normal((3, 1, h, d)).astype(np.float32)
     pos = np.asarray([ln - 1 for ln in lengths], np.int32)
     out = np.asarray(kv_cache.paged_attention(
-        jnp.asarray(q), kv_cache.gather_layer(pool_k, 0, tables),
-        kv_cache.gather_layer(pool_v, 0, tables), jnp.asarray(pos),
+        jnp.asarray(q), pool_k, pool_v, 0, tables, jnp.asarray(pos),
         interpret=False))
     for r, ln in enumerate(lengths):
         # contiguous reference: replay the SAME last-token write through
@@ -161,8 +160,9 @@ def test_paged_kernel_interpret_parity_per_row_pos():
     kb = jnp.asarray(rng.standard_normal((b, h_kv, t, d)).astype(np.float32))
     vb = jnp.asarray(rng.standard_normal((b, h_kv, t, d)).astype(np.float32))
     pos = jnp.asarray([3, 31, 62], jnp.int32)
-    out_k = kv_cache.paged_attention(q, kb, vb, pos, interpret=True)
-    out_c = kv_cache.paged_attention(q, kb, vb, pos, interpret=False)
+    from paddle_tpu.ops.kernels import mmha_pallas
+    out_k = mmha_pallas.mmha_decode(q, kb, vb, pos, interpret=True)
+    out_c = kv_cache.reference_paged_attention(q, kb, vb, pos)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_c),
                                rtol=2e-5, atol=2e-5)
 

@@ -315,6 +315,20 @@ def test_mmha_decode_lowers(cfg):
         q, kb, vb))
 
 
+def test_paged_mmha_decode_lowers():
+    """The paged decode-attention kernel (the whole pool left in HBM, page
+    tables, positions and the layer as scalar prefetch, page DMAs in the
+    kernel) lowers for TPU."""
+    from paddle_tpu.ops.kernels import mmha_pallas
+    q = jnp.zeros((4, 1, 8, 128), jnp.bfloat16)
+    pool = jnp.zeros((2, 33, 2, 16, 128), jnp.bfloat16)
+    tables = jnp.zeros((4, 16), jnp.int32)
+    pos = jnp.zeros((4,), jnp.int32)
+    assert_mosaic(lower_tpu(
+        lambda a, kk, vv, t, p: mmha_pallas.paged_mmha_decode(
+            a, kk, vv, jnp.int32(1), t, p), q, pool, pool, tables, pos))
+
+
 def test_swiglu_fwd_bwd_lowers():
     from paddle_tpu.ops.kernels import swiglu_pallas as sg
     g = jnp.zeros((256, 2048), jnp.bfloat16)

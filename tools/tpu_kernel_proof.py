@@ -272,6 +272,27 @@ def main():
         lambda q_, k_, v_: mm.reference_mmha(q_, k_, v_, pos),
         (qd, kb, vb), tol=2e-2)
 
+    # 8b. the serving decode program's attention: the same math straight
+    # from the paged pool [L, P, Hkv, ps, D] through page tables, against
+    # the composite over the page-table gather
+    from paddle_tpu.serving import kv_cache as kvc
+    ps, n_tab = 16, tmax // 16
+    n_pool = bq * n_tab + 1
+    kpool = jnp.asarray(rng.standard_normal((2, n_pool, hkv, ps, dq)),
+                        jnp.bfloat16)
+    vpool = jnp.asarray(rng.standard_normal((2, n_pool, hkv, ps, dq)),
+                        jnp.bfloat16)
+    tabs = jnp.asarray(rng.permutation(np.arange(1, n_pool))
+                       .reshape(bq, n_tab), jnp.int32)
+    posv = jnp.asarray(rng.integers(0, tmax, (bq,)), jnp.int32)
+    fam["paged_mmha_decode"] = run_family(
+        "paged_mmha_decode",
+        lambda q_, k_, v_: mm.paged_mmha_decode(
+            q_, k_, v_, jnp.int32(1), tabs, posv, interpret=interp),
+        lambda q_, k_, v_: kvc.paged_attention(
+            q_, k_, v_, 1, tabs, posv, interpret=False),
+        (qd, kpool, vpool), tol=2e-2)
+
     # 9. weight-only int8 matmul (decode GEMV shape)
     from paddle_tpu.ops.kernels import wo_matmul_pallas as wm
     kk, nn_ = (512, 1024) if interp else (4096, 11008)
