@@ -230,7 +230,10 @@ def phase_serve(tiny: bool, seed: int) -> dict:
                    (("decode", eng._decode_sf), ("prefill", eng._prefill_sf))}
     finally:
         eng.shutdown()
-    leaked, lost = eng.pool.leaked(), eng.pool.lost()
+    # by group of the cache (a model with window layers has two)
+    groups = eng.stats()["pages"]["groups"]
+    leaked = sum(g["used"] for g in groups.values())
+    lost = sum(g["lost"] for g in groups.values())
 
     t0 = time.perf_counter()
     ref = []
@@ -259,6 +262,7 @@ def phase_serve(tiny: bool, seed: int) -> dict:
            "engine_tokens": got, "reference_tokens": ref,
            "decode_steps": stats["decode_steps"], "programs": programs,
            "pages_leaked": leaked, "pages_lost": lost,
+           "page_groups": sorted(groups),
            "tpu_custom_calls": kernels,
            "engine_seconds": round(t_engine, 2),
            "reference_seconds": round(t_ref, 2)}

@@ -26,7 +26,107 @@ from .....autograd.grad_mode import no_grad
 from .....nn.layer import Layer
 from .gate import BaseGate, NaiveGate, GShardGate, SwitchGate
 
-__all__ = ["MoELayer"]
+__all__ = ["MoELayer", "sort_by_expert", "dropless_experts", "row_tile"]
+
+
+# -- routed experts that drop nothing ---------------------------------------
+#
+# No capacity and no [n, e, c] tensor: the (token, choice) rows are sorted by
+# expert, each expert's rows padded to whole row tiles, and two grouped
+# matmuls over the ragged groups (`ops/kernels/moe_gemm_pallas.py`) do the
+# experts' SwiGLU; a weighted gather puts the rows back. Whatever the
+# imbalance, every choice of every token is computed.
+
+#: tokens of one pass through the experts: a longer input is cut into
+#: passes of this many, so that the sorted copies of the tokens (k of each)
+#: stay a fraction of a prefill's memory; the weights are read once a pass
+DROPLESS_CHUNK = 4096
+
+
+def row_tile(rows: int, n_experts: int) -> int:
+    """Row tile of the grouped matmuls for `rows` (token, choice) rows over
+    `n_experts`: the mean group rounded up to a power of two, between the
+    16 rows of a bf16 tile and 256."""
+    mean = max(1, -(-rows // n_experts))
+    return int(min(256, max(16, 1 << (mean - 1).bit_length())))
+
+
+def sort_by_expert(sel, n_experts: int, tile: int):
+    """The padded sorted layout of routed rows. sel [n, k] int32: the
+    experts each token chose, `n_experts` standing for none (a row of
+    padding, an idle slot: sorted behind every expert's rows, in tiles no
+    kernel runs). Returns (src [R] the token that feeds each row of the
+    layout, dest [n, k] the row of each (token, choice), tile_expert
+    [R / tile], used: live tiles, sizes [E] rows per expert). R is static:
+    n k plus a tile short of one for every expert, rounded up to tiles."""
+    n, k = sel.shape
+    rows = n * k
+    total = -(-(rows + n_experts * (tile - 1)) // tile) * tile
+    flat = sel.reshape(-1).astype(jnp.int32)
+    # a row's rank among its expert's rows, in token order: a running count
+    # over one-hot columns (no sort: the groups' order is the experts')
+    onehot = flat[:, None] == jnp.arange(n_experts + 1, dtype=jnp.int32)
+    counts = jnp.cumsum(onehot.astype(jnp.int32), axis=0)
+    rank = jnp.sum(jnp.where(onehot, counts, 0), axis=1) - 1
+    sizes = counts[-1]
+    # every expert's group padded to whole tiles; the rows of none as they are
+    padded = jnp.where(jnp.arange(n_experts + 1) < n_experts,
+                       -(-sizes // tile) * tile, sizes)
+    ends = jnp.cumsum(padded)
+    dest = (ends - padded)[flat] + rank
+    src = jnp.zeros((total,), jnp.int32).at[dest].set(
+        jnp.arange(rows, dtype=jnp.int32) // k)
+    used = ends[n_experts - 1] // tile
+    t = jnp.minimum(jnp.arange(total // tile, dtype=jnp.int32),
+                    jnp.maximum(used - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends[:n_experts], t * tile, side="right"),
+        n_experts - 1).astype(jnp.int32)
+    return src, dest.reshape(n, k), tile_expert, used.astype(jnp.int32), \
+        sizes[:n_experts]
+
+
+def _dropless_pass(x, sel, weights, gate_w, up_w, down_w, interpret):
+    from .....ops.kernels import moe_gemm_pallas as mg
+    n, k = sel.shape
+    e = gate_w.shape[0]
+    tile = row_tile(n * k, e)
+    src, dest, tile_expert, used, sizes = sort_by_expert(sel, e, tile)
+    rows = x[src]                                   # [R, H]
+    if interpret is True or (interpret is None and mg.use_ragged_kernel(
+            x.shape[-1], gate_w.shape[-1], x.dtype)):
+        from .....ops.kernels import _common as kern
+        itp = interpret is True or kern.interpret_mode()
+        h = mg.ragged_swiglu(rows, gate_w, up_w, tile_expert, used, tile,
+                             itp)
+        y = mg.ragged_matmul(h, down_w, tile_expert, used, tile, itp)
+    else:
+        h = mg.reference_grouped_swiglu(rows, gate_w, up_w, tile_expert,
+                                        tile)
+        y = mg.reference_grouped_matmul_ragged(h, down_w, tile_expert, tile)
+    # a row that chose no expert lies in a tile nothing wrote
+    picked = jnp.where((sel < e)[..., None], y[dest].astype(jnp.float32), 0.0)
+    out = jnp.einsum("nk,nkh->nh", weights.astype(jnp.float32), picked)
+    return out.astype(x.dtype), sizes
+
+
+def dropless_experts(x, sel, weights, gate_w, up_w, down_w, interpret=None):
+    """sum_j weights[t, j] * expert_{sel[t, j]}(x[t]) for every token, each
+    expert a SwiGLU, nothing dropped. x [n, H]; sel [n, k] int32 (E: no
+    expert, the row is worked on by none and adds nothing); weights
+    [n, k]; gate_w/up_w [E, H, I]; down_w [E, I, H]. `interpret=True`
+    forces the kernels in interpret mode, `False` the composite. Returns
+    (out [n, H], sizes [E]: rows each expert got)."""
+    n = x.shape[0]
+    if n <= DROPLESS_CHUNK or n % DROPLESS_CHUNK:
+        return _dropless_pass(x, sel, weights, gate_w, up_w, down_w,
+                              interpret)
+    c = n // DROPLESS_CHUNK
+    cut = lambda a: a.reshape(c, DROPLESS_CHUNK, *a.shape[1:])  # noqa: E731
+    out, sizes = jax.lax.map(
+        lambda a: _dropless_pass(*a, gate_w, up_w, down_w, interpret),
+        (cut(x), cut(sel), cut(weights)))
+    return out.reshape(n, -1), jnp.sum(sizes, axis=0)
 
 
 def _functionalize(template: Layer):

@@ -390,18 +390,22 @@ def gumbel_softmax(x, temperature=1.0, hard=False, axis=-1, name=None) -> Tensor
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 name=None) -> Tensor:
+                                 name=None, window=None) -> Tensor:
     """SDPA with [batch, seq, heads, head_dim] layout (reference:
     paddle/phi/kernels/gpu/flash_attn_kernel.cu API). Uses the Pallas flash
-    kernel on TPU when enabled, else an XLA-fused reference path."""
+    kernel on TPU when enabled, else an XLA-fused reference path. `window`
+    (with `is_causal`): query i sees key j iff ``i - window < j <= i``."""
     from ...core.flags import flag
     from ...ops.kernels import flash_attention as fa
     mask_arr = as_tensor(attn_mask)._data if attn_mask is not None else None
 
     if fa.available() and flag("use_pallas_kernels") and dropout_p == 0.0 \
             and mask_arr is None:
-        return apply(lambda q, k, v: fa.flash_attention(q, k, v, causal=is_causal),
-                     query, key, value, name="flash_attention")
+        return apply(lambda q, k, v: fa.flash_attention(
+            q, k, v, causal=is_causal, window=window),
+            query, key, value, name="flash_attention")
+    if window is not None and not is_causal:
+        raise ValueError("window needs is_causal=True")
 
     drop_key = gen_mod.default_generator.split() if dropout_p > 0.0 and training \
         else None
@@ -416,6 +420,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         if is_causal:
             s, t = logits.shape[-2], logits.shape[-1]
             causal = jnp.tril(jnp.ones((s, t), bool), t - s)
+            if window is not None:
+                causal &= ~jnp.tril(jnp.ones((s, t), bool), t - s - window)
             logits = jnp.where(causal, logits, -jnp.inf)
         if mask_arr is not None:
             if jnp.issubdtype(mask_arr.dtype, jnp.bool_):

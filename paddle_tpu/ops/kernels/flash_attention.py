@@ -16,6 +16,7 @@ kernels on CPU via `force_interpret(True)` (Pallas interpret mode).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -38,7 +39,7 @@ def expand_kv_heads(q, k, v):
     return k, v
 
 
-def _reference_attention(q, k, v, causal, segment_ids=None):
+def _reference_attention(q, k, v, causal, segment_ids=None, window=None):
     k, v = expand_kv_heads(q, k, v)
     qh, kh, vh = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
     scale = 1.0 / math.sqrt(q.shape[-1])
@@ -47,7 +48,10 @@ def _reference_attention(q, k, v, causal, segment_ids=None):
     mask = None
     if causal:
         s, t = logits.shape[-2], logits.shape[-1]
-        mask = jnp.tril(jnp.ones((s, t), bool), t - s)[None, None]
+        mask = jnp.tril(jnp.ones((s, t), bool), t - s)
+        if window is not None:      # query i sees key j iff i - window < j
+            mask &= ~jnp.tril(jnp.ones((s, t), bool), t - s - window)
+        mask = mask[None, None]
     if segment_ids is not None:
         same = (segment_ids[:, :, None] == segment_ids[:, None, :])[:, None]
         mask = same if mask is None else (mask & same)
@@ -78,6 +82,17 @@ def _pallas_ok(q) -> bool:
     return s % blk == 0 and blk % 8 == 0
 
 
+#: longest sequence whose k and v the whole-sequence forward keeps in VMEM;
+#: a longer causal one takes the banded forward, which brings them in by block
+WHOLE_KV_MAX_SEQ = 4096
+
+
+def _banded_ok(q) -> bool:
+    s = q.shape[1]
+    return available() and s % min(1024, s) == 0 and min(512, s) % 8 == 0 \
+        and s % min(512, s) == 0
+
+
 @jax.custom_vjp
 def _flash_causal(q, k, v):
     return _flash_impl(q, k, v, True)
@@ -89,6 +104,11 @@ def _flash_full(q, k, v):
 
 
 def _flash_impl(q, k, v, causal):
+    if causal and q.shape[1] > WHOLE_KV_MAX_SEQ and _banded_ok(q):
+        # primal only (inference): k and v too long to keep in VMEM whole
+        from .flash_attention_pallas import flash_attention_forward_banded
+        return flash_attention_forward_banded(q, k, v,
+                                              interpret=interpret_mode())
     if _pallas_ok(q):
         from .flash_attention_pallas import flash_attention_forward
         return flash_attention_forward(q, k, v, causal=causal,
@@ -204,8 +224,38 @@ _flash_seg_full.defvjp(lambda q, k, v, s: _seg_fwd_impl(q, k, v, s, False),
                        lambda res, g: _seg_bwd_impl(False, res, g))
 
 
-def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _flash_banded(q, k, v, window):
+    if _banded_ok(q):
+        from .flash_attention_pallas import flash_attention_forward_banded
+        return flash_attention_forward_banded(q, k, v, window=window,
+                                              interpret=interpret_mode())
+    return _reference_attention(q, k, v, True, window=window)
+
+
+def _banded_fwd(q, k, v, window):
+    return _flash_banded(q, k, v, window), (q, k, v)
+
+
+def _banded_bwd(window, res, g):
+    # serving's forward: the backward is the composite's, [S, S] and all
+    _, vjp = jax.vjp(lambda a, b, c: _reference_attention(
+        a, b, c, True, window=window), *res)
+    return vjp(g)
+
+
+_flash_banded.defvjp(_banded_fwd, _banded_bwd)
+
+
+def flash_attention(q, k, v, causal: bool = False, segment_ids=None,
+                    window=None):
     """[B, S, H, D] attention; fused Pallas forward+backward on TPU.
+
+    `window` (causal only): query i sees key j iff ``i - window < j <= i``.
+    With a window the forward is the banded kernel (k blocks wholly before
+    the window are never fetched) and the backward the composite's; without
+    one, a causal primal-only call over more than WHOLE_KV_MAX_SEQ positions
+    takes the banded kernel too (k and v by block, not whole in VMEM).
 
     k/v may carry fewer heads than q (GQA/MQA): the kernels read each shared
     kv head directly via the block index map instead of materializing the
@@ -215,8 +265,12 @@ def flash_attention(q, k, v, causal: bool = False, segment_ids=None):
     the packed-varlen masking of the reference's flash_attn_unpadded
     (paddle/phi/kernels/gpu/flash_attn_kernel.cu varlen path), with causal
     applied inside each segment when both are set."""
+    if window is not None and (not causal or segment_ids is not None):
+        raise ValueError("a window needs causal=True and no segment_ids")
     if segment_ids is not None:
         seg = jnp.asarray(segment_ids, jnp.int32)
         return (_flash_seg_causal(q, k, v, seg) if causal
                 else _flash_seg_full(q, k, v, seg))
+    if window is not None:
+        return _flash_banded(q, k, v, int(window))
     return _flash_causal(q, k, v) if causal else _flash_full(q, k, v)

@@ -16,6 +16,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ._common import x64_off, jit_x64_off
 
@@ -203,6 +204,124 @@ def flash_attention_forward(q, k, v, causal=False, block_q=256, block_k=256,
     segment masking as in flash_attention_forward_lse."""
     return _fwd_common(q, k, v, segment_ids, causal, block_q, block_k,
                        interpret, with_lse=False)
+
+
+# -- banded forward: a lower bound on the keys a query block reads ----------
+
+def _banded_kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *, window,
+                   block_q, block_k, scale):
+    # grid (B*H, q blocks, k steps); q_ref/o_ref [1, block_q, d], k/v_ref
+    # [1, block_k, d]: step j of q block qi holds k block first(qi) + j (the
+    # index map clamps past the diagonal, so a step beyond it brings nothing
+    # in and is skipped here). m_s/l_s [block_q, 128] lane-broadcast, acc_s
+    # [block_q, d], all f32, carried over the k steps.
+    qi, j = pl.program_id(1), pl.program_id(2)
+    first = _first_k_block(qi, window, block_q, block_k)
+    last = (qi * block_q + block_q - 1) // block_k
+    kb = first + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
+        l_s[...] = jnp.zeros(l_s.shape, jnp.float32)
+        acc_s[...] = jnp.zeros(acc_s.shape, jnp.float32)
+
+    @pl.when(kb <= last)
+    def _score():
+        k, v = k_ref[0], v_ref[0]
+        # operands as they are stored (bf16 on the MXU), scores in f32
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        k_pos = kb * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        valid = q_pos >= k_pos
+        if window is not None:
+            valid &= k_pos > q_pos - window
+        s = jnp.where(valid, s, jnp.float32(NEG_INF))
+        m_prev = m_s[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # a row with no key yet in this block keeps m at NEG_INF, where
+        # exp(s - m) would read 1 for every masked entry
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_s[...] = jnp.broadcast_to(
+            alpha * l_s[:, :1] + jnp.sum(p, axis=1, keepdims=True), l_s.shape)
+        acc_s[...] = alpha * acc_s[...] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_s[...] = jnp.broadcast_to(m_new, m_s.shape)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        o_ref[0] = (acc_s[...] / jnp.maximum(
+            l_s[:, :1], jnp.float32(1e-30))).astype(o_ref.dtype)
+
+
+def _first_k_block(qi, window, block_q, block_k):
+    """The first k block that holds a key some query of q block `qi` sees:
+    0 without a window, else the block of position qi*block_q - window + 1."""
+    if window is None:
+        return qi * 0
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def banded_k_steps(s, window, block_q, block_k) -> int:
+    """k blocks a q block can need: all of them without a window, else those
+    a span of window - 1 + block_q positions can touch."""
+    n_k = s // block_k
+    if window is None:
+        return n_k
+    return min(n_k, (window - 1 + block_q - 1) // block_k + 2)
+
+
+@functools.partial(jit_x64_off, static_argnames=("window", "block_q",
+                                                 "block_k", "interpret"))
+def flash_attention_forward_banded(q, k, v, window=None, block_q=1024,
+                                   block_k=512, interpret=False):
+    """Causal primal-only forward in which query i sees key j iff
+    ``i - window < j <= i`` (``window=None``: plain causal). Unlike
+    :func:`flash_attention_forward` it brings k and v in block by block, so a
+    sequence need not fit VMEM whole, and a q block reads only the k blocks
+    from the first one inside its window to its diagonal: those wholly before
+    the window are neither fetched nor scored. [B, S, H, D] layout, GQA as
+    in the other forwards."""
+    b, s, h, d = q.shape
+    h_kv = k.shape[2]
+    if h % h_kv:
+        raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
+    block_q, block_k = min(block_q, s), min(block_k, s)
+    if s % block_q or s % block_k:
+        raise ValueError(f"seq {s} must divide block sizes {block_q}/{block_k}")
+    steps = banded_k_steps(s, window, block_q, block_k)
+    qt = jnp.swapaxes(q, 1, 2).reshape(b * h, s, d)
+    kt = jnp.swapaxes(k, 1, 2).reshape(b * h_kv, s, d)
+    vt = jnp.swapaxes(v, 1, 2).reshape(b * h_kv, s, d)
+    kv_row = _kv_index_map(h, h_kv)
+
+    def kv_map(bi, qi, j):
+        last = (qi * block_q + block_q - 1) // block_k
+        kb = _first_k_block(qi, window, block_q, block_k) + j
+        return (kv_row(bi, qi)[0], jnp.minimum(kb, last), 0)
+
+    blk_q = pl.BlockSpec((1, block_q, d), lambda bi, qi, j: (bi, qi, 0))
+    blk_k = pl.BlockSpec((1, block_k, d), kv_map)
+    with x64_off():
+        out = pl.pallas_call(
+            functools.partial(_banded_kernel, window=window, block_q=block_q,
+                              block_k=block_k, scale=1.0 / math.sqrt(d)),
+            grid=(b * h, s // block_q, steps),
+            in_specs=[blk_q, blk_k, blk_k],
+            out_specs=blk_q,
+            out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((block_q, LSE_LANES), jnp.float32),
+                            pltpu.VMEM((block_q, LSE_LANES), jnp.float32),
+                            pltpu.VMEM((block_q, d), jnp.float32)],
+            interpret=interpret,
+        )(qt, kt, vt)
+    return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
@@ -465,6 +584,9 @@ def pk_examples():
         ("fwd_causal", flash_attention_forward, (q, kv, kv),
          dict(causal=True)),
         ("fwd_lse", flash_attention_forward_lse, (q, kv, kv), {}),
+        ("fwd_banded", flash_attention_forward_banded,
+         (s((1, 4096, 8, 128), bf16), s((1, 4096, 2, 128), bf16),
+          s((1, 4096, 2, 128), bf16)), dict(window=2048)),
         ("bwd_causal", flash_attention_backward,
          (q, kv, kv, full, lse, full), dict(causal=True)),
     ]

@@ -175,9 +175,9 @@ def mmha_decode(q, k_buf, v_buf, pos, block_t=BLOCK_T, interpret=False):
 PAGES_PER_BLOCK = 8
 
 
-def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
-                       o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s, slot_s,
-                       *, ppb, max_pages, scale):
+def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, lo_ref, q_ref, k_hbm,
+                       v_hbm, o_ref, k_buf, v_buf, sems, m_s, l_s, acc_s,
+                       slot_s, *, ppb, max_pages, scale):
     # grid (B,), one row a step, run in order. q_ref/o_ref
     # [1, Hkv, rep_p, D]; k_hbm/v_hbm the WHOLE pool [L, P, Hkv, ps, D],
     # left in HBM; k_buf/v_buf [2, ppb, Hkv, ps, D] VMEM (two slots);
@@ -191,10 +191,18 @@ def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
     h_kv, ps, d = k_buf.shape[2:]
     rep_p = q_ref.shape[2]
     bt = ppb * ps
-    # position-bounded: only the blocks that hold a live position. A row
-    # with nothing live (pos < 0) still takes the one block the row before
-    # it started fetching, and scores nothing
-    n_blocks = jnp.maximum(pos, 0) // jnp.int32(bt) + jnp.int32(1)
+    # position-bounded at both ends: only the blocks that hold a position
+    # in lo .. pos (lo: the first key the row's query sees, 0 without a
+    # window). A row with nothing live (pos < 0) still takes the one block
+    # the row before it started fetching, and scores nothing
+
+    def first_block(row):
+        return jnp.minimum(lo_ref[row], jnp.maximum(pos_ref[row], 0)) \
+            // jnp.int32(bt)
+
+    lo = lo_ref[b]
+    first = first_block(b)
+    n_blocks = jnp.maximum(pos, 0) // jnp.int32(bt) + jnp.int32(1) - first
 
     def fetch(row, blk, slot):
         for j in range(ppb):
@@ -217,7 +225,7 @@ def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
     @pl.when(b == 0)
     def _first():
         slot_s[0] = jnp.int32(0)
-        fetch(b, jnp.int32(0), jnp.int32(0))
+        fetch(b, first, jnp.int32(0))
 
     slot0 = slot_s[0]
     m_s[...] = jnp.full(m_s.shape, NEG_INF, jnp.float32)
@@ -226,7 +234,7 @@ def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
 
     def score_block(i, slot):
         t_idx = i * bt + jax.lax.broadcasted_iota(jnp.int32, (rep_p, bt), 1)
-        live = t_idx <= pos
+        live = (t_idx <= pos) & (t_idx >= lo)
         for g in range(h_kv):   # one page DMA serves every KV head
             k = k_buf[slot, :, g].reshape(bt, d)
             v = v_buf[slot, :, g].reshape(bt, d)
@@ -256,13 +264,14 @@ def _paged_mmha_kernel(layer_ref, tables_ref, pos_ref, q_ref, k_hbm, v_hbm,
 
         @pl.when(nrow < n_rows)
         def _prefetch():
-            fetch(nrow, jnp.where(last, 0, i + 1), 1 - slot)
+            fetch(nrow, jnp.where(last, first_block(nrow), first + i + 1),
+                  1 - slot)
 
         wait(slot)
 
         @pl.when(pos >= 0)
         def _score():
-            score_block(i, slot)
+            score_block(first + i, slot)
         return carry
 
     jax.lax.fori_loop(jnp.int32(0), n_blocks, body, jnp.int32(0))
@@ -302,7 +311,7 @@ def paged_block_positions(page_size, max_pages,
 
 @functools.partial(jit_x64_off,
                    static_argnames=("pages_per_block", "interpret"))
-def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos,
+def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos, lo=None,
                       pages_per_block=PAGES_PER_BLOCK, interpret=False):
     """Decode attention straight from the paged pool.
 
@@ -311,10 +320,13 @@ def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos,
     of them is ever built); layer: traced int32 scalar; tables
     [B, max_pages] int32 (physical page of each logical page); pos [B]
     int32, last valid position per row (its token already written), or -1
-    for a row with nothing live. A row reads ceil((pos+1) /
-    (pages_per_block*ps)) blocks of pages, the last one masked per element;
-    a row with nothing live reads the first block of its table, scores
-    nothing and returns zeros. Returns [B, 1, H, D]."""
+    for a row with nothing live; lo [B] int32 or None: the first position
+    each row's query sees (a window layer: pos - window + 1, not below 0;
+    None: 0, every position up to pos). A row reads the blocks of
+    pages_per_block*ps positions from the one that holds lo to the one that
+    holds pos, both masked per element: what lies behind the window is
+    neither fetched nor scored. A row with nothing live reads one block,
+    scores nothing and returns zeros. Returns [B, 1, H, D]."""
     b, s, h, d = q.shape
     if s != 1:
         raise ValueError(
@@ -332,8 +344,10 @@ def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos,
             [qg, jnp.zeros((b, h_kv, rep_p - rep, d), qg.dtype)], axis=2)
 
     row = lambda bi, *_: (bi, 0, 0, 0)  # noqa: E731
+    lo = jnp.zeros((b,), jnp.int32) if lo is None else \
+        jnp.maximum(jnp.reshape(lo, (-1,)).astype(jnp.int32), 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, h_kv, rep_p, d), row),
@@ -363,16 +377,17 @@ def paged_mmha_decode(q, k_pool, v_pool, layer, tables, pos,
             interpret=interpret,
         )(jnp.reshape(layer, (1,)).astype(jnp.int32),
           tables.reshape(-1).astype(jnp.int32),
-          jnp.reshape(pos, (-1,)).astype(jnp.int32), qg, k_pool, v_pool)
+          jnp.reshape(pos, (-1,)).astype(jnp.int32), lo, qg, k_pool, v_pool)
     return out[:, :, :rep, :].reshape(b, 1, h, d)
 
 
-def reference_mmha(q, k_buf, v_buf, pos):
+def reference_mmha(q, k_buf, v_buf, pos, lo=None):
     """Composite decode attention (what XLA runs without the kernel):
     grouped einsum over the [B, Hkv, T, D] cache with a <=pos mask.
     `pos` is a scalar (uniform decode) or [B] vector (the serving
     runtime's per-row lengths) — ONE composite for both, so the training
-    and serving decode paths can never diverge."""
+    and serving decode paths can never diverge. `lo` (scalar or [B]): the
+    first position seen, a window layer's lower bound."""
     b, s, h, d = q.shape
     h_kv, t = k_buf.shape[1], k_buf.shape[2]
     rep = h // h_kv
@@ -381,7 +396,10 @@ def reference_mmha(q, k_buf, v_buf, pos):
                         k_buf.astype(jnp.float32)) / math.sqrt(d)
     # scalar pos -> [1,1,1,1,1], vector [B] -> [B,1,1,1,1]: same mask rule
     pos_b = jnp.reshape(jnp.asarray(pos), (-1, 1, 1, 1, 1))
-    mask = jnp.arange(t)[None, None, None, None, :] <= pos_b
+    t_idx = jnp.arange(t)[None, None, None, None, :]
+    mask = t_idx <= pos_b
+    if lo is not None:
+        mask &= t_idx >= jnp.reshape(jnp.asarray(lo), (-1, 1, 1, 1, 1))
     logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bgrst,bgtd->bsgrd", probs, v_buf.astype(jnp.float32))

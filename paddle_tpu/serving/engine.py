@@ -67,6 +67,10 @@ class ServingConfig:
     max_new_tokens, temperature) rides in VALUES, never in shapes."""
     page_size: int = 16          # token positions per KV page
     num_pages: int = 64          # pool pages incl. the reserved trash page
+    #                              (a model with window layers: the pages of
+    #                              the GLOBAL group, every page of a row; the
+    #                              window group holds what max_batch rows can
+    #                              hold at once, so it never runs out)
     max_batch: int = 8           # decode slots (the continuous batch)
     max_seq_len: int | None = None   # default: model max_position_embeddings
     prefill_buckets: tuple | None = None  # default: powers of two
@@ -136,11 +140,42 @@ class LLMEngine:
                 f"max_seq_len {max_seq} exceeds the model's "
                 f"max_position_embeddings {self._sm.max_pos}")
         self.max_seq_len = int(max_seq)
-        self.pool = PagePool(
-            num_layers=len(model.layers), num_pages=cfg.num_pages,
-            num_kv_heads=self._sm.n_kv, page_size=cfg.page_size,
-            head_dim=self._sm.head_dim, dtype=cfg.dtype)
-        self._sm.bind_pool(self.pool)
+        sm = self._sm
+        if not sm.plain:
+            # what each of these would need first stands in ROADMAP B4
+            refused = [
+                ("prefix_cache=True", cfg.prefix_cache,
+                 "one sharing rule for every layer cannot hold where window "
+                 "pages are freed behind the window, and a hit's suffix runs "
+                 "as a prefill chunk" if sm.window else
+                 "a hit's suffix runs as a prefill chunk"),
+                (f"prefill_chunk={cfg.prefill_chunk}",
+                 cfg.prefill_chunk is not None,
+                 "the chunk program knows the plain Llama layer and one "
+                 "page table alone"),
+                (f"spec_k={cfg.spec_k}", cfg.spec_k > 0,
+                 "the verify program knows the plain Llama layer and one "
+                 "page table alone")]
+            for what, asked, why in refused:
+                if asked:
+                    raise ValueError(
+                        f"{what} is refused for {type(model).__name__}: "
+                        f"{why}")
+        kv = dict(num_kv_heads=sm.n_kv, page_size=cfg.page_size,
+                  head_dim=sm.head_dim, dtype=cfg.dtype)
+        # pages by layer kind: the global layers keep every page of a row,
+        # the window layers those inside the window (each group its own
+        # tensors, allocator and page table)
+        self.pool = PagePool(num_layers=sm.n_global_layers,
+                             num_pages=cfg.num_pages, **kv)
+        self.window_pool = None
+        if sm.window:
+            from .kv_cache import window_pages
+            self.window_pool = PagePool(
+                num_layers=sm.n_window_layers,
+                num_pages=1 + cfg.max_batch
+                * window_pages(sm.window, cfg.page_size), **kv)
+        sm.bind_pool(self.pool, self.window_pool)
         if cfg.prefill_chunk is not None and cfg.prefill_chunk < 1:
             raise ValueError(
                 f"prefill_chunk must be >= 1 tokens, got {cfg.prefill_chunk}")
@@ -172,7 +207,9 @@ class LLMEngine:
                                    prefill_chunk=cfg.prefill_chunk,
                                    prefill_budget=cfg.prefill_budget,
                                    spec_k=cfg.spec_k,
-                                   spec_adaptive=cfg.spec_adaptive)
+                                   spec_adaptive=cfg.spec_adaptive,
+                                   window_pool=self.window_pool,
+                                   window=sm.window)
         self.buckets = tuple(sorted(cfg.prefill_buckets)) \
             if cfg.prefill_buckets else _auto_buckets(self.max_seq_len)
         if self.buckets[-1] < self.max_seq_len:
@@ -183,6 +220,7 @@ class LLMEngine:
         self._key_t = Tensor(np.asarray(
             jax.random.PRNGKey(cfg.seed), dtype=np.uint32))
         self._step_seq = 0
+        self.last_counts: dict = {}
         self.tuning = None  # autotune entry (or None) for bench/telemetry
         if self._sm._fused_layer_active():
             # the measured block_i must be installed BEFORE the one
@@ -216,25 +254,32 @@ class LLMEngine:
     def _build_programs(self):
         sm, eng = self._sm, self
 
+        def with_counts(nxt):
+            # the forward's counts (experts hit) ride out behind the tokens
+            import jax.numpy as jnp
+            counts = sm.take_counts()
+            return Tensor(nxt if counts is None
+                          else jnp.concatenate([nxt, counts]))
+
         def serving_decode_step(tokens, positions, tables, temps, key,
-                                step):
+                                step, *window_tables):
             with no_grad():
-                logits = sm.decode_forward(tokens, positions, tables)
-            nxt = eng._sample(logits._data, temps._data, key._data,
-                              step._data)
-            return Tensor(nxt)
+                logits = sm.decode_forward(tokens, positions, tables,
+                                           *window_tables)
+            return with_counts(eng._sample(logits._data, temps._data,
+                                           key._data, step._data))
 
         serving_decode_step.__qualname__ = DECODE_PROGRAM
         self._decode_sf = to_static(serving_decode_step,
                                     donate_state=self.config.donate_state)
 
         def serving_prefill(tokens, prompt_len, table_row, temp, key,
-                            step):
+                            step, *window_row):
             with no_grad():
-                logits = sm.prefill_forward(tokens, prompt_len, table_row)
-            nxt = eng._sample(logits._data, temp._data.reshape(1),
-                              key._data, step._data)
-            return Tensor(nxt)
+                logits = sm.prefill_forward(tokens, prompt_len, table_row,
+                                            *window_row)
+            return with_counts(eng._sample(
+                logits._data, temp._data.reshape(1), key._data, step._data))
 
         serving_prefill.__qualname__ = PREFILL_PROGRAM
         self._prefill_sf = to_static(serving_prefill,
@@ -324,20 +369,24 @@ class LLMEngine:
         bucket = self.bucket_for(len(ctx))
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :len(ctx)] = ctx
-        row = np.zeros(self.scheduler.max_pages, np.int32)
-        row[:len(req.pages)] = req.pages
+        def row_of(pages):
+            row = np.zeros(self.scheduler.max_pages, np.int32)
+            row[:len(pages)] = pages
+            return paddle.to_tensor(row)
+
         step = self._step_seq
         self._step_seq += 1
         out = self._prefill_sf(
             paddle.to_tensor(toks),
             paddle.to_tensor(np.int32(len(ctx))),
-            paddle.to_tensor(row),
+            row_of(req.pages),
             paddle.to_tensor(np.float32(max(req.temperature, 0.0))),
             self._key_t,
-            paddle.to_tensor(np.int32(step)))
+            paddle.to_tensor(np.int32(step)),
+            *([row_of(req.window_pages)] if self.window_pool else []))
         self._last_step_wall = time.time()
         req.prefilled = len(ctx)
-        return int(np.asarray(out.numpy()).reshape(-1)[0])
+        return self._tokens_of(out, 1)[0]
 
     def prefill_chunk(self, req: Request, n: int):
         """Run ONE chunk of ``req``'s prefill: ``n`` context tokens from
@@ -372,20 +421,40 @@ class LLMEngine:
             return int(np.asarray(out.numpy()).reshape(-1)[0])
         return None
 
-    def gathered_positions(self, program: str, lengths=()) -> int:
+    @property
+    def experts_total(self) -> int:
+        """Experts of all the model's routed layers: what `experts_hit` of
+        a step span is counted against."""
+        return self._sm.routed_layers * self._sm.n_experts
+
+    def _tokens_of(self, out, n: int):
+        """The first `n` values of a program's output as token ids; what
+        rides behind them (`ServingModel.take_counts`) is kept as
+        :attr:`last_counts` for the scheduler's step span."""
+        arr = np.asarray(out.numpy()).reshape(-1)
+        self.last_counts = {"experts_hit": int(arr[n])} if arr.size > n \
+            else {}
+        return arr[:n]
+
+    def gathered_positions(self, program: str, lengths=(),
+                           window: bool = False) -> int:
         """Positions of the pool that one call of `program` ("decode",
         "verify", "chunk") reads per layer for live rows holding
         `lengths` positions each (0 before its first call). A page-table
         gather reads every slot of every table row whatever is live, from
         the shapes its forward gathers; the paged decode kernel reads each
-        live row's positions rounded up to its block."""
+        live row's positions rounded up to its block and, in a layer of the
+        `window` group, from the block that holds the first position inside
+        the row's window on."""
         whole, block = self._sm.gathered.get(program, (0, 0))
         if not block:
             return whole
-        return int((-(-np.asarray(lengths, np.int64) // block)
-                    * block).sum())
+        n = np.asarray(lengths, np.int64)
+        first = np.maximum(n - self._sm.window, 0) // block if window \
+            else 0
+        return int(((-(-n // block) - first) * block).sum())
 
-    def decode(self, tokens, positions, tables, temps):
+    def decode(self, tokens, positions, tables, temps, window_tables=None):
         import paddle_tpu as paddle
         step = self._step_seq
         self._step_seq += 1
@@ -393,6 +462,8 @@ class LLMEngine:
             args = (paddle.to_tensor(tokens), paddle.to_tensor(positions),
                     paddle.to_tensor(tables), paddle.to_tensor(temps),
                     self._key_t, paddle.to_tensor(np.int32(step)))
+            if window_tables is not None:
+                args += (paddle.to_tensor(window_tables),)
         with _tracing.span("engine.dispatch"):
             out = self._decode_sf(*args)
         self._last_step_wall = time.time()
@@ -403,7 +474,7 @@ class LLMEngine:
                            active=len(self.scheduler.active_requests()),
                            free_pages=self.pool.free_pages)
         with _tracing.span("engine.pull"):   # device time + the copy back
-            return np.asarray(out.numpy())
+            return self._tokens_of(out, len(tokens))
 
     def verify(self, tokens, positions, dlens, tables, temps):
         """One speculative verify step: tokens ``[B, spec_k+1]`` (last
@@ -557,13 +628,17 @@ class LLMEngine:
         n_active = self.scheduler.abort_active(
             "engine shut down before completion" if not drain
             else "drain timeout exceeded")
-        leaked = self.pool.leaked()
+        leaked = sum(p.leaked() for p in self._pools())
         summary = {"drained": drain, "failed_queued": n_queued,
                    "failed_active": n_active,
                    "completed": self.scheduler.completed,
                    "pages_leaked": leaked}
         _flight.record("serving_drain", **summary)
         return summary
+
+    def _pools(self):
+        """The cache's groups: global, then window where the model has it."""
+        return [p for p in (self.pool, self.window_pool) if p is not None]
 
     def close(self):
         self.shutdown(drain=False)
@@ -658,6 +733,7 @@ class LLMEngine:
     def stats(self) -> dict:
         sched = self.scheduler
         steps = sched.decode_steps
+        groups = self._page_stats()
         return {
             "queue_depth": sched.queue_depth(),
             "active_requests": len(sched.active_requests()),
@@ -666,17 +742,23 @@ class LLMEngine:
             "completed": sched.completed,
             "evictions": sched.evictions,
             "occupancy_mean": (sched.occupancy_sum / steps) if steps else 0.0,
-            "pages": {"free": self.pool.free_pages,
-                      "used": self.pool.used_pages,
-                      "cached": self.pool.cached_pages,
-                      "shared": self.pool.shared_pages,
-                      "lost": self.pool.lost(),
-                      "total": self.pool.allocatable},
+            # summed over the cache's groups; `groups` gives each
+            "pages": dict(
+                {k: sum(g[k] for g in groups.values())
+                 for k in ("free", "used", "cached", "shared", "lost",
+                           "total")}, groups=groups),
             "prefix_cache": sched.prefix_stats(),
             "prefill_chunks": sched.chunks,
             "speculative": sched.spec_stats(),
             "programs": self.program_stats(),
         }
+
+    def _page_stats(self) -> dict:
+        names = ("global", "window")
+        return {name: {"free": p.free_pages, "used": p.used_pages,
+                       "cached": p.cached_pages, "shared": p.shared_pages,
+                       "lost": p.lost(), "total": p.allocatable}
+                for name, p in zip(names, self._pools())}
 
     def health(self, stall_after_s: float = 120.0) -> tuple[int, dict]:
         """Serving liveness: (http_code, payload). Healthy while idle;

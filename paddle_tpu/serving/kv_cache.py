@@ -86,7 +86,7 @@ __all__ = [
     "TRASH_PAGE",
     "write_token", "write_token_rows", "write_prefill", "gather_layer",
     "paged_attention",
-    "chunk_attention",
+    "chunk_attention", "window_pages", "window_first_page",
 ]
 
 #: physical page id reserved as the write sink for padding / inactive rows
@@ -500,7 +500,7 @@ def chunk_attention(q, k_cache, v_cache, start):
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
-def reference_paged_attention(q, k_cache, v_cache, pos):
+def reference_paged_attention(q, k_cache, v_cache, pos, lo=None):
     """Composite decode attention with PER-ROW positions over the
     gathered paged view: delegates to
     ``ops/kernels/mmha_pallas.py:reference_mmha`` (which accepts vector
@@ -509,11 +509,12 @@ def reference_paged_attention(q, k_cache, v_cache, pos):
     no way to diverge.
 
     q ``[B, 1, H, D]``; k/v_cache ``[B, Hkv, T, D]``; pos ``[B]`` int32,
-    last valid cache index per row. Returns ``[B, 1, H, D]``.
+    last valid cache index per row; lo ``[B]`` int32 or None, the first
+    (a window layer's lower bound). Returns ``[B, 1, H, D]``.
     """
     from ..ops.kernels import mmha_pallas
     return mmha_pallas.reference_mmha(q, k_cache, v_cache,
-                                      jnp.asarray(pos, jnp.int32))
+                                      jnp.asarray(pos, jnp.int32), lo)
 
 
 def paged_attention_path(q_shape, pool_shape, pool_dtype) -> str:
@@ -523,6 +524,19 @@ def paged_attention_path(q_shape, pool_shape, pool_dtype) -> str:
     from ..ops.kernels import mmha_pallas
     return PAGED_PATH if mmha_pallas.use_paged_kernel(
         q_shape, pool_shape, pool_dtype) else "composite"
+
+
+def window_pages(window: int, page_size: int) -> int:
+    """Pages of a window group that one row can hold at a time: the pages
+    `window` consecutive positions can touch."""
+    return -(-int(window) // int(page_size)) + 1
+
+
+def window_first_page(length: int, window: int, page_size: int) -> int:
+    """First logical page of a window layer that a row holding `length`
+    positions still needs: the page of the first key that the query at the
+    row's last position, and so any later one, can see."""
+    return max(0, int(length) - int(window)) // int(page_size)
 
 
 def paged_block_positions(path: str, page_size, max_pages) -> int:
@@ -535,9 +549,14 @@ def paged_block_positions(path: str, page_size, max_pages) -> int:
     return mmha_pallas.paged_block_positions(page_size, max_pages)
 
 
-def paged_attention(q, k_pool, v_pool, layer, tables, pos, interpret=None):
+def paged_attention(q, k_pool, v_pool, layer, tables, pos, interpret=None,
+                    window=None, live=None):
     """Decode attention of one layer over the paged pool, per-row
-    positions.
+    positions. `window` (a window layer, whose pool and tables are its
+    group's): the row's query sees the last `window` positions up to `pos`
+    alone, and the kernel starts at the first block that holds one. `live`
+    ``[B]`` bool says which rows hold anything (default: those whose table
+    does not start with the trash page, which a window group's may).
 
     q ``[B, 1, H, D]``; k_pool/v_pool ``[L, P, Hkv, ps, D]`` (the row's
     new token already written); ``layer`` int; tables ``[B, max_pages]``
@@ -553,15 +572,18 @@ def paged_attention(q, k_pool, v_pool, layer, tables, pos, interpret=None):
     from ..ops.kernels import mmha_pallas
 
     pos = jnp.asarray(pos, jnp.int32)
+    lo = None if window is None else jnp.maximum(pos - (int(window) - 1), 0)
     if interpret is True or (interpret is None and paged_attention_path(
             q.shape, k_pool.shape, k_pool.dtype) == PAGED_PATH):
         with jax.named_scope("kv_gather"):
             # an inactive slot (position 0, an all-trash table) has nothing
             # live: the kernel scores nothing for it
-            pos = jnp.where(tables[:, 0] == TRASH_PAGE, jnp.int32(-1), pos)
+            if live is None:
+                live = tables[:, 0] != TRASH_PAGE
+            pos = jnp.where(live, pos, jnp.int32(-1))
             return mmha_pallas.paged_mmha_decode(
-                q, k_pool, v_pool, jnp.int32(layer), tables, pos,
+                q, k_pool, v_pool, jnp.int32(layer), tables, pos, lo,
                 interpret=interpret is True or kern.interpret_mode())
     return reference_paged_attention(
         q, gather_layer(k_pool, layer, tables),
-        gather_layer(v_pool, layer, tables), pos)
+        gather_layer(v_pool, layer, tables), pos, lo)

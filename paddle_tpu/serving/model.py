@@ -16,6 +16,23 @@ v_proj, o_proj)`` / ``post_attention_layernorm`` / ``mlp(gate_proj,
 up_proj, down_proj)``, rotate-half RoPE, and a final ``_head`` (or
 ``norm`` + ``lm_head``/tied embeddings). A model missing the contract
 raises at adapter construction with the missing pieces named.
+
+What else a layer may be is asked of the layer, and the path follows the
+answers (``models/afmoe.py`` gives all of them):
+
+* attention: ``self_attn.window`` (an int: the query sees the last so many
+  positions, and the layer's KV lives in the window group of the cache;
+  None or absent: global), ``self_attn.use_rope`` (absent: True),
+  ``self_attn.qkv(h)`` -> (q, k, v, gate) and ``self_attn.out(attn, gate)``
+  (per-head norms, an output gate; absent: the four plain projections);
+* feed-forward: ``mlp.routed(y)`` -> (out, experts_hit) for routed experts
+  (absent: dense, ``mlp(y)``);
+* norms: ``pre_mlp_layernorm`` and ``post_mlp_layernorm`` beside the usual
+  two make the layer ``h += norm(attn(norm(h))); h += norm(mlp(norm(h)))``;
+* the model's ``_embed(ids)`` where the embedding is scaled.
+
+Chunked prefill, the speculative verify and the fused junctions know the
+plain Llama layer alone (:attr:`ServingModel.plain`).
 """
 
 from __future__ import annotations
@@ -83,12 +100,43 @@ class ServingModel:
             raise TypeError(
                 "ServingModel needs a Llama-family module layout; "
                 f"{type(model).__name__} is missing: {', '.join(missing)}")
+        # each layer's kinds, asked of the layer
+        self.windows = [getattr(layer.self_attn, "window", None)
+                        for layer in layers]
+        if len({w for w in self.windows if w}) > 1:
+            raise TypeError("ServingModel keeps one window group: the "
+                            f"layers' windows differ ({self.windows})")
+        self.window = next((int(w) for w in self.windows if w), None)
+        # layer -> its index inside its group's pool (window or global)
+        self.group_index, counts = [], {True: 0, False: 0}
+        for w in self.windows:
+            self.group_index.append(counts[bool(w)])
+            counts[bool(w)] += 1
+        self.n_window_layers, self.n_global_layers = counts[True], counts[False]
+        self.routed_layers = sum(
+            callable(getattr(layer.mlp, "routed", None)) for layer in layers)
+        self.n_experts = max((getattr(layer.mlp, "n_experts", 0)
+                              for layer in layers), default=0)
+        #: every layer the plain Llama layer: what chunked prefill, the
+        #: verify program, quantised linears and the fused paths assume
+        self.plain = not any(
+            self.windows[i] or callable(getattr(layer.self_attn, "qkv", None))
+            or callable(getattr(layer.mlp, "routed", None))
+            or getattr(layer, "pre_mlp_layernorm", None) is not None
+            or not getattr(layer.self_attn, "use_rope", True)
+            for i, layer in enumerate(layers)) \
+            and not callable(getattr(model, "_embed", None))
         self.cfg = cfg
         self.n_head = cfg.num_heads
         self.n_kv = cfg.num_kv_heads
         self.head_dim = cfg.head_dim
         self.max_pos = cfg.max_position_embeddings
         self.pool: kv_cache.PagePool | None = None
+        self.window_pool: kv_cache.PagePool | None = None
+        # experts that got a row, one traced scalar a routed layer, of the
+        # program being traced (`take_counts` empties it)
+        self._hits: list = []
+        self._live = None       # rows that are tokens, set by each forward
         # {program: {stage: kernel or "composite"}} — which path each
         # compiled program's stages took at trace time (the kernel gates
         # below decide per shape; nothing falls back unrecorded)
@@ -104,7 +152,7 @@ class ServingModel:
         # the final norm + head EXPOSED as attributes so the last junction
         # can fold the norm in and the head skip its own; a model carrying
         # only an opaque _head keeps the per-op tail
-        self._fused_block = bool(fused_block) and \
+        self._fused_block = bool(fused_block) and self.plain and \
             getattr(model, "norm", None) is not None and \
             (getattr(model, "lm_head", None) is not None
              or getattr(cfg, "tie_word_embeddings", False))
@@ -121,6 +169,10 @@ class ServingModel:
         self._quant_dtype = None
         self._qweights: dict = {}
         if quant:
+            if not self.plain:
+                raise TypeError(
+                    f"quant={quant!r} swaps the seven linears of a plain "
+                    f"Llama layer; {type(model).__name__} has other layers")
             if quant not in _QUANT_ALGOS:
                 raise ValueError(f"quant must be one of "
                                  f"{sorted(_QUANT_ALGOS)}, got {quant!r}")
@@ -153,16 +205,36 @@ class ServingModel:
 
     # -- wiring --------------------------------------------------------------
 
-    def bind_pool(self, pool: kv_cache.PagePool) -> "ServingModel":
-        if (pool.num_layers, pool.num_kv_heads, pool.head_dim) != \
-                (len(self.model.layers), self.n_kv, self.head_dim):
-            raise ValueError(
-                f"pool shape (layers={pool.num_layers}, "
-                f"kv={pool.num_kv_heads}, d={pool.head_dim}) does not "
-                f"match model (layers={len(self.model.layers)}, "
-                f"kv={self.n_kv}, d={self.head_dim})")
-        self.pool = pool
+    def bind_pool(self, pool: kv_cache.PagePool,
+                  window_pool: kv_cache.PagePool | None = None
+                  ) -> "ServingModel":
+        """`pool` holds the global layers' KV, `window_pool` (needed iff
+        the model has window layers) the window layers'."""
+        for grp, n, name in ((pool, self.n_global_layers, "global"),
+                             (window_pool, self.n_window_layers, "window")):
+            if grp is None:
+                if n:
+                    raise ValueError(f"the model has {n} {name} layer(s) "
+                                     f"and was given no {name} pool")
+                continue
+            if (grp.num_layers, grp.num_kv_heads, grp.head_dim) != \
+                    (n, self.n_kv, self.head_dim):
+                raise ValueError(
+                    f"{name} pool shape (layers={grp.num_layers}, "
+                    f"kv={grp.num_kv_heads}, d={grp.head_dim}) does not "
+                    f"match model (layers={n}, "
+                    f"kv={self.n_kv}, d={self.head_dim})")
+        self.pool, self.window_pool = pool, window_pool
         return self
+
+    def take_counts(self):
+        """int32 [1]: the experts that got a row in the forward just
+        traced, summed over its routed layers; None for a model with no
+        routed layer. The program hands it out beside the tokens."""
+        hits, self._hits = self._hits, []
+        if not hits:
+            return None
+        return jnp.sum(jnp.stack(hits)).astype(jnp.int32).reshape(1)
 
     @property
     def quantized(self) -> bool:
@@ -225,6 +297,50 @@ class ServingModel:
             return paddle.matmul(x, m.embed_tokens.weight, transpose_y=True)
         return m.lm_head(x)
 
+    def _embed(self, tokens):
+        embed = getattr(self.model, "_embed", None)
+        return embed(tokens) if callable(embed) \
+            else self.model.embed_tokens(tokens)
+
+    def _attn_in(self, i, layer, h, b, s, sin, cos):
+        """(q, k, v, gate) of layer `i` for the normed input `h`, RoPE
+        applied where the layer has it; gate None without an output gate."""
+        attn = layer.self_attn
+        if callable(getattr(attn, "qkv", None)):
+            q, k, v, gate = attn.qkv(h)
+        else:
+            (q, k, v), gate = self._qkv(i, layer, h, b, s), None
+        if getattr(attn, "use_rope", True):
+            q, k = F.rope(q, k, sin, cos)
+        return q, k, v, gate
+
+    def _attn_out(self, i, layer, out, gate, b, s):
+        """The attention block's output from the heads' `out` ([B, S, H, D]
+        array): through the layer's gate where it has one, then o_proj."""
+        if gate is not None:
+            return layer.self_attn.out(Tensor(out), gate)
+        return self._linear(
+            "o", i, Tensor(out.reshape(b, s, self.n_head * self.head_dim)),
+            layer.self_attn.o_proj)
+
+    def _ffn(self, i, mlp, y):
+        if callable(getattr(mlp, "routed", None)):
+            from ..ops.kernels import moe_gemm_pallas as mg
+            self._note("experts", "moe_grouped" if mg.use_ragged_kernel(
+                int(y.shape[-1]), int(mlp.gate_w.shape[-1]), y._data.dtype)
+                else "composite")
+            # `_live`: which rows of this program's batch are tokens
+            out, hit = mlp.routed(y, self._live)
+            self._hits.append(hit._data)
+            return out
+        return self._mlp(i, mlp, y)
+
+    def _cache_of(self, i):
+        """(pool, index of layer `i` in it, its window or None)."""
+        w = self.windows[i]
+        return (self.window_pool if w else self.pool), \
+            self.group_index[i], w
+
     def _qkv(self, i, layer, h, b, s):
         attn = layer.self_attn
         q = self._linear("q", i, h, attn.q_proj) \
@@ -237,11 +353,17 @@ class ServingModel:
 
     def _block_tail(self, i, layer, x, attn_out):
         """Shared post-attention half: fused residual-add + rmsnorm, MLP
-        (the same primitive chain as ``LlamaDecoderLayer.forward``)."""
+        (the same primitive chain as ``LlamaDecoderLayer.forward``); with
+        four norms a layer, each half's output is normed before it is
+        added."""
+        if getattr(layer, "pre_mlp_layernorm", None) is not None:
+            x = x + layer.post_attention_layernorm(attn_out)
+            return x + layer.post_mlp_layernorm(
+                self._ffn(i, layer.mlp, layer.pre_mlp_layernorm(x)))
         y, h = F.fused_rms_norm_add(attn_out, x,
                                     layer.post_attention_layernorm.weight,
                                     layer.post_attention_layernorm._epsilon)
-        return h + self._mlp(i, layer.mlp, y)
+        return h + self._ffn(i, layer.mlp, y)
 
     def _layer_tail(self, i, layers, fused, x, hres, attn_out):
         """(x, y, hres) after layer `i`'s post-attention half. Fused: both
@@ -331,14 +453,16 @@ class ServingModel:
 
     # -- decode --------------------------------------------------------------
 
-    def decode_forward(self, tokens, positions, tables):
+    def decode_forward(self, tokens, positions, tables, window_tables=None):
         """One continuous-batch decode token per row.
 
         tokens ``[B]`` int32 (last emitted token per slot), positions
         ``[B]`` int32 (absolute position that token occupies — its KV is
-        written there), tables ``[B, max_pages]`` int32. Inactive slots
-        carry position 0 and an all-trash table. Returns logits Tensor
-        ``[B, vocab]`` for the NEXT position.
+        written there), tables ``[B, max_pages]`` int32 (the global
+        group's), window_tables the window group's (a model with window
+        layers; slots behind a row's window hold the trash page). Inactive
+        slots carry position 0 and an all-trash table. Returns logits
+        Tensor ``[B, vocab]`` for the NEXT position.
         """
         self._prog = "decode"
         pool = self.pool
@@ -349,9 +473,19 @@ class ServingModel:
         path = kv_cache.paged_attention_path(
             (b, 1, self.n_head, self.head_dim), pool.k._data.shape,
             pool.k._data.dtype)
-        page_ids = jnp.take_along_axis(tab, (pos // ps)[:, None],
-                                       axis=1)[:, 0]
-        slots = pos % ps
+
+        def write_at(t):    # (page, slot) of each row's new token
+            return jnp.take_along_axis(t, (pos // ps)[:, None],
+                                       axis=1)[:, 0], pos % ps
+
+        page_ids, slots = write_at(tab)
+        wtab = w_page_ids = None
+        if self.window_pool is not None:
+            wtab = window_tables._data.astype(jnp.int32)
+            w_page_ids, _ = write_at(wtab)
+        # an inactive slot has an all-trash global table; a live row's
+        # window table starts with the trash page once its window has moved
+        live = self._live = tab[:, 0] != kv_cache.TRASH_PAGE
 
         cos_f, sin_f = self._rope_tables()
         cos = Tensor(cos_f._data[0, pos][:, None])      # [B, 1, 1, D]
@@ -377,26 +511,27 @@ class ServingModel:
         write = kv_cache.write_token_rows if path == kv_cache.PAGED_PATH \
             else kv_cache.write_token
         fused = self._fused_active()
-        x = self.model.embed_tokens(Tensor(tokens._data.reshape(b, 1)))
+        x = self._embed(Tensor(tokens._data.reshape(b, 1)))
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
+            grp, j, window = self._cache_of(i)
+            t_i, pages_i = (wtab, w_page_ids) if window else (tab, page_ids)
             with jax.named_scope("attention"):
                 h = y if fused else layer.input_layernorm(x)
-                q, k, v = self._qkv(i, layer, h, b, 1)
-                q, k = F.rope(q, k, sin, cos)
-            kp = write(pool.k._data, i, page_ids, slots, k._data[:, 0])
-            vp = write(pool.v._data, i, page_ids, slots, v._data[:, 0])
-            pool.k._data = kp
-            pool.v._data = vp
-            with jax.named_scope("attention"):
+                q, k, v, gate = self._attn_in(i, layer, h, b, 1, sin, cos)
+            kp = write(grp.k._data, j, pages_i, slots, k._data[:, 0])
+            vp = write(grp.v._data, j, pages_i, slots, v._data[:, 0])
+            grp.k._data = kp
+            grp.v._data = vp
+            with jax.named_scope("attention"), jax.named_scope(
+                    "attention_window" if window else "attention_global"):
                 # the whole pools go in: the paged kernel fetches the
                 # live pages itself (a layer slice here would be copied)
-                out = kv_cache.paged_attention(q._data, kp, vp, i, tab, pos)
-                attn_out = self._linear(
-                    "o", i, Tensor(out.reshape(b, 1,
-                                               self.n_head * self.head_dim)),
-                    layer.self_attn.o_proj)
+                out = kv_cache.paged_attention(
+                    q._data, kp, vp, j, t_i, pos, window=window,
+                    live=live if window else None)
+                attn_out = self._attn_out(i, layer, out, gate, b, 1)
             with jax.named_scope("mlp"):
                 x, y, hres = self._layer_tail(i, layers, fused, x, hres,
                                               attn_out)
@@ -535,22 +670,27 @@ class ServingModel:
 
     # -- prefill -------------------------------------------------------------
 
-    def prefill_forward(self, tokens, prompt_len, table_row):
+    def prefill_forward(self, tokens, prompt_len, table_row, window_row=None):
         """Whole-prompt forward for one request, writing its KV pages.
 
         tokens ``[1, L_bucket]`` int32 (prompt padded to the compile
         bucket), prompt_len scalar int32 (traced — one compiled program
-        per bucket serves every length), table_row ``[max_pages]`` int32.
+        per bucket serves every length), table_row ``[max_pages]`` int32
+        (the global group's), window_row the window group's: the pages
+        behind the prompt's last window are the trash page there, so a
+        long prompt keeps only its last window in that group.
         Padding positions' KV writes land in the trash page; causal
         attention keeps them out of every real position's output.
         Returns logits Tensor ``[1, vocab]`` at position ``prompt_len-1``
         (the first generated token's distribution).
         """
         self._prog = "prefill"
-        pool = self.pool
         n = int(tokens.shape[1])
         plen = prompt_len._data.reshape(()).astype(jnp.int32)
-        tab_row = table_row._data.astype(jnp.int32)
+        self._live = jnp.arange(n, dtype=jnp.int32) < plen
+        rows = {False: table_row._data.astype(jnp.int32)}
+        if self.window_pool is not None:
+            rows[True] = window_row._data.astype(jnp.int32)
 
         cos_f, sin_f = self._rope_tables()
         cos = Tensor(cos_f._data[:, :n])
@@ -558,27 +698,25 @@ class ServingModel:
 
         layers = list(self.model.layers)
         fused = self._fused_active()
-        x = self.model.embed_tokens(tokens)
+        x = self._embed(tokens)
         hres = x
         y = layers[0].input_layernorm(x) if fused else None
         for i, layer in enumerate(layers):
+            grp, j, window = self._cache_of(i)
             with jax.named_scope("attention"):
                 h = y if fused else layer.input_layernorm(x)
-                q, k, v = self._qkv(i, layer, h, 1, n)
-                q, k = F.rope(q, k, sin, cos)
-            pool.k._data = kv_cache.write_prefill(
-                pool.k._data, i, tab_row, plen, k._data[0],
-                pool.page_size)
-            pool.v._data = kv_cache.write_prefill(
-                pool.v._data, i, tab_row, plen, v._data[0],
-                pool.page_size)
-            with jax.named_scope("attention"):
-                out = F.scaled_dot_product_attention(q, k, v,
-                                                     is_causal=True)
-                attn_out = self._linear(
-                    "o", i,
-                    out.reshape([1, n, self.n_head * self.head_dim]),
-                    layer.self_attn.o_proj)
+                q, k, v, gate = self._attn_in(i, layer, h, 1, n, sin, cos)
+            grp.k._data = kv_cache.write_prefill(
+                grp.k._data, j, rows[bool(window)], plen, k._data[0],
+                grp.page_size)
+            grp.v._data = kv_cache.write_prefill(
+                grp.v._data, j, rows[bool(window)], plen, v._data[0],
+                grp.page_size)
+            with jax.named_scope("attention"), jax.named_scope(
+                    "attention_window" if window else "attention_global"):
+                out = F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, window=window)
+                attn_out = self._attn_out(i, layer, out._data, gate, 1, n)
             with jax.named_scope("mlp"):
                 x, y, hres = self._layer_tail(i, layers, fused, x, hres,
                                               attn_out)

@@ -68,7 +68,7 @@ from ..observability import (counter as _obs_counter, gauge as _obs_gauge,
                              histogram as _obs_histogram)
 from ..observability import flight as _flight
 from ..observability import tracing as _tracing
-from .kv_cache import PagePoolExhausted
+from .kv_cache import PagePoolExhausted, TRASH_PAGE, window_first_page
 from .speculative import NgramDrafter, SpecState
 
 __all__ = ["Request", "Scheduler", "RequestRejected", "ServingError",
@@ -110,10 +110,11 @@ _PREFILL_TOKENS = _obs_counter(
 _KV_POSITIONS = _obs_counter(
     "paddle_tpu_serving_kv_positions_total",
     "KV positions per layer over all decode and verify steps: kind=live "
-    "those of the contexts in the batch, kind=gathered those the program "
-    "read of the pool (a page-table gather: every slot of every table row; "
-    "the paged decode kernel: the live rows' positions rounded up to its "
-    "block)")
+    "those of the contexts in the batch (group=window: those inside each "
+    "row's window), kind=gathered those the program read of the pool (a "
+    "page-table gather: every slot of every table row; the paged decode "
+    "kernel: the live rows' positions rounded up to its block, in "
+    "group=window from the block that holds the window's first position)")
 _QUEUE = _obs_gauge("paddle_tpu_serving_queue_depth",
                     "requests waiting for admission")
 _ACTIVE = _obs_gauge("paddle_tpu_serving_active_requests",
@@ -184,6 +185,10 @@ class Request:
         self.tokens: list[int] = []
         self.error: str | None = None
         self.pages: list[int] = []
+        # the window group's pages by logical page, the trash page where
+        # one was released behind the window (or never held: a long prompt)
+        self.window_pages: list[int] = []
+        self.window_held = 0      # those of them that are real pages
         self.slot: int | None = None
         self.arrival = next(_arrival)
         self.evictions = 0
@@ -413,8 +418,12 @@ class Scheduler:
                  prefill_chunk: int | None = None,
                  prefill_budget: int | None = None,
                  spec_k: int = 0, spec_adaptive: bool = True,
-                 drafter=None):
+                 drafter=None, window_pool=None, window: int | None = None):
         self.pool = pool
+        # the window layers' group (a model that has them): its own pool
+        # and page table; a row holds there only the pages inside `window`
+        self.window_pool = window_pool
+        self.window = int(window) if window_pool is not None else None
         self.programs = programs
         self.max_batch = int(max_batch)
         self.max_seq_len = int(max_seq_len)
@@ -432,6 +441,8 @@ class Scheduler:
         self.waiting: list[Request] = []      # kept sorted by arrival
         self.slots: list[Request | None] = [None] * self.max_batch
         self.tables = np.zeros((self.max_batch, self.max_pages), np.int32)
+        self.window_tables = None if window_pool is None \
+            else np.zeros_like(self.tables)
         self.decode_steps = 0
         self.occupancy_sum = 0.0
         self.completed = 0
@@ -688,7 +699,15 @@ class Scheduler:
                     tail = claimed[(ctx_len - 1) // self.pool.page_size]
                     if self.pool.refcount(tail) > 1:
                         need_new += 1
-                if need_new > self.pool.available_pages:
+                # by group: the window layers need the pages from the
+                # context's last window on (and the same headroom page)
+                w_first = 0 if self.window_pool is None else \
+                    window_first_page(ctx_len, self.window,
+                                      self.pool.page_size)
+                if need_new > self.pool.available_pages or (
+                        self.window_pool is not None
+                        and self.pool.pages_for(ctx_len + 1) - w_first
+                        > self.window_pool.available_pages):
                     if claimed:        # hand the claims back (they fall
                         self.pool.free(claimed)   # to the cached state)
                     break                      # FIFO head-of-line wait
@@ -699,6 +718,11 @@ class Scheduler:
                     if claimed:
                         self.pool.free(claimed)
                     break
+                if self.window_pool is not None:
+                    req.window_pages = [TRASH_PAGE] * w_first \
+                        + self.window_pool.alloc(
+                            self.pool.pages_for(ctx_len) - w_first)
+                    req.window_held = len(req.window_pages) - w_first
                 self.waiting.pop(0)
                 _QUEUE.set(len(self.waiting))
                 if self.prefix_cache is not None:
@@ -719,6 +743,10 @@ class Scheduler:
                 row = self.tables[slot]
                 row[:] = 0
                 row[:len(req.pages)] = req.pages
+                if self.window_pool is not None:
+                    row = self.window_tables[slot]
+                    row[:] = 0
+                    row[:len(req.window_pages)] = req.window_pages
                 self.slots[slot] = req
                 req.state = RUNNING
                 if self.spec_k and req.spec is None:
@@ -766,6 +794,7 @@ class Scheduler:
                     self._release(req)
                     req._finish(FAILED, f"prefill failed: {e!r}")
                     continue
+                self._count_experts(sp)
                 t_pf1 = time.perf_counter()
             req.prefill_ms += (t_pf1 - t_pf0) * 1000.0
             if req.trace is not _tracing.NOOP_TRACE:
@@ -811,8 +840,14 @@ class Scheduler:
             if req.pages:
                 self.pool.free(req.pages)
                 req.pages = []
+            if req.window_pages:
+                self.window_pool.free(
+                    [p for p in req.window_pages if p != TRASH_PAGE])
+                req.window_pages, req.window_held = [], 0
             if req.slot is not None:
                 self.tables[req.slot][:] = 0
+                if self.window_tables is not None:
+                    self.window_tables[req.slot][:] = 0
                 self.slots[req.slot] = None
                 if self.spec_k:
                     # the vacated slot no longer drafts: a stale K here
@@ -999,19 +1034,50 @@ class Scheduler:
             with self.lock:
                 req.pages.append(page)
                 self.tables[req.slot][len(req.pages) - 1] = page
+        if self.window_pool is not None:
+            self._slide_window(req)
         # the decode write position must be exclusively owned
         return self._make_writable(req, req.cur_len() - 1, 1)
 
+    def _slide_window(self, req: Request) -> None:
+        """The window group's side of :meth:`_ensure_pages`: a page for the
+        next write position, and the pages that have fallen behind the
+        window (no query from here on can see a key in them) released. The
+        group holds a window's pages for every slot, so it is never short."""
+        with self.lock:
+            while len(req.window_pages) < self.pool.pages_for(req.cur_len()):
+                page = self.window_pool.alloc(1)[0]
+                req.window_pages.append(page)
+                req.window_held += 1
+                self.window_tables[req.slot][len(req.window_pages) - 1] = page
+            # only the newest pages behind the window can still be held
+            i = window_first_page(req.cur_len(), self.window,
+                                  self.pool.page_size) - 1
+            behind = []
+            while i >= 0 and req.window_pages[i] != TRASH_PAGE:
+                behind.append(req.window_pages[i])
+                req.window_pages[i] = TRASH_PAGE
+                self.window_tables[req.slot][i] = TRASH_PAGE
+                i -= 1
+            if behind:
+                req.window_held -= len(behind)
+                self.window_pool.free(behind)
+
     def _masked_tables(self):
-        """Page-table snapshot for one batched step: empty AND
+        """Page-table snapshots (global group, window group or None) for
+        one batched step: empty AND
         still-prefilling slots ride with an all-zero row — their batched
         writes land on the trash page and a mid-prefill table never
         takes a write at position 0. Caller holds the lock."""
         tables = self.tables.copy()
+        window = None if self.window_tables is None \
+            else self.window_tables.copy()
         for i, r in enumerate(self.slots):
             if r is None or not r.prefill_done:
                 tables[i][:] = 0
-        return tables
+                if window is not None:
+                    window[i][:] = 0
+        return tables, window
 
     def _account_step(self, occ: float, emitted: int, rows: int,
                       proposed: int = 0, accepted: int = 0,
@@ -1088,11 +1154,14 @@ class Scheduler:
                 tokens[req.slot] = req.tokens[-1]
                 positions[req.slot] = req.cur_len() - 1
                 temps[req.slot] = max(req.temperature, 0.0)
-            tables = self._masked_tables()
+            tables, window_tables = self._masked_tables()
         with _tracing.span("serving.decode") as sp:
-            out = self.programs.decode(tokens, positions, tables, temps)
+            out = self.programs.decode(
+                tokens, positions, tables, temps,
+                *([] if window_tables is None else [window_tables]))
         self._count_positions(
             sp, "decode", [positions[req.slot] + 1 for req in active])
+        self._count_experts(sp)
         self._account_step(len(active) / float(self.max_batch),
                            emitted=len(active), rows=len(active))
         with _tracing.span("serving.emit"):
@@ -1114,8 +1183,32 @@ class Scheduler:
         gathered = int(fn(program, lengths)) if fn is not None else 0
         live = int(sum(lengths))
         sp.count(rows=len(lengths), positions=live, gathered=gathered)
-        _KV_POSITIONS.inc(live, kind="live")
-        _KV_POSITIONS.inc(gathered, kind="gathered")
+        _KV_POSITIONS.inc(live, kind="live", group="global")
+        _KV_POSITIONS.inc(gathered, kind="gathered", group="global")
+        if self.window_pool is None:
+            return
+        # the window group: what lies inside each row's window, what the
+        # kernel read of it, and the pages held against whole contexts
+        live = int(sum(min(int(n), self.window) for n in lengths))
+        gathered = int(fn(program, lengths, window=True)) \
+            if fn is not None else 0
+        with self.lock:
+            rows = [r for r in self.slots if r is not None]
+            held = sum(r.window_held for r in rows)
+            whole = sum(len(r.window_pages) for r in rows)
+        sp.count(positions_window=live, gathered_window=gathered,
+                 window_pages_held=held, window_pages_whole=whole)
+        _KV_POSITIONS.inc(live, kind="live", group="window")
+        _KV_POSITIONS.inc(gathered, kind="gathered", group="window")
+
+    def _count_experts(self, sp) -> None:
+        """On a step span of a model with routed layers: the experts that
+        got a row in the program just run (the engine pulled the count out
+        with the tokens) against the experts of all its routed layers."""
+        counts = getattr(self.programs, "last_counts", None)
+        if counts:
+            sp.count(experts_hit=counts["experts_hit"],
+                     experts_total=self.programs.experts_total)
 
     # -- speculative decoding ------------------------------------------------
 
@@ -1215,7 +1308,7 @@ class Scheduler:
                 positions[req.slot] = req.cur_len() - 1
                 dlens[req.slot] = len(d)
                 temps[req.slot] = max(req.temperature, 0.0)
-            tables = self._masked_tables()
+            tables, _ = self._masked_tables()
             n_prop = int(dlens.sum())
         _flight.record("serving_spec_propose", rows=len(active),
                        proposed=n_prop)

@@ -246,3 +246,47 @@ def test_decode_layer_kernel_is_withdrawn_on_the_chip(one_chip, monkeypatch):
     monkeypatch.setattr(kern, "available", lambda: True)
     with pytest.raises(NotImplementedError, match="withdrawn"):
         dlp.use_kernel(*gate)
+
+
+# Trinity-Mini (afmoe): 32 heads / 4 kv heads x 128, hidden 2048, 128
+# experts of width 1024, window 2048; prompts to 16384
+TRINITY = dict(h=32, kv=4, d=128, hidden=2048, expert=1024, experts=128,
+               window=2048)
+
+
+@pytest.mark.parametrize("s,window", [(16384, 2048), (16384, None),
+                                      (256, 2048)],
+                         ids=["window_16k", "global_16k", "window_256"])
+def test_flash_attention_banded(one_chip, s, window):
+    from paddle_tpu.ops.kernels import flash_attention_pallas as fap
+    c = TRINITY
+    q = ((1, s, c["h"], c["d"]), jnp.bfloat16)
+    kv = ((1, s, c["kv"], c["d"]), jnp.bfloat16)
+    compile_for(one_chip, functools.partial(
+        fap.flash_attention_forward_banded, window=window), q, kv, kv)
+
+
+@pytest.mark.parametrize("rows,tile", [(2560, 16), (65536, 256)],
+                         ids=["decode_64x8", "prefill_4096x8"])
+def test_moe_grouped_kernels(one_chip, rows, tile):
+    from paddle_tpu.ops.kernels import moe_gemm_pallas as mg
+    c = TRINITY
+    e, h, m = c["experts"], c["hidden"], c["expert"]
+    te, used = ((rows // tile,), jnp.int32), ((), jnp.int32)
+    compile_for(one_chip, functools.partial(mg.moe_grouped_swiglu, tile=tile),
+                ((rows, h), jnp.bfloat16), ((e, h, m), jnp.bfloat16),
+                ((e, h, m), jnp.bfloat16), te, used)
+    compile_for(one_chip, functools.partial(mg.moe_grouped_matmul, tile=tile),
+                ((rows, m), jnp.bfloat16), ((e, m, h), jnp.bfloat16), te,
+                used)
+
+
+def test_paged_mmha_decode_with_lower_bound(one_chip):
+    from paddle_tpu.ops.kernels import mmha_pallas as mp
+    c = TRINITY
+    b, pages, ps, max_pages = 64, 8257, 16, 1088
+    pool = ((3, pages, c["kv"], ps, c["d"]), jnp.bfloat16)
+    compile_for(one_chip, mp.paged_mmha_decode,
+                ((b, 1, c["h"], c["d"]), jnp.bfloat16), pool, pool,
+                ((), jnp.int32), ((b, max_pages), jnp.int32),
+                ((b,), jnp.int32), ((b,), jnp.int32))

@@ -544,10 +544,17 @@ def test_off_means_noop_span_and_an_empty_buffer():
 def served(tracer):
     """A tiny engine's run with tracing on: (step spans, requests)."""
     import paddle_tpu.observability as obs
-    before = {k: obs.value("paddle_tpu_serving_" + n, kind=k)
-              for n, ks in (("prefill_tokens_total", ("real", "padded")),
-                            ("kv_positions_total", ("live", "gathered")))
-              for k in ks}
+    def counted():
+        # the KV positions are counted by group of the cache: a model with
+        # no window layers has the global group alone
+        return {k: obs.value("paddle_tpu_serving_" + n, kind=k, **labels)
+                for n, ks, labels in (
+                    ("prefill_tokens_total", ("real", "padded"), {}),
+                    ("kv_positions_total", ("live", "gathered"),
+                     {"group": "global"}))
+                for k in ks}
+
+    before = counted()
     eng = _tiny_engine()
     try:
         reqs = [eng.submit(p) for p in PROMPTS]
@@ -555,10 +562,7 @@ def served(tracer):
             r.result(timeout=300)
     finally:
         eng.shutdown(drain=False)
-    after = {k: obs.value("paddle_tpu_serving_" + n, kind=k)
-             for n, ks in (("prefill_tokens_total", ("real", "padded")),
-                           ("kv_positions_total", ("live", "gathered")))
-             for k in ks}
+    after = counted()
     got = tracing.step_spans()
     assert got["dropped"] == 0
     return got["spans"], reqs, {k: after[k] - before[k] for k in after}
