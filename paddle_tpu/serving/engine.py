@@ -329,21 +329,32 @@ class LLMEngine:
     def _sample(self, logits, temps, key, step):
         """On-device next-token selection: greedy where temp == 0, else
         temperature (+ static top_k) gumbel sampling. logits [N, V],
-        temps [N]; returns int32 [N]. The scaling/filtering step is
-        shared with the speculative verify acceptance — the spec-on ==
-        spec-off exactness guarantee depends on the two never drifting."""
+        temps [N]; returns int32 [N]. The noise is drawn only in a step
+        that has a sampling row (a batch of greedy rows pays one argmax),
+        and then in float32 as the logits it is added to: the process's
+        x64 would make the draw float64, which the chip emulates. The
+        scaling/filtering step is shared with the speculative verify
+        acceptance — the spec-on == spec-off exactness guarantee depends
+        on the two never drifting."""
         import jax
         import jax.numpy as jnp
 
         from .speculative import scaled_filtered_logits
 
         with jax.named_scope("head_sample"):
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            arr = scaled_filtered_logits(logits, temps, self.config.top_k)
-            kk = jax.random.fold_in(key, step.astype(jnp.uint32))
-            g = jax.random.gumbel(kk, arr.shape)
-            sampled = jnp.argmax(arr + g, axis=-1).astype(jnp.int32)
-            return jnp.where(temps > 0, sampled, greedy)
+            last = logits.ndim - 1
+            greedy = jax.lax.argmax(logits, last, jnp.int32)
+
+            def with_noise():
+                arr = scaled_filtered_logits(logits, temps,
+                                             self.config.top_k)
+                kk = jax.random.fold_in(key, step.astype(jnp.uint32))
+                g = jax.random.gumbel(kk, arr.shape, dtype=jnp.float32)
+                sampled = jax.lax.argmax(arr + g, last, jnp.int32)
+                return jnp.where(temps > 0, sampled, greedy)
+
+            return jax.lax.cond(jnp.any(temps > 0), with_noise,
+                                lambda: greedy)
 
     # -- programs interface the scheduler drives -----------------------------
 

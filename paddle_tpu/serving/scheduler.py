@@ -1161,6 +1161,7 @@ class Scheduler:
                 *([] if window_tables is None else [window_tables]))
         self._count_positions(
             sp, "decode", [positions[req.slot] + 1 for req in active])
+        sp.count(sampling_rows=int((temps > 0).sum()))
         self._count_experts(sp)
         self._account_step(len(active) / float(self.max_batch),
                            emitted=len(active), rows=len(active))
@@ -1317,6 +1318,7 @@ class Scheduler:
                                             temps)
         self._count_positions(
             sp, "verify", [positions[req.slot] + 1 for req in active])
+        sp.count(sampling_rows=int((temps > 0).sum()))
         occ = len(active) / float(self.max_batch)
         n_acc = n_emit = 0
         with _tracing.span("serving.emit"):

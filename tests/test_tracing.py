@@ -628,7 +628,8 @@ def test_serving_spans_follow_the_layer_boundaries(served):
     for s in spans:
         assert set(s["counts"]) <= {
             "serving.prefill": {"tokens", "bucket"},
-            "serving.decode": {"rows", "positions", "gathered"},
+            "serving.decode": {"rows", "positions", "gathered",
+                               "sampling_rows"},
         }.get(s["name"].replace("_chunk", "").replace("verify", "decode"),
               set()), s
     # upload, dispatch, pull: in that order, one of each under a decode
