@@ -502,25 +502,6 @@ def _plan_block(model, batch, seq, measured_step_ms, dev):
                 if measured_step_ms else None,
                 "peak_flops_source": peak_src if peak else "cpu-preset",
             }
-        # tuning-cache calibration (ISSUE 20): per-kernel roofline
-        # prediction vs the autotuner's MEASURED ms for every cache-backed
-        # kernel on this chip — the feedback loop that tightens the
-        # planner's predicted-vs-measured gap, and the ratios
-        # PERF_GATE_KERNEL_PRED_TOL_X bounds both directions
-        try:
-            from paddle_tpu.cost_model import kernel_cost
-            ratios = {}
-            for mod in ("decode_layer_pallas",):
-                for s in kernel_cost(
-                        "paddle_tpu.ops.kernels." + mod)["kernels"]:
-                    if s.get("cost_source") == "measured" and \
-                            s.get("predicted_vs_measured"):
-                        ratios[s["kernel"]] = s["predicted_vs_measured"]
-            if ratios:
-                block["kernel_calibration"] = {
-                    "source": "tuning_cache", "ratios": ratios}
-        except Exception:
-            pass
         return block
     except Exception:
         return {"error": traceback.format_exc(limit=2)[:500]}
@@ -588,7 +569,6 @@ _KERNEL_AB_JOIN = (
     ("bias_dropout_ln_pallas", "_fwd_kernel", "bias_dropout_ln_pallas_ms"),
     ("wo_matmul_pallas", "_wo_kernel", "wo_int8_decode_pallas_ms"),
     ("wo_matmul_pallas", "_wo4_kernel", "wo_int4_decode_pallas_ms"),
-    ("decode_layer_pallas", "block_decode_layer", "decode_layer_pallas_ms"),
 )
 
 
@@ -632,15 +612,9 @@ def _kernel_static_block(kernel_ab):
                 "vmem_bytes": sheet["vmem_bytes"],
                 "hbm_bytes": sheet["hbm_bytes"],
                 "arithmetic_intensity": sheet["arithmetic_intensity"],
-                # tuning-cache feedback (ISSUE 20): roofline prediction
-                # plus, when the autotuner has measured this kernel on
-                # this chip, the measured ms and the ratio perf_gate
-                # bounds via PERF_GATE_KERNEL_PRED_TOL_X
+                # the chip roofline over the sheet's flops and bytes
                 "cost_source": sheet.get("cost_source"),
                 "predicted_ms": sheet.get("predicted_ms"),
-                "tuned_ms": sheet.get("measured_ms"),
-                "tuned_block": sheet.get("tuned_block"),
-                "predicted_vs_measured": sheet.get("predicted_vs_measured"),
             })
 
         from paddle_tpu.ops.kernels import swiglu_pallas as sw
@@ -1018,105 +992,6 @@ def _serve_speculative_block(users=6, suffix_len=4, max_new=96, spec_k=6):
     }
 
 
-def _serve_fused_decode_block(users=6, max_new=48):
-    """Fused-decode-layer A/B (ISSUE 20 acceptance): the SAME workload on
-    identical engines, fused decode-layer mega-kernel on vs off (the
-    composite path is the parity oracle). Greedy outputs must be
-    token-exact; both runs carry the zero-retrace / zero-leak /
-    zero-lost sub-block fields perf_gate hard-checks, and the fused run
-    must not lose TPOT within-round (PERF_GATE_DECODE_FUSED_TOL_PCT
-    soft-gates p50). The ``tuning_cache`` sibling block proves the
-    autotuner round-trip: a warm cache serves the measured ``block_i``
-    with zero new trial seconds."""
-    import threading
-
-    import numpy as np
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import llama_tiny
-    from paddle_tpu.serving import LLMEngine, ServingConfig
-
-    rng = np.random.default_rng(29)
-    prompt_lens = [12, 28]
-    prompts = [[int(t) for t in
-                rng.integers(1, 500, size=prompt_lens[u % 2])]
-               for u in range(users)]
-    warm_prompts = [[int(t) for t in rng.integers(1, 500, size=n)]
-                    for n in prompt_lens]
-
-    def run(fused):
-        paddle.seed(0)
-        model = llama_tiny()
-        eng = LLMEngine(model, ServingConfig(
-            page_size=16, num_pages=129, max_batch=users,
-            max_new_tokens=max_new, temperature=0.0, seed=0,
-            fused_decode_layer=fused))
-        for wp in warm_prompts:
-            eng.generate(wp, timeout=600)
-            eng.generate(wp, timeout=600)
-        warm = eng.program_stats()
-
-        results: dict = {}
-        errors: list = []
-
-        def user(uid):
-            try:
-                req = eng.submit(prompts[uid])
-                results[uid] = (req, req.result(timeout=600))
-            except Exception as e:  # noqa: BLE001 — survey, don't die
-                errors.append(repr(e)[:200])
-
-        t0 = time.perf_counter()
-        threads = [threading.Thread(target=user, args=(u,))
-                   for u in range(users)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        wall = time.perf_counter() - t0
-
-        after = eng.program_stats()
-        reqs = [results[u][0] for u in sorted(results)]
-        toks = {u: results[u][1] for u in sorted(results)}
-        gen = sum(len(t) for t in toks.values())
-        active = bool(fused and eng._sm._fused_layer_active())
-        tuning = eng.tuning
-        eng.shutdown(drain=True)
-        blk = {
-            "fused_decode_layer": fused,
-            "fused_active": active,
-            "requests_completed": len(results),
-            "requests_failed": len(errors),
-            "tokens_per_s": round(gen / wall, 1) if wall > 0 else 0.0,
-            "wall_s": round(wall, 3),
-            "tpot_ms": _serve_pct([g for r in reqs for g in r.tpot_ms]),
-            "e2e_ms": _serve_pct([r.e2e_ms for r in reqs
-                                  if r.e2e_ms is not None]),
-            "pages_leaked": eng.pool.leaked(),
-            "pages_lost": eng.pool.lost(),
-            "decode_program": dict(
-                after["decode"],
-                retraces_after_warmup=after["decode"]["retraces"]
-                - warm["decode"]["retraces"]),
-            "tuned_block_i": tuning.get("block_i") if tuning else None,
-            "errors": errors[:5],
-        }
-        return blk, toks
-
-    from paddle_tpu.ops.kernels import autotune
-    on, toks_on = run(True)
-    off, toks_off = run(False)
-    return {
-        "users": users, "max_new": max_new,
-        "token_exact": toks_on == toks_off,
-        "fused_on": on, "fused_off": off,
-        "tpot_p50_ratio": round(
-            on["tpot_ms"]["p50"] / off["tpot_ms"]["p50"], 4)
-        if (on["tpot_ms"] or {}).get("p50")
-        and (off["tpot_ms"] or {}).get("p50") else None,
-        "tuning_cache": autotune.stats(),
-    }
-
-
 def _serve_tracing_block(users=6, max_new=12):
     """Request-tracing probe (ISSUE 16 acceptance): the serve workload
     under tracing. Proves (1) every completed request carries a root
@@ -1337,7 +1212,6 @@ def run_serve_bench(dev=None, users=8, total_requests=16, max_new=16):
     chunked = _serve_chunked_block()
     spec = _serve_speculative_block()
     tracing_blk = _serve_tracing_block()
-    fused_decode = _serve_fused_decode_block()
     return {
         "users": users,
         "requests_completed": len(done),
@@ -1376,10 +1250,6 @@ def run_serve_bench(dev=None, users=8, total_requests=16, max_new=16):
         # ISSUE 16: request-tracing probe + top-level mirrors
         "tracing": tracing_blk,
         "trace_span_coverage": tracing_blk["coverage"]["mean"],
-        # ISSUE 20: fused decode-layer A/B + autotuner telemetry mirrors
-        "fused_decode": fused_decode,
-        "fused_decode_token_exact": fused_decode["token_exact"],
-        "tuning_cache": fused_decode["tuning_cache"],
     }
 
 
@@ -1751,45 +1621,6 @@ def run_kernel_ab(dev):
         res["serving_mmha_decode_pallas_ms"] = round(pal, 3)
         res["serving_mmha_decode_xla_ms"] = round(xla, 3)
         res["serving_mmha_decode_speedup"] = round(xla / pal, 3)
-
-    # whole-decode-LAYER mega-kernel (decode_layer_pallas) vs the
-    # composite chain it replaces — gather -> attention -> o_proj ->
-    # junction -> swiglu MLP -> junction. Shape sized to the kernel's
-    # whole-layer VMEM residency gate (weights live in VMEM, so this is
-    # a small-model/draft-model decode shape, not Llama-7B).
-    from paddle_tpu.ops.kernels import decode_layer_pallas as dlp
-    db, dh, dkv, dd, dps, dpages, dtab = 8, 8, 4, 32, 16, 64, 8
-    dhd, di = dh * dd, 1024
-    if dlp.use_kernel((db, dh, dd), (dpages, dkv, dps, dd), dtab, dhd,
-                      di, jnp.float32):
-        qd = jnp.asarray(rng.standard_normal((db, dh, dd)), jnp.float32)
-        kld = jnp.asarray(
-            rng.standard_normal((dpages, dkv, dps, dd)), jnp.float32)
-        vld = jnp.asarray(
-            rng.standard_normal((dpages, dkv, dps, dd)), jnp.float32)
-        tabd = jnp.asarray(
-            rng.permutation(dpages - 1)[:db * dtab].reshape(db, dtab) + 1,
-            jnp.int32)
-        posd = jnp.full((db,), dtab * dps - 1, jnp.int32)
-        hrd = jnp.asarray(rng.standard_normal((db, dhd)), jnp.float32)
-        wod = jnp.asarray(
-            rng.standard_normal((dh * dd, dhd)) * 0.02, jnp.float32)
-        wgd = jnp.asarray(
-            rng.standard_normal((dhd, di)) * 0.02, jnp.float32)
-        wud = jnp.asarray(
-            rng.standard_normal((dhd, di)) * 0.02, jnp.float32)
-        wdd = jnp.asarray(
-            rng.standard_normal((di, dhd)) * 0.02, jnp.float32)
-        nrm = jnp.ones((dhd,), jnp.float32)
-        pal = timed(lambda a: dlp.decode_layer(
-            a, kld, vld, tabd, posd, hrd, wod, nrm, wgd, wud, wdd, nrm,
-            interpret=kcommon.interpret_mode())[0], qd)
-        xla = timed(lambda a: dlp.reference_decode_layer(
-            a, kld, vld, tabd, posd, hrd, wod, nrm, wgd, wud, wdd,
-            nrm)[0], qd)
-        res["decode_layer_pallas_ms"] = round(pal, 3)
-        res["decode_layer_xla_ms"] = round(xla, 3)
-        res["decode_layer_speedup"] = round(xla / pal, 3)
     return res
 
 

@@ -48,7 +48,7 @@ _FUSIBLE_NODE = _FUSE_THROUGH | {KIND_REDUCE}
 #: must stop advertising saved bytes in GA100's ranking and instead carry
 #: ``fused: true`` in the fusion_targets table.
 MEGA_KERNEL_MARKERS = ("block_attn_epilogue", "block_mlp_epilogue",
-                       "block_decode_epilogue", "block_decode_layer")
+                       "block_decode_epilogue")
 
 
 def is_mega_kernel(name) -> bool:
@@ -199,8 +199,6 @@ def _pallas_hint(chain: list[FusionGroup]) -> str | None:
     names = [str(grp.first.name or "") for grp in chain
              if grp.kind == "breaker" and grp.first.kind == KIND_PALLAS]
     joined = " ".join(names)
-    if "decode_layer" in joined:
-        return "decode-layer"  # the whole-layer mega-kernel (PR 20)
     if any(k in joined for k in ("attn", "mmha", "flash")):
         return "attention"
     if "mlp_epilogue" in joined:

@@ -162,32 +162,18 @@ def kernel_cost(module_or_path, chip=None) -> dict:
         "module": os.path.basename(path),
         "chip": chip,
         "vmem_budget": budget,
-        "kernels": [_join_measured(resource_sheet(m, budget).to_dict(),
+        "kernels": [_with_roofline(resource_sheet(m, budget).to_dict(),
                                    chip) for m in models],
         "notes": [f"[{n.label}] {n.message}" if n.label else n.message
                   for n in notes],
     }
 
 
-def _join_measured(sheet: dict, chip_name: str) -> dict:
-    """Prefer a tuning-cache measurement over the analytic roofline.
-
-    Every sheet gains ``predicted_ms`` (the chip roofline over the static
-    flops/hbm figures) and ``cost_source``; a sheet whose kernel has a
-    matching tuning-cache entry for this chip additionally carries
-    ``measured_ms``, ``tuned_block`` and ``predicted_vs_measured`` — the
-    ratio ``tools/perf_gate.py`` bounds both directions."""
+def _with_roofline(sheet: dict, chip_name: str) -> dict:
+    """Every sheet gains ``predicted_ms`` (the chip roofline over the
+    static flops/hbm figures) and ``cost_source: "roofline"``."""
     from ...cost_model.collective import roofline_ms
-    from ...ops.kernels import autotune
     sheet["predicted_ms"] = roofline_ms(
         sheet.get("flops", 0.0), sheet.get("hbm_bytes", 0), chip_name)
     sheet["cost_source"] = "roofline"
-    entry = autotune.lookup_measured(sheet.get("kernel"), chip=chip_name)
-    if entry and entry.get("ms"):
-        sheet["measured_ms"] = float(entry["ms"])
-        sheet["tuned_block"] = entry.get("block_i")
-        sheet["cost_source"] = "measured"
-        if sheet["measured_ms"] > 0:
-            sheet["predicted_vs_measured"] = round(
-                sheet["predicted_ms"] / sheet["measured_ms"], 4)
     return sheet

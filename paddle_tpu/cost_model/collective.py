@@ -78,8 +78,8 @@ class ChipSpec(dict):
 #: per-chip aggregate inter-chip-interconnect bandwidth inside a slice;
 #: ``dcn`` the per-chip share of the data-center network between slices.
 #: ``peak_flops`` is dense bf16. ``hbm_gbps`` is the per-chip HBM
-#: bandwidth — the memory side of the per-kernel roofline the autotuner's
-#: predicted-vs-measured comparison uses. ``vmem_bytes`` is the per-core
+#: bandwidth — the memory side of the per-kernel roofline
+#: (:func:`roofline_ms`). ``vmem_bytes`` is the per-core
 #: VMEM the Pallas pipeline stages blocks through (~16 MiB/core on
 #: current chips; v6e doubles it) — the budget every kernel's block
 #: picker and the PK200 residency check share.

@@ -6,9 +6,10 @@ growth never retrace it) and the bucketed prefill program (one compiled
 signature per prompt bucket, prompt length traced so every length in a
 bucket shares the program). Both are ``to_static`` functions, so the
 repo's jit telemetry (``paddle_tpu_jit_trace_cache_*`` labeled
-``fn="serving.decode_step"`` / ``"serving.prefill"``) is the retrace
-proof `bench.py serve` asserts — and the page pool + model weights
-thread through them as state.
+``fn="serving.decode_step"`` / ``"serving.prefill"``) counts their
+compiles and retraces (:meth:`LLMEngine.program_stats`; the benchmark's
+``compiles_in_window.serve`` reads the same counters) — and the page pool
++ model weights thread through them as state.
 
 User surface::
 
@@ -80,15 +81,11 @@ class ServingConfig:
     eos_token_id: int | None = None
     quant: str | None = None     # None | weight_only_int8 | weight_only_int4
     quant_group_size: int = -1
-    fused_block: bool = True     # block_decode_epilogue mega-kernel in the
-    #                              decode/prefill programs (TPU; shape-
+    fused_block: bool = True     # a plain layer's two residual junctions
+    #                              (projection output -> residual add ->
+    #                              rmsnorm) each one block_decode_epilogue
+    #                              kernel, in every program (TPU; shape-
     #                              static, zero-retrace preserved)
-    fused_decode_layer: bool = False  # block_decode_layer mega-kernel: the
-    #                              WHOLE decode layer (page gather -> mmha
-    #                              -> o_proj -> junctions -> MLP) as one
-    #                              VMEM-resident pallas_call per layer;
-    #                              composite path is the parity oracle
-    #                              (escape hatch PADDLE_TPU_FUSED_DECODE=0)
     prefix_cache: bool = True    # copy-on-write KV page sharing across
     #                              requests with a common prompt prefix
     prefill_chunk: int | None = None   # tokens per prefill chunk: chunks
@@ -132,8 +129,7 @@ class LLMEngine:
         self.config = cfg
         self._sm = ServingModel(model, quant=cfg.quant,
                                 quant_group_size=cfg.quant_group_size,
-                                fused_block=cfg.fused_block,
-                                fused_decode_layer=cfg.fused_decode_layer)
+                                fused_block=cfg.fused_block)
         max_seq = cfg.max_seq_len or self._sm.max_pos
         if max_seq > self._sm.max_pos:
             raise ValueError(
@@ -221,14 +217,6 @@ class LLMEngine:
             jax.random.PRNGKey(cfg.seed), dtype=np.uint32))
         self._step_seq = 0
         self.last_counts: dict = {}
-        self.tuning = None  # autotune entry (or None) for bench/telemetry
-        if self._sm._fused_layer_active():
-            # the measured block_i must be installed BEFORE the one
-            # decode trace below — tuning after would force a retrace
-            from ..ops.kernels import autotune as _autotune
-            self.tuning = _autotune.tune_for_serving(
-                self._sm, cfg.page_size, cfg.num_pages,
-                self.scheduler.max_pages, cfg.max_batch)
         self._prog_base = self._raw_program_stats()
         self._build_programs()
 

@@ -31,11 +31,21 @@ answers (``models/afmoe.py`` gives all of them):
   two make the layer ``h += norm(attn(norm(h))); h += norm(mlp(norm(h)))``;
 * the model's ``_embed(ids)`` where the embedding is scaled.
 
-Chunked prefill, the speculative verify and the fused junctions know the
-plain Llama layer alone (:attr:`ServingModel.plain`).
+What a layer is gets read off its modules once, at construction
+(:class:`_LayerKind`). The four programs (decode, speculative verify,
+prefill, prefill chunk) share one loop over the layers,
+:meth:`ServingModel._run_layers`; a program owns its positions, its
+``cache_step`` (where a layer's new K/V go and what its query attends to)
+and which positions the head reads. The verify and chunk programs bind one
+pool and one page table, and the fused junctions fold a plain layer's two
+norms, so those three take a model whose every layer is the plain Llama
+layer alone (:attr:`ServingModel.plain`).
 """
 
 from __future__ import annotations
+
+import contextlib
+from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +76,33 @@ def _get_path(obj, path):
     return obj
 
 
+@dataclass(frozen=True)
+class _LayerKind:
+    """What one decoder layer is, read off its modules once."""
+    window: int | None   # the query sees the last so many positions and the
+    #                      layer's KV lives in the window group; None: global
+    index: int           # of the layer inside its group's pool
+    own_qkv: bool        # self_attn.qkv() / .out() (per-head norms, an
+    #                      output gate), else the four plain projections
+    rope: bool
+    routed: bool         # mlp.routed(): routed experts, else dense mlp(y)
+    four_norms: bool     # pre/post_mlp_layernorm beside the usual two
+
+    @property
+    def plain(self) -> bool:
+        return self.rope and not (self.window or self.own_qkv
+                                  or self.routed or self.four_norms)
+
+
+@contextlib.contextmanager
+def _attention_scope(kind: _LayerKind):
+    """The scopes of a layer's attention op and output projection:
+    ``attention`` and, inside it, the layer's group."""
+    with jax.named_scope("attention"), jax.named_scope(
+            "attention_window" if kind.window else "attention_global"):
+        yield
+
+
 class ServingModel:
     """Prefill/decode forward of a Llama-family LM over a :class:`PagePool`.
 
@@ -76,8 +113,7 @@ class ServingModel:
     """
 
     def __init__(self, model, quant: str | None = None,
-                 quant_group_size: int = -1, fused_block: bool = True,
-                 fused_decode_layer: bool = False):
+                 quant_group_size: int = -1, fused_block: bool = True):
         self.model = model
         cfg = getattr(model, "cfg", None)
         missing = [n for n in ("embed_tokens", "layers") if
@@ -100,32 +136,33 @@ class ServingModel:
             raise TypeError(
                 "ServingModel needs a Llama-family module layout; "
                 f"{type(model).__name__} is missing: {', '.join(missing)}")
-        # each layer's kinds, asked of the layer
-        self.windows = [getattr(layer.self_attn, "window", None)
-                        for layer in layers]
-        if len({w for w in self.windows if w}) > 1:
+        # each layer's kinds, asked of the layer here and nowhere else
+        windows = [getattr(layer.self_attn, "window", None)
+                   for layer in layers]
+        if len({w for w in windows if w}) > 1:
             raise TypeError("ServingModel keeps one window group: the "
-                            f"layers' windows differ ({self.windows})")
-        self.window = next((int(w) for w in self.windows if w), None)
-        # layer -> its index inside its group's pool (window or global)
-        self.group_index, counts = [], {True: 0, False: 0}
-        for w in self.windows:
-            self.group_index.append(counts[bool(w)])
+                            f"layers' windows differ ({windows})")
+        self.window = next((int(w) for w in windows if w), None)
+        kinds, counts = [], {True: 0, False: 0}
+        for layer, w in zip(layers, windows):
+            kinds.append(_LayerKind(
+                window=int(w) if w else None, index=counts[bool(w)],
+                own_qkv=callable(getattr(layer.self_attn, "qkv", None)),
+                rope=bool(getattr(layer.self_attn, "use_rope", True)),
+                routed=callable(getattr(layer.mlp, "routed", None)),
+                four_norms=getattr(layer, "pre_mlp_layernorm", None)
+                is not None))
             counts[bool(w)] += 1
+        self._kinds = tuple(kinds)
         self.n_window_layers, self.n_global_layers = counts[True], counts[False]
-        self.routed_layers = sum(
-            callable(getattr(layer.mlp, "routed", None)) for layer in layers)
+        self.routed_layers = sum(kind.routed for kind in kinds)
         self.n_experts = max((getattr(layer.mlp, "n_experts", 0)
                               for layer in layers), default=0)
+        embed = getattr(model, "_embed", None)
+        self._embed = embed if callable(embed) else model.embed_tokens
         #: every layer the plain Llama layer: what chunked prefill, the
-        #: verify program, quantised linears and the fused paths assume
-        self.plain = not any(
-            self.windows[i] or callable(getattr(layer.self_attn, "qkv", None))
-            or callable(getattr(layer.mlp, "routed", None))
-            or getattr(layer, "pre_mlp_layernorm", None) is not None
-            or not getattr(layer.self_attn, "use_rope", True)
-            for i, layer in enumerate(layers)) \
-            and not callable(getattr(model, "_embed", None))
+        #: verify program, quantised linears and the fused junctions assume
+        self.plain = all(kind.plain for kind in kinds) and not callable(embed)
         self.cfg = cfg
         self.n_head = cfg.num_heads
         self.n_kv = cfg.num_kv_heads
@@ -156,15 +193,6 @@ class ServingModel:
             getattr(model, "norm", None) is not None and \
             (getattr(model, "lm_head", None) is not None
              or getattr(cfg, "tie_word_embeddings", False))
-        # decode-layer mega-kernel (ops/kernels/decode_layer_pallas):
-        # needs the same exposed norm/head contract PLUS bias-free
-        # o/gate/up/down projections (the kernel folds them whole)
-        self._fused_decode_layer = bool(fused_decode_layer) and \
-            self._fused_block and all(
-                getattr(_get_path(layer, path), "bias", None) is None
-                for layer in layers
-                for tag, path in _LAYER_LINEARS
-                if tag in ("o", "gate", "up", "down"))
 
         self._quant_dtype = None
         self._qweights: dict = {}
@@ -190,18 +218,6 @@ class ServingModel:
                     qw, scale = weight_quantize(
                         mod.weight, algo=algo, group_size=quant_group_size)
                     self._qweights[(tag, i)] = (qw.detach(), scale.detach())
-        # the decode-layer mega-kernel consumes dense weights; for quant
-        # engines it must see the QUANTIZED values (dequantized once here)
-        # or its output would diverge from the weight_only_linear oracle
-        self._dq_weights: dict = {}
-        if self._fused_decode_layer and self._qweights:
-            from ..nn.quant import weight_dequantize
-            algo = "weight_only_" + self._quant_dtype
-            for i in range(len(layers)):
-                for tag in ("o", "gate", "up", "down"):
-                    qw, scale = self._qweights[(tag, i)]
-                    self._dq_weights[(tag, i)] = weight_dequantize(
-                        qw, scale, algo=algo).detach()
 
     # -- wiring --------------------------------------------------------------
 
@@ -279,14 +295,6 @@ class ServingModel:
                                weight_dtype=self._quant_dtype)
         return y.reshape(list(shp[:-1]) + [y.shape[-1]])
 
-    def _mlp(self, i, mlp, y):
-        if not self._qweights:
-            return mlp(y)
-        import paddle_tpu as paddle
-        g = self._linear("gate", i, y, mlp.gate_proj)
-        u = self._linear("up", i, y, mlp.up_proj)
-        return self._linear("down", i, paddle.swiglu(g, u), mlp.down_proj)
-
     def _head(self, x):
         m = self.model
         if callable(getattr(m, "_head", None)):
@@ -297,20 +305,41 @@ class ServingModel:
             return paddle.matmul(x, m.embed_tokens.weight, transpose_y=True)
         return m.lm_head(x)
 
-    def _embed(self, tokens):
-        embed = getattr(self.model, "_embed", None)
-        return embed(tokens) if callable(embed) \
-            else self.model.embed_tokens(tokens)
+    def _head_normed(self, x):
+        """lm head over an ALREADY-normalized hidden state (the fused
+        path's last junction folded the final norm in)."""
+        m = self.model
+        if getattr(m, "lm_head", None) is not None:
+            return m.lm_head(x)
+        import paddle_tpu as paddle
+        return paddle.matmul(x, m.embed_tokens.weight, transpose_y=True)
 
-    def _attn_in(self, i, layer, h, b, s, sin, cos):
+    def _logits(self, hidden, normed, valid=None):
+        """Logits Tensor of `hidden` ``[b, s, H]`` (`normed`: the final
+        norm is already in): at every position, or ``[b, 1, V]`` at the
+        last of the first `valid` positions (a traced scalar) alone."""
+        with jax.named_scope("head_sample"):
+            if valid is not None:
+                hidden = Tensor(jax.lax.dynamic_slice_in_dim(
+                    hidden._data, valid - 1, 1, axis=1))   # [b, 1, H]
+            return self._head_normed(hidden) if normed \
+                else self._head(hidden)
+
+    def _attn_in(self, i, kind, layer, h, b, s, sin, cos):
         """(q, k, v, gate) of layer `i` for the normed input `h`, RoPE
         applied where the layer has it; gate None without an output gate."""
         attn = layer.self_attn
-        if callable(getattr(attn, "qkv", None)):
+        if kind.own_qkv:
             q, k, v, gate = attn.qkv(h)
         else:
-            (q, k, v), gate = self._qkv(i, layer, h, b, s), None
-        if getattr(attn, "use_rope", True):
+            gate = None
+            q = self._linear("q", i, h, attn.q_proj) \
+                .reshape([b, s, self.n_head, self.head_dim])
+            k = self._linear("k", i, h, attn.k_proj) \
+                .reshape([b, s, self.n_kv, self.head_dim])
+            v = self._linear("v", i, h, attn.v_proj) \
+                .reshape([b, s, self.n_kv, self.head_dim])
+        if kind.rope:
             q, k = F.rope(q, k, sin, cos)
         return q, k, v, gate
 
@@ -323,8 +352,11 @@ class ServingModel:
             "o", i, Tensor(out.reshape(b, s, self.n_head * self.head_dim)),
             layer.self_attn.o_proj)
 
-    def _ffn(self, i, mlp, y):
-        if callable(getattr(mlp, "routed", None)):
+    def _ffn(self, i, kind, mlp, y):
+        """The layer's feed-forward of the normed `y`: routed experts
+        (their hit count kept for `take_counts`), quantised linears, or the
+        module's own dense forward."""
+        if kind.routed:
             from ..ops.kernels import moe_gemm_pallas as mg
             self._note("experts", "moe_grouped" if mg.use_ragged_kernel(
                 int(y.shape[-1]), int(mlp.gate_w.shape[-1]), y._data.dtype)
@@ -333,61 +365,52 @@ class ServingModel:
             out, hit = mlp.routed(y, self._live)
             self._hits.append(hit._data)
             return out
-        return self._mlp(i, mlp, y)
+        if not self._qweights:
+            return mlp(y)
+        import paddle_tpu as paddle
+        g = self._linear("gate", i, y, mlp.gate_proj)
+        u = self._linear("up", i, y, mlp.up_proj)
+        return self._linear("down", i, paddle.swiglu(g, u), mlp.down_proj)
 
-    def _cache_of(self, i):
-        """(pool, index of layer `i` in it, its window or None)."""
-        w = self.windows[i]
-        return (self.window_pool if w else self.pool), \
-            self.group_index[i], w
+    def _pool_of(self, kind):
+        """The pool of a layer's group; the layer is `kind.index` in it."""
+        return self.window_pool if kind.window else self.pool
 
-    def _qkv(self, i, layer, h, b, s):
-        attn = layer.self_attn
-        q = self._linear("q", i, h, attn.q_proj) \
-            .reshape([b, s, self.n_head, self.head_dim])
-        k = self._linear("k", i, h, attn.k_proj) \
-            .reshape([b, s, self.n_kv, self.head_dim])
-        v = self._linear("v", i, h, attn.v_proj) \
-            .reshape([b, s, self.n_kv, self.head_dim])
-        return q, k, v
-
-    def _block_tail(self, i, layer, x, attn_out):
-        """Shared post-attention half: fused residual-add + rmsnorm, MLP
-        (the same primitive chain as ``LlamaDecoderLayer.forward``); with
-        four norms a layer, each half's output is normed before it is
-        added."""
-        if getattr(layer, "pre_mlp_layernorm", None) is not None:
-            x = x + layer.post_attention_layernorm(attn_out)
-            return x + layer.post_mlp_layernorm(
-                self._ffn(i, layer.mlp, layer.pre_mlp_layernorm(x)))
-        y, h = F.fused_rms_norm_add(attn_out, x,
-                                    layer.post_attention_layernorm.weight,
-                                    layer.post_attention_layernorm._epsilon)
-        return h + self._ffn(i, layer.mlp, y)
-
-    def _layer_tail(self, i, layers, fused, x, hres, attn_out):
+    def _layer_tail(self, i, kind, layers, fused, x, hres, attn_out):
         """(x, y, hres) after layer `i`'s post-attention half. Fused: both
         residual junctions are single block_decode_epilogue passes and the
         next layer's input norm (the final model norm after the LAST
         layer) folds into the MLP junction, so `y` is the next normed
-        input and `hres` the residual stream; else `x` is the stream."""
+        input and `hres` the residual stream. Else `x` is the stream: a
+        fused residual-add + rmsnorm, then the MLP (the same primitive
+        chain as ``LlamaDecoderLayer.forward``); with four norms a layer,
+        each half's output is normed before it is added."""
         layer = layers[i]
-        if not fused:
-            return self._block_tail(i, layer, x, attn_out), None, hres
-        y, hres = self._junction(attn_out, hres,
-                                 layer.post_attention_layernorm)
-        m = self._mlp(i, layer.mlp, y)
-        nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-            else self.model.norm
-        y, hres = self._junction(m, hres, nxt)
-        return x, y, hres
+        if fused:
+            y, hres = self._junction(attn_out, hres,
+                                     layer.post_attention_layernorm)
+            m = self._ffn(i, kind, layer.mlp, y)
+            nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
+                else self.model.norm
+            y, hres = self._junction(m, hres, nxt)
+            return x, y, hres
+        if kind.four_norms:
+            x = x + layer.post_attention_layernorm(attn_out)
+            x = x + layer.post_mlp_layernorm(self._ffn(
+                i, kind, layer.mlp, layer.pre_mlp_layernorm(x)))
+        else:
+            y, h = F.fused_rms_norm_add(
+                attn_out, x, layer.post_attention_layernorm.weight,
+                layer.post_attention_layernorm._epsilon)
+            x = h + self._ffn(i, kind, layer.mlp, y)
+        return x, None, hres
 
-    # -- fused-block (mega-kernel) serving path ------------------------------
+    # -- fused junctions (block_fused_pallas.decode_epilogue) ----------------
 
     def _fused_active(self) -> bool:
-        """Decode-epilogue mega-kernel gate: ``ServingConfig(fused_block=)``
-        AND the Pallas kernels dispatching (TPU / interpret tests). Off,
-        the per-op loops below run byte-identically to before."""
+        """Fused-junction gate: ``ServingConfig(fused_block=)`` AND the
+        Pallas kernels dispatching (TPU / interpret tests). Off, the
+        per-op residual add and norm run."""
         from ..core.flags import flag
         from ..ops.kernels import _common as kern
         active = (self._fused_block and kern.available()
@@ -395,32 +418,6 @@ class ServingModel:
         if not active:
             self._note("junction", "composite")
         return active
-
-    def _fused_layer_active(self) -> bool:
-        """Decode-layer mega-kernel gate: ``ServingConfig(
-        fused_decode_layer=True)`` AND the Pallas kernels dispatching AND
-        the escape hatch ``PADDLE_TPU_FUSED_DECODE=0`` not pulled. The
-        per-call shape gate (``decode_layer_pallas.use_kernel``) is
-        checked at trace time in :meth:`decode_forward` — layers too big
-        for VMEM fall back to the composite path below."""
-        import os
-
-        from ..core.flags import flag
-        from ..ops.kernels import _common as kern
-        return (self._fused_decode_layer and kern.available()
-                and flag("use_pallas_kernels")
-                and os.environ.get("PADDLE_TPU_FUSED_DECODE", "1") != "0")
-
-    def _layer_mats(self, i, layer):
-        """(wo, wg, wu, wd) raw jnp weights the decode-layer kernel folds
-        — the dequantized copies on quant engines."""
-        def pick(tag, mod):
-            dq = self._dq_weights.get((tag, i))
-            return (dq if dq is not None else mod.weight)._data
-        return (pick("o", layer.self_attn.o_proj),
-                pick("gate", layer.mlp.gate_proj),
-                pick("up", layer.mlp.up_proj),
-                pick("down", layer.mlp.down_proj))
 
     def _junction(self, x, residual, norm_mod):
         """(normed, h): one residual junction as a single
@@ -433,23 +430,53 @@ class ServingModel:
         eps = norm_mod._epsilon
         if bfp.use_kernel(tuple(x.shape), tuple(residual.shape)):
             self._note("junction", "block_decode_epilogue")
-            fn = lambda a, r, w: bfp.decode_epilogue(  # noqa: E731
-                a, r, w, eps, kern.interpret_mode())
+
+            def fn(a, r, w):
+                return bfp.decode_epilogue(a, r, w, eps,
+                                           kern.interpret_mode())
         else:  # tiny batches below the kernel's amortization floor
             self._note("junction", "composite")
-            fn = lambda a, r, w: bfp.reference_fused_epilogue(  # noqa: E731
-                a, r, w, None, 0, 0.0, eps, None, "rms")
+
+            def fn(a, r, w):
+                return bfp.reference_fused_epilogue(
+                    a, r, w, None, 0, 0.0, eps, None, "rms")
         return apply_multi(fn, x, residual, norm_mod.weight,
                            name="serving_decode_epilogue")
 
-    def _head_normed(self, x):
-        """lm head over an ALREADY-normalized hidden state (the fused
-        path's last junction folded the final norm in)."""
-        m = self.model
-        if getattr(m, "lm_head", None) is not None:
-            return m.lm_head(x)
-        import paddle_tpu as paddle
-        return paddle.matmul(x, m.embed_tokens.weight, transpose_y=True)
+    # -- the layer loop ------------------------------------------------------
+
+    def _run_layers(self, x, sin, cos, b, s, cache_step):
+        """The decoder layers over the embedded tokens `x` ``[b, s, H]``:
+        the one loop of the four programs. A layer is its input norm (or
+        the fused junction's `y`), q/k/v with the RoPE rows `sin`/`cos`
+        where it has RoPE, the program's ``cache_step(kind, q, k, v)``,
+        the output projection and the post-attention half.
+
+        ``cache_step`` is what a program does with the cache: it writes
+        the layer's new K/V where they belong and returns the heads'
+        output, a ``[b, s, n_head, head_dim]`` array, of attending to what
+        the query may see; `kind` says which pool, which layer of it and
+        which window.
+
+        Returns (hidden ``[b, s, H]``, normed): with the fused junctions
+        on, the last one folded the model's final norm in.
+        """
+        layers = list(self.model.layers)
+        fused = self._fused_active()
+        hres = x
+        for i, (layer, kind) in enumerate(zip(layers, self._kinds)):
+            with jax.named_scope("attention"):
+                if i == 0 or not fused:     # else the last junction's `y`
+                    y = layer.input_layernorm(x)
+                q, k, v, gate = self._attn_in(i, kind, layer, y, b, s,
+                                              sin, cos)
+            out = cache_step(kind, q, k, v)
+            with _attention_scope(kind):
+                attn_out = self._attn_out(i, layer, out, gate, b, s)
+            with jax.named_scope("mlp"):
+                x, y, hres = self._layer_tail(i, kind, layers, fused, x,
+                                              hres, attn_out)
+        return (y, True) if fused else (x, False)
 
     # -- decode --------------------------------------------------------------
 
@@ -491,97 +518,29 @@ class ServingModel:
         cos = Tensor(cos_f._data[0, pos][:, None])      # [B, 1, 1, D]
         sin = Tensor(sin_f._data[0, pos][:, None])
 
-        layers = list(self.model.layers)
-        if self._fused_layer_active():
-            from ..ops.kernels import decode_layer_pallas as dlp
-            hd = int(self.model.embed_tokens.weight.shape[1])
-            if all(dlp.use_kernel(
-                    (b, self.n_head, self.head_dim),
-                    tuple(pool.k._data.shape[1:]), int(tab.shape[1]), hd,
-                    int(layer.mlp.gate_proj.weight.shape[1]),
-                    pool.k._data.dtype) for layer in layers):
-                self._note("layer", "block_decode_layer")
-                self._note_gather(tab)
-                return self._decode_forward_fused_layer(
-                    tokens, pos, tab, page_ids, slots, sin, cos, b)
-            self._note("layer", "composite")
         self._note("attention", path)
         self._note_gather(tab, kv_cache.paged_block_positions(
             path, ps, int(tab.shape[1])))
         write = kv_cache.write_token_rows if path == kv_cache.PAGED_PATH \
             else kv_cache.write_token
-        fused = self._fused_active()
-        x = self._embed(Tensor(tokens._data.reshape(b, 1)))
-        hres = x
-        y = layers[0].input_layernorm(x) if fused else None
-        for i, layer in enumerate(layers):
-            grp, j, window = self._cache_of(i)
-            t_i, pages_i = (wtab, w_page_ids) if window else (tab, page_ids)
-            with jax.named_scope("attention"):
-                h = y if fused else layer.input_layernorm(x)
-                q, k, v, gate = self._attn_in(i, layer, h, b, 1, sin, cos)
+
+        def cache_step(kind, q, k, v):
+            grp, j = self._pool_of(kind), kind.index
+            t_i, pages_i = (wtab, w_page_ids) if kind.window \
+                else (tab, page_ids)
             kp = write(grp.k._data, j, pages_i, slots, k._data[:, 0])
             vp = write(grp.v._data, j, pages_i, slots, v._data[:, 0])
-            grp.k._data = kp
-            grp.v._data = vp
-            with jax.named_scope("attention"), jax.named_scope(
-                    "attention_window" if window else "attention_global"):
+            grp.k._data, grp.v._data = kp, vp
+            with _attention_scope(kind):
                 # the whole pools go in: the paged kernel fetches the
                 # live pages itself (a layer slice here would be copied)
-                out = kv_cache.paged_attention(
-                    q._data, kp, vp, j, t_i, pos, window=window,
-                    live=live if window else None)
-                attn_out = self._attn_out(i, layer, out, gate, b, 1)
-            with jax.named_scope("mlp"):
-                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
-                                              attn_out)
-        with jax.named_scope("head_sample"):
-            logits = self._head_normed(y) if fused else self._head(x)
-        return Tensor(logits._data[:, 0, :])
+                return kv_cache.paged_attention(
+                    q._data, kp, vp, j, t_i, pos, window=kind.window,
+                    live=live if kind.window else None)
 
-    def _decode_forward_fused_layer(self, tokens, pos, tab, page_ids,
-                                    slots, sin, cos, b):
-        """Decode step through the decode-LAYER mega-kernel: per layer,
-        QKV + RoPE + the KV scatter run as before (a scatter into the
-        paged pool cannot ride a read-steered kernel), then ONE
-        ``block_decode_layer`` pallas_call covers page-table gather ->
-        mmha -> o_proj -> attention junction -> swiglu MLP -> MLP
-        junction, returning the next layer's normed input and the
-        residual stream. The final model norm folds into the LAST
-        layer's second junction — same dataflow as the composite
-        epilogue path, so greedy output is token-exact against it.
-        Shapes all static: the compiled decode program never retraces.
-        """
-        from ..ops.kernels import _common as kern
-        from ..ops.kernels import decode_layer_pallas as dlp
-        pool = self.pool
-        layers = list(self.model.layers)
-        x = self.model.embed_tokens(Tensor(tokens._data.reshape(b, 1)))
-        hres = x._data[:, 0]                                  # [B, Hd]
-        y = layers[0].input_layernorm(x)
-        for i, layer in enumerate(layers):
-            with jax.named_scope("attention"):
-                q, k, v = self._qkv(i, layer, y, b, 1)
-                q, k = F.rope(q, k, sin, cos)
-            kp = kv_cache.write_token(pool.k._data, i, page_ids, slots,
-                                      k._data[:, 0])
-            vp = kv_cache.write_token(pool.v._data, i, page_ids, slots,
-                                      v._data[:, 0])
-            pool.k._data = kp
-            pool.v._data = vp
-            wo, wg, wu, wd = self._layer_mats(i, layer)
-            nxt = layers[i + 1].input_layernorm if i + 1 < len(layers) \
-                else self.model.norm
-            post = layer.post_attention_layernorm
-            yj, hres = dlp.decode_layer(
-                q._data[:, 0], kp[i], vp[i], tab, pos, hres, wo,
-                post.weight._data, wg, wu, wd, nxt.weight._data,
-                eps_post=post._epsilon,
-                eps_next=getattr(nxt, "_epsilon", 1e-6),
-                interpret=kern.interpret_mode())
-            y = Tensor(yj[:, None])
-        with jax.named_scope("head_sample"):
-            logits = self._head_normed(y)
+        x = self._embed(Tensor(tokens._data.reshape(b, 1)))
+        logits = self._logits(
+            *self._run_layers(x, sin, cos, b, 1, cache_step))
         return Tensor(logits._data[:, 0, :])
 
     # -- speculative verify --------------------------------------------------
@@ -631,42 +590,22 @@ class ServingModel:
         cos = Tensor(cos_f._data[0, pos_c])                   # [B, S, 1, D]
         sin = Tensor(sin_f._data[0, pos_c])
 
-        layers = list(self.model.layers)
-        fused = self._fused_active()
-        x = self.model.embed_tokens(tokens)
-        hres = x
-        y = layers[0].input_layernorm(x) if fused else None
-        for i, layer in enumerate(layers):
-            with jax.named_scope("attention"):
-                h = y if fused else layer.input_layernorm(x)
-                q, k, v = self._qkv(i, layer, h, b, s)
-                q, k = F.rope(q, k, sin, cos)
+        def cache_step(kind, q, k, v):
             # write_token scatter over the flattened [B*S] lanes: one
             # (page, slot) per lane, invalid lanes steered to trash
-            kp = kv_cache.write_token(
-                pool.k._data, i, w_page.reshape(-1), w_slot.reshape(-1),
-                k._data.reshape(b * s, self.n_kv, self.head_dim))
-            vp = kv_cache.write_token(
-                pool.v._data, i, w_page.reshape(-1), w_slot.reshape(-1),
-                v._data.reshape(b * s, self.n_kv, self.head_dim))
-            pool.k._data = kp
-            pool.v._data = vp
-            kc = kv_cache.gather_layer(kp, i, tab)
-            vc = kv_cache.gather_layer(vp, i, tab)
-            with jax.named_scope("attention"):
-                out = kv_cache.chunk_attention(q._data, kc, vc, base)
-                attn_out = self._linear(
-                    "o", i, Tensor(out.reshape(b, s,
-                                               self.n_head * self.head_dim)),
-                    layer.self_attn.o_proj)
-            with jax.named_scope("mlp"):
-                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
-                                              attn_out)
-        h_all = y if fused else x                             # [B, S, H]
-        with jax.named_scope("head_sample"):
-            logits = self._head_normed(h_all) if fused \
-                else self._head(h_all)
-        return logits                                         # [B, S, V]
+            kp, vp = (kv_cache.write_token(
+                p._data, kind.index, w_page.reshape(-1), w_slot.reshape(-1),
+                t._data.reshape(b * s, self.n_kv, self.head_dim))
+                for p, t in ((pool.k, k), (pool.v, v)))
+            pool.k._data, pool.v._data = kp, vp
+            kc = kv_cache.gather_layer(kp, kind.index, tab)
+            vc = kv_cache.gather_layer(vp, kind.index, tab)
+            with _attention_scope(kind):
+                return kv_cache.chunk_attention(q._data, kc, vc, base)
+
+        hidden, normed = self._run_layers(self._embed(tokens), sin, cos,
+                                          b, s, cache_step)
+        return self._logits(hidden, normed)                   # [B, S, V]
 
     # -- prefill -------------------------------------------------------------
 
@@ -696,35 +635,21 @@ class ServingModel:
         cos = Tensor(cos_f._data[:, :n])
         sin = Tensor(sin_f._data[:, :n])
 
-        layers = list(self.model.layers)
-        fused = self._fused_active()
-        x = self._embed(tokens)
-        hres = x
-        y = layers[0].input_layernorm(x) if fused else None
-        for i, layer in enumerate(layers):
-            grp, j, window = self._cache_of(i)
-            with jax.named_scope("attention"):
-                h = y if fused else layer.input_layernorm(x)
-                q, k, v, gate = self._attn_in(i, layer, h, 1, n, sin, cos)
+        def cache_step(kind, q, k, v):
+            grp, row = self._pool_of(kind), rows[bool(kind.window)]
             grp.k._data = kv_cache.write_prefill(
-                grp.k._data, j, rows[bool(window)], plen, k._data[0],
+                grp.k._data, kind.index, row, plen, k._data[0],
                 grp.page_size)
             grp.v._data = kv_cache.write_prefill(
-                grp.v._data, j, rows[bool(window)], plen, v._data[0],
+                grp.v._data, kind.index, row, plen, v._data[0],
                 grp.page_size)
-            with jax.named_scope("attention"), jax.named_scope(
-                    "attention_window" if window else "attention_global"):
-                out = F.scaled_dot_product_attention(
-                    q, k, v, is_causal=True, window=window)
-                attn_out = self._attn_out(i, layer, out._data, gate, 1, n)
-            with jax.named_scope("mlp"):
-                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
-                                              attn_out)
-        with jax.named_scope("head_sample"):
-            h_last = jax.lax.dynamic_slice_in_dim(
-                (y if fused else x)._data, plen - 1, 1, axis=1)  # [1, 1, H]
-            last = Tensor(h_last)
-            logits = self._head_normed(last) if fused else self._head(last)
+            with _attention_scope(kind):
+                return F.scaled_dot_product_attention(
+                    q, k, v, is_causal=True, window=kind.window)._data
+
+        hidden, normed = self._run_layers(self._embed(tokens), sin, cos,
+                                          1, n, cache_step)
+        logits = self._logits(hidden, normed, valid=plen)
         return Tensor(logits._data[:, 0, :])
 
     # -- chunked prefill -----------------------------------------------------
@@ -770,38 +695,19 @@ class ServingModel:
                            jnp.int32(kv_cache.TRASH_PAGE))
         w_slot = pos % ps
 
-        layers = list(self.model.layers)
-        fused = self._fused_active()
-        x = self.model.embed_tokens(tokens)
-        hres = x
-        y = layers[0].input_layernorm(x) if fused else None
-        for i, layer in enumerate(layers):
-            with jax.named_scope("attention"):
-                h = y if fused else layer.input_layernorm(x)
-                q, k, v = self._qkv(i, layer, h, 1, n)
-                q, k = F.rope(q, k, sin, cos)
+        def cache_step(kind, q, k, v):
             # write_token's scatter semantics fit a chunk exactly: one
             # (page, slot) per lane, padding lanes steered to trash
-            kp = kv_cache.write_token(pool.k._data, i, w_page, w_slot,
-                                      k._data[0])
-            vp = kv_cache.write_token(pool.v._data, i, w_page, w_slot,
-                                      v._data[0])
-            pool.k._data = kp
-            pool.v._data = vp
-            kc = kv_cache.gather_layer(kp, i, tab_row[None])
-            vc = kv_cache.gather_layer(vp, i, tab_row[None])
-            with jax.named_scope("attention"):
-                out = kv_cache.chunk_attention(q._data, kc, vc, s0)
-                attn_out = self._linear(
-                    "o", i, Tensor(out.reshape(1, n,
-                                               self.n_head * self.head_dim)),
-                    layer.self_attn.o_proj)
-            with jax.named_scope("mlp"):
-                x, y, hres = self._layer_tail(i, layers, fused, x, hres,
-                                              attn_out)
-        with jax.named_scope("head_sample"):
-            h_last = jax.lax.dynamic_slice_in_dim(
-                (y if fused else x)._data, clen - 1, 1, axis=1)  # [1, 1, H]
-            last = Tensor(h_last)
-            logits = self._head_normed(last) if fused else self._head(last)
+            kp, vp = (kv_cache.write_token(p._data, kind.index, w_page,
+                                           w_slot, t._data[0])
+                      for p, t in ((pool.k, k), (pool.v, v)))
+            pool.k._data, pool.v._data = kp, vp
+            kc = kv_cache.gather_layer(kp, kind.index, tab_row[None])
+            vc = kv_cache.gather_layer(vp, kind.index, tab_row[None])
+            with _attention_scope(kind):
+                return kv_cache.chunk_attention(q._data, kc, vc, s0)
+
+        hidden, normed = self._run_layers(self._embed(tokens), sin, cos,
+                                          1, n, cache_step)
+        logits = self._logits(hidden, normed, valid=clen)
         return Tensor(logits._data[:, 0, :])

@@ -227,27 +227,6 @@ def test_wo_int4_matmul(one_chip):
                     ((4096, 2048), jnp.int8), ((4096,), jnp.float32))
 
 
-def test_decode_layer_kernel_is_withdrawn_on_the_chip(one_chip, monkeypatch):
-    """`block_decode_layer` blocks its [B, hidden] rows (1, hidden): the
-    chip's compiler refuses that (neither (8, 128)-divisible nor the full
-    array), so the dispatch gate refuses a real chip loudly and interpret
-    mode keeps the parity tests."""
-    from paddle_tpu.ops.kernels import decode_layer_pallas as dlp
-    ex = {e[0]: e for e in dlp.pk_examples()}["decode_layer"]
-    with pytest.raises(ValueError, match="divisible"):
-        compile_for(one_chip, lambda *a: ex[1](*a, **(ex[3] or {})),
-                    *[(a.shape, a.dtype) for a in ex[2]])
-    gate = ((4, 8, 64), (17, 2, 16, 64), 4, 512, 1024)
-    kern.force_interpret(True)
-    try:
-        assert dlp.use_kernel(*gate)
-    finally:
-        kern.force_interpret(False)
-    monkeypatch.setattr(kern, "available", lambda: True)
-    with pytest.raises(NotImplementedError, match="withdrawn"):
-        dlp.use_kernel(*gate)
-
-
 # Trinity-Mini (afmoe): 32 heads / 4 kv heads x 128, hidden 2048, 128
 # experts of width 1024, window 2048; prompts to 16384
 TRINITY = dict(h=32, kv=4, d=128, hidden=2048, expert=1024, experts=128,

@@ -419,42 +419,6 @@ def fusion_applied_gate(cd):
     return []
 
 
-def kernel_pred_gate(cd):
-    """Soft gate: every tuning-cache-backed kernel cost must stay within
-    PERF_GATE_KERNEL_PRED_TOL_X (default 2x) of the analytic roofline —
-    BOTH directions. Measured >> predicted means the kernel (or the
-    tuner's winner) is leaving the roofline on the table; predicted >>
-    measured means the static flops/hbm model is wrong and the planner
-    is being fed fiction. Reads ``extra.plan.kernel_calibration`` (only
-    populated when the autotuner measured on this chip — CPU rounds,
-    where the kernel never dispatches, pass trivially). <= 0 disables."""
-    tol = _tol_pct("PERF_GATE_KERNEL_PRED_TOL_X", 2.0)
-    if tol <= 0:
-        return []
-    plan = (cd.get("extra") or {}).get("plan") or {}
-    ratios = (plan.get("kernel_calibration") or {}).get("ratios") or {}
-    fails = []
-    for kname, ratio in ratios.items():
-        try:
-            r = float(ratio)
-        except (TypeError, ValueError):
-            continue
-        if r <= 0:
-            continue
-        if r > tol or r < 1.0 / tol:
-            side = ("static model overpredicts (roofline fiction)"
-                    if r > 1 else "kernel runs far off its roofline")
-            fails.append(
-                f"perf gate [REGRESSION:kernel-pred] {kname}: "
-                f"predicted/measured = {r:.3f}x outside [{1 / tol:.2f}, "
-                f"{tol:g}] (tol via PERF_GATE_KERNEL_PRED_TOL_X): {side}")
-        else:
-            print(f"perf gate [ok:kernel-pred] {kname}: "
-                  f"predicted/measured = {r:.3f}x within "
-                  f"[{1 / tol:.2f}, {tol:g}]")
-    return fails
-
-
 def serve_block(d):
     """``extra.serve`` — the serving-runtime bench section (None when the
     round predates the serving engine or skipped it)."""
@@ -480,12 +444,6 @@ def serve_subblocks(cur):
     for k in ("spec_on", "spec_off"):
         if isinstance(sd.get(k), dict):
             blocks.append((f"serve.speculative.{k}", sd[k]))
-    # the fused-decode-layer A/B engines: the mega-kernel path must hold
-    # the exact same zero-retrace / zero-leak contract as the composite
-    fd = cur.get("fused_decode") or {}
-    for k in ("fused_on", "fused_off"):
-        if isinstance(fd.get(k), dict):
-            blocks.append((f"serve.fused_decode.{k}", fd[k]))
     # the tracing probe's engine runs with the tracer ON: if tracing
     # flipped a retrace / leaked a page, the hard gates catch it HERE
     if isinstance(cur.get("tracing"), dict):
@@ -607,38 +565,6 @@ def serve_gates(cd, bd):
                   f"spec-on vs {off_tpot:.2f} ms spec-off "
                   f"(delta {delta:+.2%}, tokens/step "
                   f"{sd.get('spec_on', {}).get('tokens_per_step')})")
-    # fused-decode-layer A/B: the mega-kernel's p50 TPOT must not exceed
-    # the composite path's within-round — a fused layer that is SLOWER
-    # than the chain it replaced is a regression of its whole thesis.
-    # Only judged when the kernel actually engaged (fused_active: on a
-    # CPU round both engines run the composite and the ratio is noise).
-    fused_tol = _tol_pct("PERF_GATE_DECODE_FUSED_TOL_PCT", 25.0)
-    fd = cur.get("fused_decode") or {}
-    try:
-        fon, foff = fd["fused_on"], fd["fused_off"]
-        on_fp = float(fon["tpot_ms"]["p50"])
-        off_fp = float(foff["tpot_ms"]["p50"])
-        active = bool(fon.get("fused_active"))
-    except (KeyError, TypeError, ValueError):
-        on_fp = off_fp = None
-        active = False
-    if fused_tol > 0 and active and on_fp is not None and off_fp and \
-            off_fp > 0:
-        ceiling = off_fp * (1 + fused_tol / 100.0)
-        delta = (on_fp - off_fp) / off_fp
-        if on_fp > ceiling:
-            soft.append(
-                f"perf gate [REGRESSION:decode-fused-tpot] fused "
-                f"decode-layer p50 TPOT {on_fp:.2f} ms vs composite "
-                f"{off_fp:.2f} ms (delta {delta:+.2%}, ceiling "
-                f"{ceiling:.2f}, tol {fused_tol:.0f}% via "
-                f"PERF_GATE_DECODE_FUSED_TOL_PCT): the mega-kernel is "
-                f"slower than the chain it replaced")
-        else:
-            print(f"perf gate [ok:decode-fused-tpot] p50 TPOT "
-                  f"{on_fp:.2f} ms fused vs {off_fp:.2f} ms composite "
-                  f"(delta {delta:+.2%}, block_i "
-                  f"{fon.get('tuned_block_i')})")
     tol = _tol_pct("PERF_GATE_SERVE_TOL_PCT", 30.0)
     base = serve_block(bd) if bd else None
     if tol > 0 and base and base.get("tokens_per_s"):
@@ -753,9 +679,6 @@ def main():
     # training-health monitor: its measured cost must hold the <1%-of-
     # window contract (absolute ceiling, not baseline-relative)
     soft_fails += health_overhead_gate(cd)
-    # tuning-cache-backed kernel costs must agree with the roofline
-    # within PERF_GATE_KERNEL_PRED_TOL_X, both directions
-    soft_fails += kernel_pred_gate(cd)
     # serving runtime: hard zero-retrace/zero-leak contract + soft
     # tokens/s comparison against the same baseline round
     serve_hard, serve_soft = serve_gates(cd, bd)
