@@ -6,4 +6,4 @@ non-TPU backends.
 """
 
 from . import flash_attention  # noqa: F401
-from . import adamw_pallas, moe_gemm_pallas, rope_pallas  # noqa: F401
+from . import moe_gemm_pallas, rope_pallas  # noqa: F401

@@ -7,6 +7,9 @@ the whole family into fused update kernels.
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor
@@ -76,6 +79,38 @@ class Momentum(Optimizer):
             p._data = new_w
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "beta1", "beta2", "eps", "decay", "out_dtype"))
+def _adam_update(w, g, m, v, vmax, lr, t, *, beta1, beta2, eps, decay,
+                 out_dtype):
+    """One Adam / AdamW step of one parameter, every operand in the shape
+    and layout it has. `w` is the float32 master, or the parameter itself
+    where there is none (`out_dtype` None); `decay` is the decoupled one;
+    `vmax` is amsgrad's running maximum or None; `lr` and `t` are float32
+    device scalars, so nothing here is float64 under the process's x64.
+
+    Inside a compiled train step XLA makes one loop of the chain that reads
+    `w, g, m, v` and writes `w', m', v'` and the low-precision copy in place,
+    and for a matrix puts that loop at the end of the matmul that makes its
+    gradient, which then never reaches HBM (PERF.md section 6, PR 32: a
+    Pallas kernel here cost 8.7 % of GPT-2 medium's tokens/s against it, and
+    one over `[rows, 128]` views 30 %). Called eagerly it is one program a
+    parameter, so that an eager step holds none of the chain's temporaries."""
+    w32 = w.astype(jnp.float32)
+    g = g.astype(jnp.float32)
+    m = beta1 * m + (1 - beta1) * g
+    v = beta2 * v + (1 - beta2) * jnp.square(g)
+    mhat = m / (1 - beta1 ** t)
+    vhat = v / (1 - beta2 ** t)
+    if vmax is not None:
+        vmax = vhat = jnp.maximum(vmax, vhat)
+    if decay:
+        w32 = w32 * (1.0 - lr * decay)
+    new_w = w32 - lr * mhat / (jnp.sqrt(vhat) + eps)
+    p_out = None if out_dtype is None else new_w.astype(out_dtype)
+    return new_w.astype(w.dtype), m, v, vmax, p_out
+
+
 class Adam(Optimizer):
     def __init__(self, learning_rate=0.001, beta1=0.9, beta2=0.999, epsilon=1e-8,
                  parameters=None, weight_decay=None, grad_clip=None, lazy_mode=False,
@@ -97,57 +132,32 @@ class Adam(Optimizer):
     def _decoupled_decay_for(self, p) -> float:
         return 0.0  # plain Adam couples decay into the gradient instead
 
-    def _use_fused_kernel(self, p) -> bool:
-        """Fused Pallas update for big tensors on TPU (small ones aren't
-        worth a kernel launch; amsgrad needs the vmax accumulator path)."""
-        from ..core.flags import flag
-        from ..ops.kernels import _common as kern
-        return (not self._amsgrad and kern.available()
-                and flag("use_pallas_kernels") and p._data.size >= 8192)
-
     def _append_optimize_op(self, p, grad):
         """Shared Adam/AdamW body: the only behavioral fork is whether decay
         is coupled into the gradient (Adam) or applied to the weights
         (AdamW, via `_decoupled_decay_for`)."""
-        g = grad._data.astype(jnp.float32)
-        master = self._get_master(p)
-        w32 = master._data if master is not None else p._data.astype(jnp.float32)
+        g = grad._data
         if not self._decoupled:
-            g = self._apply_coupled_weight_decay(p, g)
+            g = self._apply_coupled_weight_decay(p, g.astype(jnp.float32))
+        master = self._get_master(p)
         m = self._add_accumulator("moment1", p, dtype=jnp.float32)
         v = self._add_accumulator("moment2", p, dtype=jnp.float32)
-        # scalar step-based bias correction (single counter, standard Adam)
-        t = self._step_tensor._data
-
-        if self._use_fused_kernel(p):
-            from ..ops.kernels import _common as kern
-            from ..ops.kernels import adamw_pallas as ap
-            new_w, m._data, v._data, p_out = ap.adamw_update(
-                w32, g, m._data, v._data, self._lr_for(p), t,
-                beta1=self._beta1, beta2=self._beta2, eps=self._epsilon,
-                wd=float(self._decoupled_decay_for(p)),
-                out_dtype=p._data.dtype, interpret=kern.interpret_mode())
-            if master is not None:
-                master._data = new_w
-            p._data = p_out
-            return
-
-        m._data = self._beta1 * m._data + (1 - self._beta1) * g
-        v._data = self._beta2 * v._data + (1 - self._beta2) * jnp.square(g)
-        mhat = m._data / (1 - self._beta1 ** t)
-        vhat = v._data / (1 - self._beta2 ** t)
-        if self._amsgrad:
-            vmax = self._add_accumulator("moment2_max", p, dtype=jnp.float32)
-            vmax._data = jnp.maximum(vmax._data, vhat)
-            vhat = vmax._data
-        lr = self._lr_for(p)
-        decay = self._decoupled_decay_for(p)
-        if decay:
-            w32 = w32 * (1.0 - lr * decay)
-        new_w = w32 - lr * mhat / (jnp.sqrt(vhat) + self._epsilon)
-        if master is not None:
-            master._data = new_w
-        p._data = new_w.astype(p._data.dtype)
+        vmax = self._add_accumulator("moment2_max", p, dtype=jnp.float32) \
+            if self._amsgrad else None
+        new_w, m._data, v._data, new_vmax, p_out = _adam_update(
+            p._data if master is None else master._data, g, m._data, v._data,
+            None if vmax is None else vmax._data, self._lr_for(p),
+            self._step_tensor._data,    # one counter corrects every bias
+            beta1=float(self._beta1), beta2=float(self._beta2),
+            eps=float(self._epsilon),
+            decay=float(self._decoupled_decay_for(p)),
+            out_dtype=None if master is None else p._data.dtype)
+        if vmax is not None:
+            vmax._data = new_vmax
+        if master is None:
+            p._data = new_w
+        else:
+            master._data, p._data = new_w, p_out
 
     @property
     def _wd_value(self):

@@ -192,31 +192,6 @@ def main():
         lambda a: rp.rope_reference(a, cos, sin),
         (xr,), n_grad_args=1, tol=2e-2)
 
-    # 4. fused AdamW
-    from paddle_tpu.ops.kernels import adamw_pallas as ap
-    n = NADAM
-    w32 = jnp.asarray(rng.standard_normal(n), jnp.float32)
-    g = jnp.asarray(rng.standard_normal(n), jnp.bfloat16)
-    m = jnp.zeros(n, jnp.float32)
-    vv = jnp.zeros(n, jnp.float32)
-
-    def adamw_ref(w32, g, m, v):
-        b1, b2, eps, wd, lr, step = 0.9, 0.95, 1e-8, 0.1, 1e-3, 1.0
-        g32 = g.astype(jnp.float32)
-        m2 = b1 * m + (1 - b1) * g32
-        v2 = b2 * v + (1 - b2) * g32 * g32
-        mh = m2 / (1 - b1 ** step)
-        vh = v2 / (1 - b2 ** step)
-        w2 = w32 - lr * (mh / (jnp.sqrt(vh) + eps) + wd * w32)
-        return w2, m2, v2
-    fam["fused_adamw"] = run_family(
-        "fused_adamw",
-        lambda w32, g, m, v: ap.adamw_update(
-            w32, g, m, v, 1e-3, 1.0, beta1=0.9, beta2=0.95, eps=1e-8,
-            wd=0.1, out_dtype=jnp.bfloat16, interpret=interp)[:3],
-        lambda w32, g, m, v: adamw_ref(w32, g, m, v),
-        (w32, g, m, vv), tol=5e-2)
-
     # 5. MoE grouped-GEMM (zero-padded rows precondition)
     from paddle_tpu.ops.kernels import moe_gemm_pallas as mg
     e, c, hh, f = (4, 64, 256, 512) if interp else (16, 128, 1024, 1408)
