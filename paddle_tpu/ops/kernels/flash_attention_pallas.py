@@ -2,7 +2,13 @@
 
 Blocked online-softmax attention (FlashAttention-2 style): grid over
 (batch*heads, q-blocks); the kernel scans k/v blocks keeping running max and
-sum. bf16 inputs compute logits in f32 on the MXU.
+sum. Every MXU product takes its operands in the dtype the inputs are stored
+in (bf16 in, bf16 operands; f32 in, f32 operands) and accumulates in f32; the
+scores, the softmax statistics and the accumulators are f32, and P and dS are
+cast to the value dtype for the products that take them. The softmax scale
+rides on the operand that stays in VMEM across the k (or q) loop, scaled once
+in f32: on the [block_q, block_k] scores it would cost the VPU a pass per
+block.
 
 Layout: [batch, seq, heads, head_dim] (reference flash_attn layout,
 paddle/phi/kernels/gpu/flash_attn_kernel.cu).
@@ -22,6 +28,24 @@ from ._common import x64_off, jit_x64_off
 
 NEG_INF = -1e30  # wrapped in jnp.float32 at use sites (x64 safety)
 LSE_LANES = 128  # lse/delta stored [.., S, 128]: Mosaic wants full-lane layouts
+#: q and k block of the whole-sequence kernels where it divides the sequence:
+#: on one v5e chip forward plus backward ran 29 % (head width 64) and 36 %
+#: (head width 128) faster at 512 x 512 than at 256 x 256
+#: (tools/flash_attention_bench.py)
+BLOCK = 512
+
+
+def _block(block, s):
+    """`block` where given, else BLOCK where it divides s, else 256; at most
+    s."""
+    if block is None:
+        block = BLOCK if s % BLOCK == 0 else 256
+    return min(block, s)
+
+
+def _scaled(x, scale):
+    """x * scale, computed in f32 and returned in x's own dtype."""
+    return (x.astype(jnp.float32) * jnp.float32(scale)).astype(x.dtype)
 
 
 def _attn_kernel(q_ref, k_ref, v_ref, *rest, causal, block_k,
@@ -38,7 +62,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, causal, block_k,
     o_ref = next(it)
     lse_ref = next(it) if with_lse else None
     d = q_ref.shape[-1]
-    q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)
+    q = _scaled(q_ref[0], scale)
     q_blk = pl.program_id(1)
     qs = qseg_ref[0][:, :1] if has_seg else None   # [block_q, 1]
 
@@ -50,8 +74,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, causal, block_k,
 
     def body(i, carry):
         m, l, acc = carry
-        k = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(i * block_k, block_k), :]
+        v = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         valid = None
@@ -77,7 +101,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, *rest, causal, block_k,
         alpha = jnp.exp(m - m_new)
         l_new = alpha * l + jnp.sum(p, axis=1, keepdims=True)
         acc_new = alpha * acc + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
     if causal:
@@ -128,8 +153,7 @@ def _fwd_common(q, k, v, segment_ids, causal, block_q, block_k, interpret,
     h_kv = k.shape[2]
     if h % h_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
+    block_q, block_k = _block(block_q, s), _block(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(f"seq {s} must divide block sizes {block_q}/{block_k}")
     scale = 1.0 / math.sqrt(d)
@@ -185,8 +209,8 @@ def _fwd_common(q, k, v, segment_ids, causal, block_q, block_k, interpret,
 
 @functools.partial(jit_x64_off, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
-def flash_attention_forward_lse(q, k, v, causal=False, block_q=256,
-                                block_k=256, interpret=False,
+def flash_attention_forward_lse(q, k, v, causal=False, block_q=None,
+                                block_k=None, interpret=False,
                                 segment_ids=None):
     """Returns (out [B,S,H,D], lse [B*H, S] float32). k/v may carry fewer
     heads than q (GQA): heads must divide evenly. `segment_ids` [B, S]
@@ -198,7 +222,7 @@ def flash_attention_forward_lse(q, k, v, causal=False, block_q=256,
 
 @functools.partial(jit_x64_off, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
-def flash_attention_forward(q, k, v, causal=False, block_q=256, block_k=256,
+def flash_attention_forward(q, k, v, causal=False, block_q=None, block_k=None,
                             interpret=False, segment_ids=None):
     """Primal-only forward: no logsumexp output (inference path). GQA and
     segment masking as in flash_attention_forward_lse."""
@@ -337,8 +361,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     dq_ref = next(it)
     d = q_ref.shape[-1]
     q_blk = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * jnp.float32(scale)  # pre-scaled q
-    do = do_ref[0].astype(jnp.float32)                # [bq, d]
+    q = _scaled(q_ref[0], scale)                      # [bq, d]
+    do = do_ref[0]                                    # [bq, d]
     lse = lse_ref[0][:, :1]                           # [bq, 1]
     delta = delta_ref[0][:, :1]                       # [bq, 1]
     qs = qseg_ref[0][:, :1] if has_seg else None      # [bq, 1]
@@ -347,8 +371,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     acc = jnp.zeros((block_q, d), jnp.float32)
 
     def body(i, acc):
-        k = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
+        k = k_ref[0, pl.ds(i * block_k, block_k), :]
+        v = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         valid = None
@@ -373,7 +397,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
         return acc + jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     if causal:
         last = ((q_blk + 1) * block_q + block_k - 1) // jnp.int32(block_k)
@@ -400,8 +425,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     dv_ref = next(it)
     d = k_ref.shape[-1]
     k_blk = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                  # [bk, d]
-    v = v_ref[0].astype(jnp.float32)                  # [bk, d]
+    k = _scaled(k_ref[0], scale)                      # [bk, d]
+    v = v_ref[0]                                      # [bk, d]
     ks = kseg_ref[0, :1, :] if has_seg else None      # [1, bk]
 
     n_q = seq_len // block_q
@@ -410,9 +435,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
 
     def body(i, carry):
         dk, dv = carry
-        q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32) \
-            * jnp.float32(scale)
-        do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
+        q = q_ref[0, pl.ds(i * block_q, block_q), :]
+        do = do_ref[0, pl.ds(i * block_q, block_q), :]
         lse = lse_ref[0, pl.ds(i * block_q, block_q), :][:, :1]
         delta = delta_ref[0, pl.ds(i * block_q, block_q), :][:, :1]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -434,12 +458,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         if has_seg:
             p = jnp.where(valid, p, 0.0)
         dv = dv + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         ds = p * (dp - delta)                          # [bq, bk]
         dk = dk + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
         return dk, dv
 
     if causal:
@@ -448,16 +474,15 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                                   body, (dk, dv))
     else:
         dk, dv = jax.lax.fori_loop(jnp.int32(0), jnp.int32(n_q), body, (dk, dv))
-    # q was pre-scaled, so ds^T q already carries one factor of scale; the
-    # analytic dK = scale * dS^T Q is exactly what accumulated above.
-    dk_ref[0] = dk.astype(dk_ref.dtype)
+    # dK = scale * dS^T Q: the scale on k served S alone
+    dk_ref[0] = (dk * jnp.float32(scale)).astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 @functools.partial(jit_x64_off, static_argnames=("causal", "block_q", "block_k",
                                              "interpret"))
-def flash_attention_backward(q, k, v, out, lse, g, causal=False, block_q=256,
-                             block_k=256, interpret=False, segment_ids=None):
+def flash_attention_backward(q, k, v, out, lse, g, causal=False, block_q=None,
+                             block_k=None, interpret=False, segment_ids=None):
     """Fused FA2-style backward: (dq, dk, dv) — dq [B,S,H,D], dk/dv with the
     kv head count (GQA: gradients of shared kv heads are summed over their
     query group).
@@ -471,8 +496,7 @@ def flash_attention_backward(q, k, v, out, lse, g, causal=False, block_q=256,
     if h % h_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads {h_kv}")
     n_rep = h // h_kv
-    block_q = min(block_q, s)
-    block_k = min(block_k, s)
+    block_q, block_k = _block(block_q, s), _block(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(f"seq {s} must divide block sizes {block_q}/{block_k}")
     scale = 1.0 / math.sqrt(d)

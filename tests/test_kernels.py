@@ -129,6 +129,46 @@ def test_uneven_seq_falls_back():
         fa.force_interpret(False)
 
 
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("segmented", [False, True], ids=["dense", "seg"])
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("d", [64, 128])
+def test_kernels_in_stored_dtype_match_f32_reference(d, causal, segmented,
+                                                     dtype):
+    """Forward and the three gradients of the kernels against the float32
+    composite. bf16 inputs go to the MXU as bf16 (P and dS cast to the
+    value dtype), so they are held to a bf16 tolerance; f32 inputs keep f32
+    operands and the tolerances the f32 tests above hold them to. The
+    blocks are the kernels' own choice for 1024 positions (two of 512)."""
+    rng = np.random.default_rng(d + 2 * causal + segmented)
+    b, s, h = 2, 1024, 1
+    q, k, v, g = (jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
+                  for _ in range(4))
+    seg = jnp.asarray([[0] * 400 + [1] * 624, [0] * 1024], jnp.int32) \
+        if segmented else None
+    out, lse = flash_attention_forward_lse(q, k, v, causal=causal,
+                                           interpret=True, segment_ids=seg)
+    grads = flash_attention_backward(q, k, v, out, lse, g, causal=causal,
+                                     interpret=True, segment_ids=seg)
+    f32 = [t.astype(jnp.float32) for t in (q, k, v, g)]
+    ref, vjp = jax.vjp(
+        lambda a, b_, c: fa._reference_attention(a, b_, c, causal, seg),
+        *f32[:3])
+    for name, got, want in zip(("out", "dq", "dk", "dv"),
+                               (out, *grads), (ref, *vjp(f32[3]))):
+        assert got.dtype == q.dtype, name
+        got, want = np.asarray(got.astype(jnp.float32)), np.asarray(want)
+        if dtype == "float32":
+            tol = 2e-5 if name == "out" else 2e-4
+            np.testing.assert_allclose(got, want, rtol=tol, atol=tol,
+                                       err_msg=name)
+        else:
+            np.testing.assert_allclose(
+                got, want, rtol=2e-2, atol=1e-2 * np.abs(want).max(),
+                err_msg=name)
+            assert np.linalg.norm(got - want) < 1e-2 * np.linalg.norm(want)
+
+
 # ---------------------------------------------------------------------------
 # fused rmsnorm(+residual)
 # ---------------------------------------------------------------------------

@@ -57,6 +57,55 @@ def test_flash_attention_fwd_bwd_lowers(dtype, gqa):
     assert_mosaic(lower_tpu(fwd_bwd, q, k, v))
 
 
+def _kernel_dot_operands(closed):
+    """{kernel function name: [(lhs dtype, rhs dtype) of every dot_general
+    in its body, loops included]} over the pallas_calls of `closed`."""
+    found = {}
+
+    def dots(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                out.append(tuple(str(v.aval.dtype) for v in eqn.invars))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                dots(sub, out)
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                body = eqn.params["jaxpr"]
+                dots(body, found.setdefault(body.debug_info.func_name, []))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(closed.jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_dots_take_stored_dtype(dtype):
+    """Every MXU product of the forward, dQ and dKV kernels takes its
+    operands in the dtype the inputs are stored in: a cast back to f32 in
+    front of a dot (multi-pass f32 on the MXU) fails here."""
+    from paddle_tpu.ops.kernels import flash_attention_pallas as fap
+    q = jnp.zeros((1, 512, 2, 64), dtype)
+    seg = jnp.zeros((1, 512), jnp.int32)
+
+    def fwd_bwd(q, k, v):
+        out, lse = fap.flash_attention_forward_lse(q, k, v, causal=True,
+                                                   segment_ids=seg)
+        return fap.flash_attention_backward(q, k, v, out, lse,
+                                            jnp.ones_like(out), causal=True,
+                                            segment_ids=seg)
+
+    found = _kernel_dot_operands(jax.make_jaxpr(fwd_bwd)(q, q, q))
+    assert set(found) == {"_attn_kernel", "_dq_kernel", "_dkv_kernel"}
+    # Q.K^T, P.V forward; S, dP, dS.K in dQ; S, P^T.dO, dP, dS^T.Q in dKV
+    assert {name: len(ops) for name, ops in found.items()} == {
+        "_attn_kernel": 2, "_dq_kernel": 3, "_dkv_kernel": 4}
+    for name, ops in found.items():
+        assert set(ops) == {(dtype, dtype)}, (name, ops)
+
+
 @pytest.mark.parametrize("shape", [(1, 509, 256), (3, 17, 384),
                                    (1, 509, 18432)])  # 18432: rows=56 budget
 def test_rms_norm_prime_rows_lowers(shape):
